@@ -1,11 +1,11 @@
 #!/bin/bash
-# Launcher with the same shape as the reference's (/root/reference/myrun.sh):
-# one command, everything tee'd to raft.log.  A -backend=... flag selects the
+# Launcher with the same shape as the reference's myrun.sh: one command,
+# everything tee'd to raft.log.  A -backend=... flag selects the
 # checker: the TPU-native engine (default) or stock TLC if tla2tools.jar is
 # present.  All other flags pass through to the selected backend.
 set -o pipefail
 BACKEND=jax
-CFG="${RAFT_CFG:-/root/reference/Raft.cfg}"
+CFG="${RAFT_CFG:-$(dirname "$0")/Raft.cfg}"
 ARGS=()
 for a in "$@"; do
   case "$a" in

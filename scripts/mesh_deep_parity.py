@@ -32,7 +32,7 @@ GOLDEN = [1, 1, 3, 9, 22, 57, 136, 345, 931, 2468, 5881, 12505, 24705,
 def main():
     import time
 
-    from tla_raft_tpu.cfgparse import load_raft_config
+    from tla_raft_tpu.cfgparse import REPO_CFG, load_raft_config
     from tla_raft_tpu.parallel import ShardedChecker, make_mesh
 
     import glob
@@ -63,7 +63,7 @@ def main():
         for f in os.listdir(ckdir):
             os.unlink(os.path.join(ckdir, f))
 
-    cfg = load_raft_config("/root/reference/Raft.cfg")
+    cfg = load_raft_config(REPO_CFG)
     mesh = make_mesh(8)
     # capacity sizing is now the ENGINE's job (run(presize=True) default,
     # engine/forecast.py): it forecasts cap_x/vcap for the whole run at

@@ -43,10 +43,10 @@ def main():
         import jax
 
         jax.config.update("jax_platforms", "cpu")
-        from tla_raft_tpu.cfgparse import load_raft_config
+        from tla_raft_tpu.cfgparse import REPO_CFG, load_raft_config
         from tla_raft_tpu.ops.successor import get_kernel
 
-        K = get_kernel(load_raft_config("/root/reference/Raft.cfg")).K
+        K = get_kernel(load_raft_config(REPO_CFG)).K
     files = sorted(glob.glob(os.path.join(ckdir, "delta_*.npz")))
     if not files:
         print(f"no delta files under {ckdir}")

@@ -94,3 +94,95 @@ def test_mutation_is_caught_with_counterexample(tmp_path):
     )
     assert rc == 1
     assert "violated" in out
+
+
+@pytest.mark.parametrize("platforms", ["", "tpu", "tpu,cpu"])
+def test_setup_jax_refuses_a_non_tpu_backend(monkeypatch, platforms):
+    """Unless JAX_PLATFORMS puts the CPU first, a run that lands on the
+    CPU (this process's backend) is an error, not a silent fallback."""
+    from tla_raft_tpu.platform import setup_jax
+
+    monkeypatch.setenv("JAX_PLATFORMS", platforms)
+    with pytest.raises(RuntimeError, match="no TPU"):
+        setup_jax()
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    assert setup_jax().default_backend() == "cpu"
+
+
+def test_compile_cache_dir(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR wins untouched; unset, the cache sits at
+    one fixed path inside the checkout."""
+    import jax
+
+    from tla_raft_tpu.platform import CACHE_DIR, setup_jax
+
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+        jax.config.update("jax_compilation_cache_dir", "/elsewhere")
+        setup_jax()
+        assert jax.config.jax_compilation_cache_dir == "/elsewhere"
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        setup_jax()
+        assert jax.config.jax_compilation_cache_dir == CACHE_DIR
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
+
+
+def _run_py(code_or_args, cwd, **env):
+    import os
+    import subprocess
+    import sys
+
+    args = ["-c", code_or_args] if isinstance(code_or_args, str) else (
+        code_or_args
+    )
+    return subprocess.run(
+        [sys.executable, *args], cwd=cwd, capture_output=True, text=True,
+        timeout=300, env=dict(os.environ, **env),
+    )
+
+
+def test_supervise_parent_stays_off_jax(tmp_path):
+    """--supervise only relaunches children: the parent never imports
+    JAX, so the chip stays free for the child."""
+    import os
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    p = _run_py(
+        "import subprocess, sys\n"
+        "subprocess.call = lambda cmd, *a, **k: 0\n"
+        "from tla_raft_tpu import check\n"
+        f"rc = check.main(['--supervise', '1', '--checkpoint-dir', "
+        f"{str(tmp_path)!r}, '--log', '-'])\n"
+        "print('RC', rc, 'jax' in sys.modules)\n",
+        repo, JAX_PLATFORMS="no_such_platform",
+    )
+    assert "RC 0 False" in p.stdout, p.stderr[-2000:]
+
+
+def test_chip_smoke_alone_fails(tmp_path):
+    """chip_smoke.py without the repo around it exits non-zero and prints
+    no result line."""
+    import os
+    import shutil
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    shutil.copy(os.path.join(repo, "chip_smoke.py"), tmp_path)
+    p = _run_py(["chip_smoke.py"], tmp_path)
+    assert p.returncode != 0
+    assert '"ok"' not in p.stdout
+
+
+def test_chip_workers_capped_at_one(monkeypatch):
+    """Pool/chaos/bench refuse a second chip-using worker (each JAX
+    process claims every chip of the host); CPU-pinned workers are not
+    capped."""
+    from tla_raft_tpu import platform
+
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    platform.check_chip_workers(1)
+    with pytest.raises(ValueError, match="2 worker processes"):
+        platform.check_chip_workers(2)
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    platform.check_chip_workers(8)

@@ -287,12 +287,12 @@ def test_intra_level_crash_resume_bit_identical(tmp_path, built):
 
     def dying_expand(*a, **kw):
         if calls["n"] >= 64:
-            raise RuntimeError("simulated tunnel crash")
+            raise RuntimeError("simulated device crash")
         calls["n"] += 1
         return real_expand(*a, **kw)
 
     chk2._expand_chunk = dying_expand
-    with pytest.raises(RuntimeError, match="simulated tunnel crash"):
+    with pytest.raises(RuntimeError, match="simulated device crash"):
         chk2.run(
             max_depth=depth_cap, checkpoint_dir=ckdir, checkpoint_every=1,
             resume_from=ckdir,

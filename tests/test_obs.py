@@ -52,6 +52,8 @@ def test_on_off_count_parity(s2_run):
     assert t["levels"] == a["depth"]
     assert len(t["level_seconds"]) == t["levels"]
     assert len(t["dispatches_per_level"]) == t["levels"]
+    assert len(t["compile_s_per_level"]) == t["levels"]
+    assert sum(t["compile_s_per_level"]) <= t["compile_s"] + 0.01
     # superstep amortization is visible in the unified block: the S2
     # sweep retires 12 levels in ~4 dispatch windows (span 4)
     assert t["supersteps"] >= 1

@@ -230,3 +230,14 @@ def test_deep_checkpoint_resume(tmp_path):
 def test_deep_requires_host_store():
     with pytest.raises(ValueError, match="host_store_dir"):
         ShardedChecker(S2, make_mesh(2), deep=True)
+
+
+def test_deep_capacities_sized_from_seg_rows(tmp_path):
+    """Deep mode sizes cap_x and the sieve slab once, from seg_rows, so
+    no capacity rung recompiles its programs; plain mode keeps 4096."""
+    chk = ShardedChecker(
+        S2, make_mesh(2), deep=True, seg_rows=1024,
+        host_store_dir=str(tmp_path),
+    )
+    assert (chk.cap_x, chk.scap) == (8192, 131072)
+    assert ShardedChecker(S2, make_mesh(2)).cap_x == 4096

@@ -62,7 +62,7 @@ def tuned(tmp_path_factory):
 
 def test_search_result_shape(tuned):
     res = tuned["res"]
-    assert res["regime"] == "jax|raft|S2V1|b2"
+    assert res["regime"] == "cpu|raft|S2V1|b2"
     assert res["committed"] == tuned["path"]
     assert res["probe"]["probes"] == len(res["ledger"]) >= 2
     assert set(plans.defaults()) == set(res["knobs"])
@@ -91,7 +91,7 @@ def test_probe_events_emitted(tuned):
     probes = [e for e in events if e["ev"] == "tune_probe"]
     assert len(probes) == tuned["res"]["probe"]["probes"]
     for e in probes:
-        assert e["regime"] == "jax|raft|S2V1|b2"
+        assert e["regime"] == "cpu|raft|S2V1|b2"
         assert e["knobs"]["superstep_span"] in (2, 4)
         assert e["metric"] > 0 and e["ok"] is True
 
@@ -102,7 +102,7 @@ def test_plan_cache_roundtrip(tuned):
     knobs = plans.resolve(S2, "jax", path=tuned["path"])
     assert knobs == tuned["res"]["knobs"]
     # re-commit folds (other regimes kept, version bumps)
-    plans.commit(tuned["path"], "jax|raft|S9V9|b0", {"chunk": 2048})
+    plans.commit(tuned["path"], "cpu|raft|S9V9|b0", {"chunk": 2048})
     doc = plans.load_cache(tuned["path"])
     assert doc["version"] == 2 and len(doc["plans"]) == 2
     assert plans.resolve(S2, "jax", path=tuned["path"]) == knobs
@@ -178,7 +178,7 @@ def test_clamp_types_and_bounds():
 
 
 def test_regime_key_and_fallback():
-    assert plans.regime_key(S2, "jax") == "jax|raft|S2V1|b2"
+    assert plans.regime_key(S2, "jax") == "cpu|raft|S2V1|b2"
     big = RaftConfig(n_servers=3, n_vals=2, max_election=3,
                      max_restart=1)
     key = plans.regime_key(big, "cpu")
@@ -192,8 +192,8 @@ def test_regime_key_and_fallback():
 
 def test_fallback_resolution_smaller_budget_only(tmp_path):
     path = str(tmp_path / "plans.json")
-    plans.commit(path, "jax|raft|S2V1|b1", {"chunk": 2048})
-    plans.commit(path, "jax|raft|S2V1|b4", {"chunk": 8192})
+    plans.commit(path, "cpu|raft|S2V1|b1", {"chunk": 2048})
+    plans.commit(path, "cpu|raft|S2V1|b4", {"chunk": 8192})
     # S2 is b2: the b1 plan transfers up, the b4 plan never flows down
     assert plans.resolve(S2, "jax", path=path)["chunk"] == 2048
 
@@ -219,7 +219,7 @@ def test_corrupt_and_stale_plans_quarantined(tmp_path):
     # committed-then-mangled: digest mismatch == quarantined
     d3 = tmp_path / "mangled"
     d3.mkdir()
-    plans.commit(str(d3 / "plans.json"), "jax|raft|S2V1|b2",
+    plans.commit(str(d3 / "plans.json"), "cpu|raft|S2V1|b2",
                  {"chunk": 2048})
     p3 = d3 / "plans.json"
     p3.write_text(p3.read_text().replace("2048", "4096"),
@@ -231,7 +231,7 @@ def test_out_of_range_plan_values_clamped(tmp_path):
     """A hand-mangled (or adversarially detuned) plan can make a run
     slow but never hand a kernel a nonsense shape."""
     path = str(tmp_path / "plans.json")
-    plans.commit(path, "jax|raft|S2V1|b2",
+    plans.commit(path, "cpu|raft|S2V1|b2",
                  {"chunk": 10 ** 9, "probe_window": 0,
                   "cap_margin": 0.1})
     got = plans.resolve(S2, "jax", path=path)
@@ -299,7 +299,7 @@ def test_committed_default_plan_cache_readable():
     assert os.path.exists(path), "committed default plan cache missing"
     doc = plans.load_cache(path)
     assert doc is not None, "shipped plan cache failed verification"
-    assert "jax|raft|S2V1|b2" in doc["plans"]
+    assert "cpu|raft|S2V1|b2" in doc["plans"]
     for ent in doc["plans"].values():
         assert plans.clamp(ent["knobs"]) == ent["knobs"]
 

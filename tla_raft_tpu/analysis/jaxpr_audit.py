@@ -190,7 +190,7 @@ def kernel_registry():
 
 
 def _subjaxprs(params: dict):
-    import jax.core as jcore
+    import jax.extend.core as jcore
 
     for v in params.values():
         if isinstance(v, jcore.ClosedJaxpr):

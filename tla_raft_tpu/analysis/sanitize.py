@@ -245,15 +245,24 @@ def obs_watch_compiles() -> None:
         return
     from jax._src import monitoring
 
+    def on_hit(name, **kw):
+        # recorded inside the compile's timed span, on its thread: the
+        # duration event that closes the span was served by the cache
+        if name == "/jax/compilation_cache/cache_hits":
+            _tl.cache_hit = True
+
     def on_event(name, *a, **kw):
         if name == "/jax/core/compile/backend_compile_duration":
             secs = a[0] if a and isinstance(a[0], (int, float)) else (
                 kw.get("duration_secs", 0.0)
             )
+            hit = getattr(_tl, "cache_hit", False)
+            _tl.cache_hit = False
             _obs.compile_done(
-                float(secs or 0.0), thread_compiles_declared()
+                float(secs or 0.0), thread_compiles_declared(), hit
             )
 
+    monitoring.register_event_listener(on_hit)
     monitoring.register_event_duration_secs_listener(on_event)
     _OBS_COMPILE_ARMED = True
 

@@ -1,11 +1,11 @@
 """Parser for the TLC model-configuration grammar used by the reference.
 
-``Raft.cfg`` (/root/reference/Raft.cfg) is the single source of truth for
-constants and checker directives; this module parses the subset of the TLC
-cfg grammar it uses — ``CONSTANTS`` (integer bindings, self-named model
-values, set literals), ``SYMMETRY``, ``VIEW``, ``INIT``, ``NEXT``,
-``INVARIANT`` — plus ``\\*`` comments, and lowers the result to a
-:class:`~tla_raft_tpu.config.RaftConfig`.
+``Raft.cfg`` (the reference's, committed at the repo root) is the single
+source of truth for constants and checker directives; this module parses
+the subset of the TLC cfg grammar it uses — ``CONSTANTS`` (integer
+bindings, self-named model values, set literals), ``SYMMETRY``, ``VIEW``,
+``INIT``, ``NEXT``, ``INVARIANT`` — plus ``\\*`` comments, and lowers
+the result to a :class:`~tla_raft_tpu.config.RaftConfig`.
 
 Honored quirks of the reference cfg (SURVEY.md §5 "config system"):
   * ``MaxTerm = 3`` (Raft.cfg:2) is vestigial — no ``CONSTANT MaxTerm``
@@ -20,9 +20,16 @@ Honored quirks of the reference cfg (SURVEY.md §5 "config system"):
 from __future__ import annotations
 
 import dataclasses
+import os
 import re
 
 from .config import RaftConfig
+
+# the reference Raft.cfg, committed at the repo root: the default of every
+# entry point (check.py, myrun.sh, bench.py, the tuner)
+REPO_CFG = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "Raft.cfg"
+)
 
 _DIRECTIVES = {
     "CONSTANTS",

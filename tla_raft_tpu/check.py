@@ -10,7 +10,7 @@ oracle, prints TLC-shaped progress/result lines, and tees everything to
 state counts + verdict, SURVEY.md §5 "metrics/logging").
 
 Usage:
-  python -m tla_raft_tpu.check --config /root/reference/Raft.cfg \
+  python -m tla_raft_tpu.check [--config Raft.cfg] \
       [--backend jax|oracle] [--max-depth N] [--chunk N] \
       [--invariant NAME]... [--no-symmetry] [--no-view] \
       [--checkpoint-dir states] [--recover states] \
@@ -34,7 +34,7 @@ import sys
 import time
 
 from .config import MSG_TYPE_NAMES, ROLE_NAMES, RaftConfig
-from .cfgparse import load_raft_config
+from .cfgparse import REPO_CFG, load_raft_config
 
 
 class Tee:
@@ -247,6 +247,17 @@ def summarize(res, chk=None, seconds: float | None = None,
         seconds=round(seconds, 3) if seconds is not None else None,
         violation=res.violation[0] if res.violation else None,
     )
+    if chk is not None:
+        import jax
+
+        devs = jax.devices()
+        out["device"] = dict(
+            platform=devs[0].platform, kind=devs[0].device_kind,
+            count=len(devs),
+        )
+        # the hash store's grow-failure fallback (device OOM, injected
+        # fault): None, or why the run left the fast path
+        out["hashstore_degraded"] = getattr(chk, "hashstore_degraded", None)
     # integrity-audit counters (single-device --audit runs only)
     aud = getattr(chk, "audit_stats", None)
     if aud and aud.get("levels"):
@@ -290,6 +301,15 @@ def summarize(res, chk=None, seconds: float | None = None,
         or gov.stats["rearms"]
     ):
         out["sieve_governor"] = gov.snapshot()
+    # sharded deep sweep: the capacities it ended at, its reactive
+    # grows (each recompiles the deep programs) and what the sieve did
+    if getattr(chk, "deep", False):
+        ex = chk.meter.summary()
+        out["mesh_deep"] = dict(
+            cap_x=chk.cap_x, scap=chk.scap if chk.sieve else 0,
+            reactive_grows=chk.reactive_grows,
+            candidates=ex["candidates"], sieved=ex["sieved"],
+        )
     # per-owner straggler/skew metrics (mesh runs); kept at top level
     # for compatibility AND folded into the telemetry block below
     skew = getattr(chk, "skew", None)
@@ -329,7 +349,7 @@ def run_check(
     seg_rows: int = 1 << 15,
     sieve: bool = True,
     compress: bool = True,
-    cap_x: int = 4096,
+    cap_x: int | None = None,
     canon: str = "late",
     use_hashstore: bool | None = None,
     pipeline: bool | None = None,
@@ -388,6 +408,12 @@ def run_check(
     """
     from .obs import telemetry as obs_telemetry
 
+    if backend != "oracle":
+        # before anything touches a device: the platform check, and the
+        # compile cache must be configured before the first compile
+        from .platform import setup_jax
+
+        setup_jax()
     tel_on = (
         obs_telemetry.enabled_by_env() if telemetry is None
         else bool(telemetry)
@@ -812,7 +838,7 @@ def _run_check_impl(
 
 def main(argv=None) -> int:
     p = argparse.ArgumentParser(prog="tla_raft_tpu.check")
-    p.add_argument("--config", default="/root/reference/Raft.cfg",
+    p.add_argument("--config", default=REPO_CFG,
                    help="TLC .cfg file (single source of truth for constants)")
     p.add_argument("--spec", default=None,
                    help="TLA+ spec file to validate against the compiled "
@@ -918,8 +944,8 @@ def main(argv=None) -> int:
     p.add_argument("--prewarm", type=int, choices=(0, 1), default=None,
                    help="forecast-driven AOT program prewarm: compile "
                         "the deep-level shape ladder in a background "
-                        "thread while shallow levels run (default: on "
-                        "for tunneled backends; env: TLA_RAFT_PREWARM; "
+                        "thread while shallow levels run (default: "
+                        "off; env: TLA_RAFT_PREWARM; "
                         "single-device engine only — ignored with "
                         "--mesh)")
     p.add_argument("--mxu-expand", type=int, choices=(0, 1), default=None,
@@ -974,8 +1000,9 @@ def main(argv=None) -> int:
                    help="revert to the sort-based visited path (lexsort "
                         "+ searchsorted + sorted merge) instead of the "
                         "on-device open-addressing fingerprint store")
-    p.add_argument("--cap-x", type=int, default=4096,
-                   help="per-device candidate capacity (distributed mode)")
+    p.add_argument("--cap-x", type=int, default=None,
+                   help="per-device candidate capacity (distributed mode; "
+                        "default 4096, with --mesh-deep 8 x --seg-rows)")
     p.add_argument("--canon", choices=("late", "expand"), default="late",
                    help="candidate canonicalization: 'late' fingerprints "
                         "only compacted candidates (default; required for "

@@ -1,16 +1,18 @@
 """Native runtime tier: lazy-built C++ components bound via ctypes.
 
 ``HostFPStore`` wraps fpstore.cpp — the external-memory fingerprint store
-that takes over TLC's FPSet role (JVM heap + ``states/`` disk spill,
-/root/reference/myrun.sh:3, .gitignore:2) when a run's visited set
-outgrows device HBM.  The shared library is compiled on first use with the
-system toolchain and cached next to the source.
+that takes over TLC's FPSet role (JVM heap + ``states/`` disk spill in
+the reference's myrun.sh) when a run's visited set outgrows device HBM.
+The shared library is compiled on first use with the system toolchain
+and cached next to the source, keyed by a hash of what built it.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
+import platform
 import subprocess
 
 import numpy as np
@@ -22,23 +24,53 @@ _BASE_SRC = os.path.join(_DIR, "cpubase.cpp")
 _BASE_BIN = os.path.join(_DIR, "cpubase")
 
 
+def _host_key() -> str:
+    """What ``-march=native`` compiles for: the build host's CPU."""
+    try:
+        with open("/proc/cpuinfo") as f:
+            info = [
+                ln for ln in f.read().splitlines()
+                if ln.startswith(("model name", "flags"))
+            ][:2]
+    except OSError:
+        info = []
+    return "|".join([platform.machine(), *info])
+
+
+def _build(src: str, out: str, cmd: list[str], force: bool,
+           host_specific: bool) -> str:
+    """Compile ``src`` -> ``out`` unless ``out.key`` records a build of
+    this exact source and command (and, for ``-march=native`` builds,
+    this CPU) — a binary copied from another host is rebuilt, never
+    run."""
+    h = hashlib.sha256()
+    with open(src, "rb") as f:
+        h.update(f.read())
+    h.update(" ".join(cmd).encode())
+    if host_specific:
+        h.update(_host_key().encode())
+    key = h.hexdigest()[:16]
+    stamp = out + ".key"
+    if not force and os.path.exists(out) and os.path.exists(stamp):
+        with open(stamp) as f:
+            if f.read().strip() == key:
+                return out
+    tmp = f"{out}.tmp{os.getpid()}"
+    subprocess.run([*cmd, src, "-o", tmp], check=True, capture_output=True)
+    # compiled-artifact cache swap, not a checkpoint artifact
+    os.replace(tmp, out)  # graftlint: waive[GL009]
+    with open(tmp, "w") as f:
+        f.write(key)
+    os.replace(tmp, stamp)  # graftlint: waive[GL009]
+    return out
+
+
 def build_native(force: bool = False) -> str:
-    """Compile fpstore.cpp -> libfpstore.so (cached by mtime)."""
-    if (
-        not force
-        and os.path.exists(_SO)
-        and os.path.getmtime(_SO) >= os.path.getmtime(_SRC)
-    ):
-        return _SO
-    tmp = _SO + ".tmp"
-    subprocess.run(
-        ["g++", "-O2", "-std=c++17", "-shared", "-fPIC", _SRC, "-o", tmp],
-        check=True,
-        capture_output=True,
+    """Compile fpstore.cpp -> libfpstore.so (cached by source hash)."""
+    return _build(
+        _SRC, _SO, ["g++", "-O2", "-std=c++17", "-shared", "-fPIC"],
+        force, host_specific=False,
     )
-    # compiled-.so cache swap, not a checkpoint artifact
-    os.replace(tmp, _SO)  # graftlint: waive[GL009]
-    return _SO
 
 
 def build_cpubase(force: bool = False) -> str:
@@ -46,23 +78,13 @@ def build_cpubase(force: bool = False) -> str:
 
     The multithreaded C++ explicit-state checker of the same spec family
     (the honest stand-in for `tlc -workers N`, BASELINE.md) — bench.py
-    measures `vs_baseline` against it."""
-    if (
-        not force
-        and os.path.exists(_BASE_BIN)
-        and os.path.getmtime(_BASE_BIN) >= os.path.getmtime(_BASE_SRC)
-    ):
-        return _BASE_BIN
-    tmp = _BASE_BIN + ".tmp"
-    subprocess.run(
-        ["g++", "-O3", "-march=native", "-std=c++17", "-pthread", "-w",
-         _BASE_SRC, "-o", tmp],
-        check=True,
-        capture_output=True,
+    measures `vs_baseline` against it.  Built with ``-march=native``,
+    so the cache is keyed by the host's CPU as well as the source."""
+    return _build(
+        _BASE_SRC, _BASE_BIN,
+        ["g++", "-O3", "-march=native", "-std=c++17", "-pthread", "-w"],
+        force, host_specific=True,
     )
-    # compiled-binary cache swap, not a checkpoint artifact
-    os.replace(tmp, _BASE_BIN)  # graftlint: waive[GL009]
-    return _BASE_BIN
 
 
 _lib = None

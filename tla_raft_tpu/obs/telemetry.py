@@ -23,7 +23,7 @@ dispatch a program.  Publishing is a plain function call with a
 ``CURRENT is None`` fast path; with telemetry off every hook in the
 tree is one global read + one branch.
 
-Event taxonomy (docs/OBSERVABILITY.md):
+Event catalogue (docs/OBSERVABILITY.md):
 
 ================  ======================================================
 ``run_begin``     config + engine flags, wall-clock anchor
@@ -371,12 +371,17 @@ class TelemetryHub:
         self.level_new: list[int] = []
         self.dispatches_per_level: list[int] = []
         self.fetches_per_level: list[int] = []
+        # compile seconds inside each level: level_seconds minus this is
+        # the level's own work
+        self.compile_s_per_level: list[float] = []
+        self._lvl_compile_s = 0.0
         self.dispatches = 0
         self.fetches = 0
         self.fetch_wait_s = 0.0
         self.fetch_bytes = 0
         self.compiles = 0
         self.compile_s = 0.0
+        self.cache_hits = 0  # compiles the persistent cache served
         self.prewarm_compiles = 0
         self.checkpoints = 0
         self.checkpoint_s = 0.0
@@ -546,8 +551,10 @@ class TelemetryHub:
             self.level_new.append(int(doc.get("n_new") or 0))
             self.dispatches_per_level.append(self._lvl_dispatches)
             self.fetches_per_level.append(self._lvl_fetches)
+            self.compile_s_per_level.append(round(self._lvl_compile_s, 6))
             self._lvl_dispatches = 0
             self._lvl_fetches = 0
+            self._lvl_compile_s = 0.0
             self.slab_cap = int(doc.get("slab_cap") or 0)
             self.distinct = int(doc.get("distinct") or 0)
         elif ev == "compile":
@@ -556,6 +563,9 @@ class TelemetryHub:
             else:
                 self.compiles += 1
             self.compile_s += float(doc.get("s") or 0.0)
+            self._lvl_compile_s += float(doc.get("s") or 0.0)
+            if doc.get("hit"):
+                self.cache_hits += 1
         elif ev == "checkpoint":
             self.checkpoints += 1
             self.checkpoint_s += float(doc.get("s") or 0.0)
@@ -627,6 +637,7 @@ class TelemetryHub:
             self.profile_windows += int(doc.get("windows") or 0)
         elif ev == "run_begin":
             self._last_boundary = t
+            self._lvl_compile_s = 0.0
 
     # -- reporting --------------------------------------------------------
 
@@ -644,11 +655,13 @@ class TelemetryHub:
                 dispatches_per_level=list(self.dispatches_per_level),
                 fetches=self.fetches,
                 fetches_per_level=list(self.fetches_per_level),
+                compile_s_per_level=list(self.compile_s_per_level),
                 fetch_wait_s=round(self.fetch_wait_s, 6),
                 fetch_bytes=self.fetch_bytes,
                 compiles=self.compiles,
                 prewarm_compiles=self.prewarm_compiles,
                 compile_s=round(self.compile_s, 3),
+                cache_hits=self.cache_hits,
                 checkpoints=self.checkpoints,
                 checkpoint_s=round(self.checkpoint_s, 6),
                 checkpoint_bytes=self.checkpoint_bytes,
@@ -772,10 +785,12 @@ def fetch_done(seconds: float, nbytes: int = 0) -> None:
         hub.emit("fetch", s=round(seconds, 6), b=nbytes)
 
 
-def compile_done(seconds: float, declared: bool) -> None:
+def compile_done(seconds: float, declared: bool, hit: bool = False) -> None:
+    """One backend compile; ``hit`` = served by the persistent cache."""
     hub = CURRENT
     if hub is not None:
-        hub.emit("compile", s=round(seconds, 4), declared=declared)
+        hub.emit("compile", s=round(seconds, 4), declared=declared,
+                 hit=hit)
 
 
 def checkpoint(kind: str, name: str, seconds: float,

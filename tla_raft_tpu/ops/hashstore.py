@@ -83,7 +83,7 @@ BIGP = np.int64(1 << 62)
 # margin so overflow-triggered rehashes are rare-to-never in practice
 # while keeping the while_loop's worst-case trip count small.
 PROBE_DEPTH = 64
-# slots examined per probe round: one [N, W] gather of W consecutive
+# slots examined per probe round: one [W, N] gather of W consecutive
 # slots per lane instead of W scalar rounds — the walk's while_loop
 # runs at most PROBE_DEPTH/W trips, and the typical batch (expected
 # chain ~1-2 at <=1/2 load) settles in ONE trip.  Consecutive slots
@@ -171,7 +171,10 @@ def _probe_rounds(slab, fps, depth):
     live = fps != SENT
     h0 = (mix64(fps) & jnp.uint64(cap - 1)).astype(I32)
     W = PROBE_WINDOW
-    woff = jnp.arange(W, dtype=I32)[None, :]
+    # window-major [W, N]: the lanes sit on the minor axis.  Lane-major
+    # [N, W] pads its minor dim of W up to a 128-wide TPU tile — 16x the
+    # bytes at W = 8, which put a 2M-row superstep 11 GB over HBM
+    woff = jnp.arange(W, dtype=I32)[:, None]
 
     def cond(c):
         d, _idx, _found, done = c
@@ -179,22 +182,23 @@ def _probe_rounds(slab, fps, depth):
 
     def body(c):
         d, idx, found, done = c
-        cur = (h0[:, None] + d + woff) & (cap - 1)  # [N, W]
+        cur = (h0[None, :] + d + woff) & (cap - 1)  # [W, N]
         v = slab[cur]
-        hitw = v == fps[:, None]
+        hitw = v == fps[None, :]
         stopw = hitw | (v == SENT)
         # first hit-or-empty slot in the window, selected gather-free
         # (one-hot contraction — the repo's standard idiom)
         one = (
             stopw
-            & (jnp.cumsum(stopw.astype(I32), axis=1) == 1)
+            & (jnp.cumsum(stopw.astype(I32), axis=0) == 1)
         )
-        cand = (cur * one).sum(1, dtype=I32)
-        is_hit = (hitw & one).any(1)
-        settle = ~done & stopw.any(1)
+        cand = (cur * one).sum(0, dtype=I32)
+        is_hit = (hitw & one).any(0)
+        stop = stopw.any(0)
+        settle = ~done & stop
         idx = jnp.where(settle, cand, idx)
         found = found | (settle & is_hit)
-        done = done | stopw.any(1)
+        done = done | stop
         return d + W, idx, found, done
 
     init = (

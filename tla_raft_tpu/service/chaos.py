@@ -147,13 +147,12 @@ def _submit(root: str, jobs) -> list[str]:
 
 
 def _spawn(root: str, name: str, args, fault: str = "",
-           batch: bool = True, cache: str | None = None):
+           batch: bool = True):
+    # the workers share the persistent compile cache (platform.py): later
+    # workers, and the fleet arm after golden, ride programs already
+    # compiled
     env = dict(os.environ)
     env["TLA_RAFT_FAULT"] = fault
-    if cache:
-        # one shared persistent compile cache: later workers (and the
-        # fleet arm after golden) ride programs already compiled
-        env["TLA_RAFT_COMPILE_CACHE"] = cache
     cmd = [
         sys.executable, "-m", "tla_raft_tpu.service", "run",
         "--root", root, "--worker", name,
@@ -195,7 +194,12 @@ def _result_stamp(path: str):
 
 
 def run_campaign(args, out=sys.stderr) -> dict:
+    from ..platform import check_chip_workers
+
     t0 = time.monotonic()
+    # the fleet runs N workers at once (golden runs alone, before it);
+    # this process never touches JAX; a chip worker runs alone
+    check_chip_workers(args.workers)
     plans = parse_schedule(args.schedule)
     names = [f"worker{i + 1}" for i in range(args.workers)]
     unknown = sorted(set(plans) - set(names))
@@ -209,7 +213,6 @@ def run_campaign(args, out=sys.stderr) -> dict:
     base = args.base
     golden_root = os.path.join(base, "golden")
     fleet_root = os.path.join(base, "fleet")
-    cache = os.path.join(base, "cache")
     jids = _submit(golden_root, jobs)
     _submit(fleet_root, jobs)
 
@@ -222,7 +225,7 @@ def run_campaign(args, out=sys.stderr) -> dict:
     # -- golden arm: one clean sequential worker -----------------------
     say(f"golden arm: draining {len(jids)} jobs sequentially")
     p, logf = _spawn(golden_root, "golden", args, fault="",
-                     batch=False, cache=cache)
+                     batch=False)
     try:
         p.wait(timeout=args.timeout)
     except subprocess.TimeoutExpired:
@@ -251,7 +254,7 @@ def run_campaign(args, out=sys.stderr) -> dict:
     for name in names:
         procs[name], lf = _spawn(
             fleet_root, name, args, fault=plans.get(name, ""),
-            batch=True, cache=cache,
+            batch=True,
         )
         logs.append(lf)
     fq = JobQueue(fleet_root, worker="chaos-supervisor",

@@ -11,11 +11,9 @@ from __future__ import annotations
 import argparse
 import dataclasses
 import json
-import os
 import sys
 
-from ..cfgparse import load_raft_config
-from ..config import RaftConfig
+from ..cfgparse import REPO_CFG, load_raft_config
 from . import plans, search
 
 
@@ -23,7 +21,7 @@ def main(argv=None) -> int:
     p = argparse.ArgumentParser(prog="python -m tla_raft_tpu.tune")
     p.add_argument("cmd", nargs="?", default="tune",
                    choices=("tune", "show"))
-    p.add_argument("--config", default="/root/reference/Raft.cfg")
+    p.add_argument("--config", default=REPO_CFG)
     p.add_argument("--backend", default="jax")
     p.add_argument("--servers", type=int, default=None)
     p.add_argument("--vals", type=int, default=None)
@@ -56,16 +54,7 @@ def main(argv=None) -> int:
         print(json.dumps(doc, indent=1, sort_keys=True))
         return 0
 
-    if os.path.exists(args.config):
-        cfg = load_raft_config(args.config)
-    else:
-        # containers without the reference checkout: RaftConfig()
-        # defaults ARE the Raft.cfg constants (config.py docstring)
-        cfg = RaftConfig()
-        print(
-            f"tune: {args.config} not found; using the built-in "
-            "reference constants", file=sys.stderr,
-        )
+    cfg = load_raft_config(args.config)
     overrides = {
         k: v for k, v in dict(
             n_servers=args.servers, n_vals=args.vals,

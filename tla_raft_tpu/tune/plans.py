@@ -112,6 +112,13 @@ def budget_class(cfg) -> int:
 
 
 def regime_key(cfg, backend: str, spec: str = "raft") -> str:
+    """``platform|spec|S<n>V<n>|b<class>``.  For the engine backend
+    (``"jax"``) the first field is the device platform JAX runs on, so
+    a plan tuned on the CPU never steers a chip run (and vice versa)."""
+    if backend == "jax":
+        import jax
+
+        backend = jax.default_backend()
     return (
         f"{backend}|{spec}|S{int(cfg.n_servers)}V{int(cfg.n_vals)}"
         f"|b{budget_class(cfg)}"
