@@ -231,7 +231,8 @@ _OBS_COMPILE_ARMED = False
 
 
 def obs_watch_compiles() -> None:
-    """Publish XLA backend compiles into the telemetry hub.
+    """Publish XLA backend compiles into the telemetry hub, and record
+    its spans (``telemetry.span``) as profiler ``TraceAnnotation``s.
 
     Registered ONCE per process (idempotent), independent of the full
     Sanitizer: the listener is a cheap no-op while no hub is
@@ -243,7 +244,10 @@ def obs_watch_compiles() -> None:
     global _OBS_COMPILE_ARMED
     if _OBS_COMPILE_ARMED:
         return
+    import jax
     from jax._src import monitoring
+
+    _obs.set_annotator(jax.profiler.TraceAnnotation)
 
     def on_hit(name, **kw):
         # recorded inside the compile's timed span, on its thread: the

@@ -435,71 +435,79 @@ def run_check(
                 config=cfg.describe(), backend=backend, mesh=mesh,
                 mesh_deep=mesh_deep, recover=bool(recover),
             )
-    # -- autotuned plan resolution (tune/plans.py) --------------------
-    # Resolved AFTER the hub install so the plan_applied event lands in
-    # this run's flight recorder; installed only when no outer caller
-    # (the tuner's probe loop, the service bucket pass) already holds
-    # the registry, and fully restored on exit either way.
-    from .ops import hashstore as _hashstore
-    from .tune import active as _plan_active
-    from .tune import plans as _plans
+    if hub is not None:
+        # publish XLA backend compiles into the flight recorder and
+        # record its spans on the profiler's clock (idempotent,
+        # armed only after setup_jax picked the platform)
+        from .analysis.sanitize import obs_watch_compiles
 
-    if plan is False or plan == "0":
-        plan_knobs: dict = {}
-    elif isinstance(plan, dict):
-        plan_knobs = _plans.clamp(plan)
-    elif isinstance(plan, str) and plan not in ("", "1"):
-        plan_knobs = _plans.resolve(cfg, backend, path=plan)
-    else:
-        plan_knobs = _plans.resolve(cfg, backend)
-    own_plan = bool(plan_knobs) and _plan_active.installed() is None
-    prev_pw = None
-    if own_plan:
-        _plan_active.install(plan_knobs)
-        if "probe_window" in plan_knobs:
-            prev_pw = _hashstore.probe_window()
-            _hashstore.set_probe_window(int(plan_knobs["probe_window"]))
-        obs_telemetry.emit(
-            "plan_applied",
-            regime=_plans.regime_key(cfg, backend),
-            knobs=dict(plan_knobs),
-        )
-        if out is not None:
-            print(
-                f"Autotuned plan: {_plans.regime_key(cfg, backend)} -> "
-                f"{plan_knobs} (TLA_RAFT_PLAN=0 reverts)", file=out,
+        obs_watch_compiles()
+    with obs_telemetry.span("raft.check"):
+        # -- autotuned plan resolution (tune/plans.py) --------------------
+        # Resolved AFTER the hub install so the plan_applied event lands in
+        # this run's flight recorder; installed only when no outer caller
+        # (the tuner's probe loop, the service bucket pass) already holds
+        # the registry, and fully restored on exit either way.
+        from .ops import hashstore as _hashstore
+        from .tune import active as _plan_active
+        from .tune import plans as _plans
+
+        if plan is False or plan == "0":
+            plan_knobs: dict = {}
+        elif isinstance(plan, dict):
+            plan_knobs = _plans.clamp(plan)
+        elif isinstance(plan, str) and plan not in ("", "1"):
+            plan_knobs = _plans.resolve(cfg, backend, path=plan)
+        else:
+            plan_knobs = _plans.resolve(cfg, backend)
+        own_plan = bool(plan_knobs) and _plan_active.installed() is None
+        prev_pw = None
+        if own_plan:
+            _plan_active.install(plan_knobs)
+            if "probe_window" in plan_knobs:
+                prev_pw = _hashstore.probe_window()
+                _hashstore.set_probe_window(int(plan_knobs["probe_window"]))
+            obs_telemetry.emit(
+                "plan_applied",
+                regime=_plans.regime_key(cfg, backend),
+                knobs=dict(plan_knobs),
             )
-    if chunk is None:
-        chunk = int(plan_knobs.get("chunk", 1024)) if own_plan else 1024
-    try:
-        summary = _run_check_impl(
-            cfg, backend=backend, max_depth=max_depth, chunk=chunk,
-            checkpoint_dir=checkpoint_dir,
-            checkpoint_every=checkpoint_every, recover=recover,
-            fpstore_dir=fpstore_dir, mesh=mesh, exchange=exchange,
-            mesh_deep=mesh_deep, seg_rows=seg_rows, sieve=sieve,
-            compress=compress, cap_x=cap_x, canon=canon,
-            use_hashstore=use_hashstore, pipeline=pipeline,
-            pipeline_window=pipeline_window, prewarm=prewarm,
-            use_mxu=use_mxu, megakernel=megakernel,
-            superstep=superstep, audit=audit,
-            audit_retries=audit_retries, watchdog=watchdog,
-            profile=profile,
-            dev_bytes=dev_bytes, warm_bytes=warm_bytes,
-            hub=hub, progress=progress, out=out,
-            install_signals=install_signals,
-        )
-        if own_plan:
-            summary["plan"] = dict(plan_knobs)
-        return summary
-    finally:
-        if own_plan:
-            _plan_active.clear()
-            if prev_pw is not None:
-                _hashstore.set_probe_window(prev_pw)
-        if own_hub:
-            obs_telemetry.install(None)
-            hub.close()
+            if out is not None:
+                print(
+                    f"Autotuned plan: {_plans.regime_key(cfg, backend)} -> "
+                    f"{plan_knobs} (TLA_RAFT_PLAN=0 reverts)", file=out,
+                )
+        if chunk is None:
+            chunk = int(plan_knobs.get("chunk", 1024)) if own_plan else 1024
+        try:
+            summary = _run_check_impl(
+                cfg, backend=backend, max_depth=max_depth, chunk=chunk,
+                checkpoint_dir=checkpoint_dir,
+                checkpoint_every=checkpoint_every, recover=recover,
+                fpstore_dir=fpstore_dir, mesh=mesh, exchange=exchange,
+                mesh_deep=mesh_deep, seg_rows=seg_rows, sieve=sieve,
+                compress=compress, cap_x=cap_x, canon=canon,
+                use_hashstore=use_hashstore, pipeline=pipeline,
+                pipeline_window=pipeline_window, prewarm=prewarm,
+                use_mxu=use_mxu, megakernel=megakernel,
+                superstep=superstep, audit=audit,
+                audit_retries=audit_retries, watchdog=watchdog,
+                profile=profile,
+                dev_bytes=dev_bytes, warm_bytes=warm_bytes,
+                hub=hub, progress=progress, out=out,
+                install_signals=install_signals,
+            )
+            if own_plan:
+                summary["plan"] = dict(plan_knobs)
+            return summary
+        finally:
+            if own_plan:
+                _plan_active.clear()
+                if prev_pw is not None:
+                    _hashstore.set_probe_window(prev_pw)
+            if own_hub:
+                obs_telemetry.install(None)
+                hub.close()
 
 
 def _run_check_impl(
@@ -548,6 +556,8 @@ def _run_check_impl(
         # stores keep their native 64 MiB buffer default — the 1 GiB
         # tiered-generation default must not silently re-budget them
         warm_bytes = int(float(os.environ["TLA_RAFT_WARM_BYTES"]))
+    from .obs import telemetry as obs_telemetry
+
     out = out if out is not None else _Silent()
     t0 = time.monotonic()
     sanitizer = None
@@ -562,13 +572,6 @@ def _run_check_impl(
         from .platform import setup_jax
 
         jax = setup_jax()
-        if hub is not None:
-            # publish XLA backend compiles into the flight recorder
-            # (idempotent, armed only after setup_jax picked the
-            # platform)
-            from .analysis.sanitize import obs_watch_compiles
-
-            obs_watch_compiles()
         if install_signals:
             # SIGTERM/SIGINT request a cooperative preemption: the
             # engine finishes the in-flight level, flushes its
@@ -779,24 +782,26 @@ def _run_check_impl(
                     )
         else:
             with sanctx, tsanctx:
-                chk = JaxChecker(
-                    cfg, chunk=chunk, progress=progress,
-                    host_store=host_store, canon=canon,
-                    use_hashstore=(
-                        True if use_hashstore is None else use_hashstore
-                    ),
-                    pipeline=pipeline,
-                    pipeline_window=pipeline_window,
-                    use_mxu=use_mxu,
-                    megakernel=megakernel,
-                    superstep=superstep,
-                    prewarm=prewarm,
-                    audit=audit,
-                    audit_retries=audit_retries,
-                    watchdog=wd,
-                    store_bytes=dev_bytes,
-                    warm_bytes=warm_bytes,
-                )
+                with obs_telemetry.span("raft.check.construct"):
+                    chk = JaxChecker(
+                        cfg, chunk=chunk, progress=progress,
+                        host_store=host_store, canon=canon,
+                        use_hashstore=(
+                            True if use_hashstore is None
+                            else use_hashstore
+                        ),
+                        pipeline=pipeline,
+                        pipeline_window=pipeline_window,
+                        use_mxu=use_mxu,
+                        megakernel=megakernel,
+                        superstep=superstep,
+                        prewarm=prewarm,
+                        audit=audit,
+                        audit_retries=audit_retries,
+                        watchdog=wd,
+                        store_bytes=dev_bytes,
+                        warm_bytes=warm_bytes,
+                    )
                 if dev_bytes:
                     print(
                         f"Tiered visited store: hot slab budget "
@@ -820,14 +825,13 @@ def _run_check_impl(
                 finally:
                     wd_teardown()
 
-    if hub is not None:
-        from .obs import telemetry as obs_telemetry
-
-        obs_telemetry.run_end(
-            ok=res.ok, distinct=res.distinct,
-            generated=res.generated, depth=res.depth,
-        )
-    summary = summarize(res, chk, time.monotonic() - t0, hub=hub)
+    with obs_telemetry.span("raft.check.finish"):
+        if hub is not None:
+            obs_telemetry.run_end(
+                ok=res.ok, distinct=res.distinct,
+                generated=res.generated, depth=res.depth,
+            )
+        summary = summarize(res, chk, time.monotonic() - t0, hub=hub)
     summary["_res"] = res
     summary["_chk"] = chk
     summary["_sanitizer"] = sanitizer

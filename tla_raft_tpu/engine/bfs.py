@@ -1654,79 +1654,81 @@ class JaxChecker:
         the per-level machinery."""
         from .forecast import forecast_new_states
 
-        ss = graft_superstep
-        # span: the EFFECTIVE level bound this window may cover (the
-        # --max-depth clamp) — a traced operand of the program, so one
-        # compiled span-N driver serves every remainder
-        span = self._superstep_span_at(max_depth, depth)
-        cap_cur = frontier.voted_for.shape[0]
-        fut = forecast_new_states(level_sizes, max_depth)[:span]
-        # shape statics always use the CONFIGURED span (the clamped
-        # span is only the traced lvl_cap operand below), so a
-        # --max-depth remainder window reuses the span-N program AND
-        # the prewarmed ("sstep", ...) ring/cap_f rungs instead of
-        # minting a one-off smaller-ring compile
-        cap_f, ring = self._superstep_shapes(
-            fut, self.superstep_span, n_f, cap_cur
-        )
-        # slab headroom for the WHOLE span: a superstep inserts up to
-        # the sum of its levels' new states before the host can grow
-        # the store, so the between-superstep reserve must budget the
-        # span's forecast inserts (margined like the ring rungs), not
-        # one level's — otherwise every growing span stops on a probe-
-        # window fill and replays per-level, eroding the amortization.
-        # reserve() grows to FIT (a single doubling can be short of a
-        # 4-level span on a >2x-growth run).
-        if fut:
-            m = graft_forecast.cap_margin()
-            ins_bound = sum(
-                min(int(f * m) + 1, cap_f) for f in fut
+        with graft_obs.span("raft.superstep.prepare"):
+            ss = graft_superstep
+            # span: the EFFECTIVE level bound this window may cover (the
+            # --max-depth clamp) — a traced operand of the program, so one
+            # compiled span-N driver serves every remainder
+            span = self._superstep_span_at(max_depth, depth)
+            cap_cur = frontier.voted_for.shape[0]
+            fut = forecast_new_states(level_sizes, max_depth)[:span]
+            # shape statics always use the CONFIGURED span (the clamped
+            # span is only the traced lvl_cap operand below), so a
+            # --max-depth remainder window reuses the span-N program AND
+            # the prewarmed ("sstep", ...) ring/cap_f rungs instead of
+            # minting a one-off smaller-ring compile
+            cap_f, ring = self._superstep_shapes(
+                fut, self.superstep_span, n_f, cap_cur
             )
-        else:
-            ins_bound = 2 * max(n_f, 1)
-        try:
-            # budget-clamped under the tiered store: a span whose
-            # forecast inserts exceed the device budget stops on
-            # FLAG_OVF_SLAB and the stop handler demotes
-            self._tier_reserve(
-                self.hstore.count + max(ins_bound, 2 * max(n_f, 1))
+            # slab headroom for the WHOLE span: a superstep inserts up to
+            # the sum of its levels' new states before the host can grow
+            # the store, so the between-superstep reserve must budget the
+            # span's forecast inserts (margined like the ring rungs), not
+            # one level's — otherwise every growing span stops on a probe-
+            # window fill and replays per-level, eroding the amortization.
+            # reserve() grows to FIT (a single doubling can be short of a
+            # 4-level span on a >2x-growth run).
+            if fut:
+                m = graft_forecast.cap_margin()
+                ins_bound = sum(
+                    min(int(f * m) + 1, cap_f) for f in fut
+                )
+            else:
+                ins_bound = 2 * max(n_f, 1)
+            try:
+                # budget-clamped under the tiered store: a span whose
+                # forecast inserts exceed the device budget stops on
+                # FLAG_OVF_SLAB and the stop handler demotes
+                self._tier_reserve(
+                    self.hstore.count + max(ins_bound, 2 * max(n_f, 1))
+                )
+            except Exception as e:  # graftlint: waive[GL003] — grow
+                # failure degrades to the sort path like every other
+                # grow site; the caller redoes the level staged
+                self._degraded_visited = self._degrade_hashstore(e)
+                return dict(degraded=True, frontier=frontier)
+            prog = ss.superstep_program_for(
+                self, self.superstep_span, self._mega_donate
             )
-        except Exception as e:  # graftlint: waive[GL003] — grow
-            # failure degrades to the sort path like every other
-            # grow site; the caller redoes the level staged
-            self._degraded_visited = self._degrade_hashstore(e)
-            return dict(degraded=True, frontier=frontier)
-        prog = ss.superstep_program_for(
-            self, self.superstep_span, self._mega_donate
-        )
-        # cap_cur (the input frontier's capacity) is part of the traced
-        # shape via the in-program padding — a changed input rung is a
-        # declared shape event like every other capacity step
-        sieve_dev = self._sieve_operand()
-        skey = (cap_cur, cap_f, ring, self.hstore.cap,
-                self.cap_x, self.cap_m, int(sieve_dev.shape[0]))
-        if graft_sanitize.tracking() and skey != self._ss_sig:
-            graft_sanitize.note_shape_event(f"superstep shapes {skey}")
-            self._ss_sig = skey
-        graft_sanitize.superstep_begin()
-        # live-HBM gauge: the trace-spool ring (fps u64 + pidx u32 +
-        # slot u16/u32 per entry) is the superstep's one extra
-        # long-lived buffer
-        graft_obs.buffer(
-            "ring", ring * (12 + (2 if self.K <= 0xFFFF else 4))
-        )
-        n_f_dev = jnp.asarray(n_f, I64)
-        span_dev = jnp.asarray(span, I64)
-        # device-cost observatory (see the megakernel site)
-        graft_devprof.profile_program(
-            "superstep.levels", prog,
-            frontier, self.hstore.slab, n_f_dev, span_dev, sieve_dev,
-            statics=dict(cap_f=cap_f, ring=ring),
-        )
-        outs = prog(
-            frontier, self.hstore.slab, n_f_dev, span_dev, sieve_dev,
-            cap_f=cap_f, ring=ring,
-        )
+            # cap_cur (the input frontier's capacity) is part of the traced
+            # shape via the in-program padding — a changed input rung is a
+            # declared shape event like every other capacity step
+            sieve_dev = self._sieve_operand()
+            skey = (cap_cur, cap_f, ring, self.hstore.cap,
+                    self.cap_x, self.cap_m, int(sieve_dev.shape[0]))
+            if graft_sanitize.tracking() and skey != self._ss_sig:
+                graft_sanitize.note_shape_event(f"superstep shapes {skey}")
+                self._ss_sig = skey
+            graft_sanitize.superstep_begin()
+            # live-HBM gauge: the trace-spool ring (fps u64 + pidx u32 +
+            # slot u16/u32 per entry) is the superstep's one extra
+            # long-lived buffer
+            graft_obs.buffer(
+                "ring", ring * (12 + (2 if self.K <= 0xFFFF else 4))
+            )
+            n_f_dev = jnp.asarray(n_f, I64)
+            span_dev = jnp.asarray(span, I64)
+            # device-cost observatory (see the megakernel site)
+            graft_devprof.profile_program(
+                "superstep.levels", prog,
+                frontier, self.hstore.slab, n_f_dev, span_dev, sieve_dev,
+                statics=dict(cap_f=cap_f, ring=ring),
+            )
+        with graft_obs.span("raft.superstep.dispatch"):
+            outs = prog(
+                frontier, self.hstore.slab, n_f_dev, span_dev, sieve_dev,
+                cap_f=cap_f, ring=ring,
+            )
         (fr_out, slab_out, ctrl_d, mn_d, mm_d, rf_d, rp_d,
          rs_d) = outs
         graft_sanitize.note_dispatch("superstep.levels")
@@ -1739,16 +1741,17 @@ class JaxChecker:
             self.pipeline, (ctrl_d, mn_d, mm_d, rf_d, rp_d, rs_d)
         )
         ctrl, mn, mm, rf, rp, rs = tail.get()
-        recs, reason, n_f_out, slab_live, flags = ss.unpack_ring(
-            ctrl, mn, mm, rf, rp, rs
-        )
-        graft_sanitize.superstep_tick(len(recs))
-        self._ss_stats["supersteps"] += 1
-        self._ss_stats["levels"] += len(recs)
-        if reason == "stop":
-            self._ss_stats["stops"] += 1
-        elif reason == "ring":
-            self._ss_stats["ring_stops"] += 1
+        with graft_obs.span("raft.superstep.unpack"):
+            recs, reason, n_f_out, slab_live, flags = ss.unpack_ring(
+                ctrl, mn, mm, rf, rp, rs
+            )
+            graft_sanitize.superstep_tick(len(recs))
+            self._ss_stats["supersteps"] += 1
+            self._ss_stats["levels"] += len(recs)
+            if reason == "stop":
+                self._ss_stats["stops"] += 1
+            elif reason == "ring":
+                self._ss_stats["ring_stops"] += 1
         return dict(
             recs=recs,
             frontier=fr_out,
@@ -3979,6 +3982,9 @@ class JaxChecker:
         resume_from: str | None = None,
     ) -> CheckResult:
         self._audit_strikes = 0
+        # the run's phases (init, levels, finish) and a level's host
+        # plan or per-level path, as profiler spans: _run moves them on
+        self._phase_spans = graft_obs.SpanStack()
         try:
             return self._run(
                 max_depth=max_depth, checkpoint_dir=checkpoint_dir,
@@ -4004,6 +4010,7 @@ class JaxChecker:
                 # checker builds a fresh prewarmer via _submit_prewarm)
                 self._prewarmer.join(10.0)
                 self._prewarmer.shutdown()
+            self._phase_spans.pop_to(0)
 
     def _run(
         self,
@@ -4015,6 +4022,9 @@ class JaxChecker:
         cfg = self.cfg
         K = self.K
         t0 = time.monotonic()
+        spans = self._phase_spans
+        spans.pop_to(0)  # an audit rewind re-enters from the level loop
+        spans.push("raft.check.init")
 
         if checkpoint_dir and checkpoint_every:
             import glob as _glob
@@ -4234,7 +4244,11 @@ class JaxChecker:
         # level) must route its level through the per-level machinery
         # exactly once before supersteps re-engage
         skip_superstep = False
+        spans.pop_to(0)
+        spans.push("raft.check.levels")
         while n_f > 0:
+            # the previous level's per-level span ends here
+            spans.pop_to(1)
             resilience.fault_fire("level.start")
             if resilience.preempt_requested():
                 # every completed level's delta record is already
@@ -4347,94 +4361,95 @@ class JaxChecker:
                 frontier = sres["frontier"]
                 hit_fixpoint = False
                 depth0 = depth  # window entry, for the dump cadence
-                for li, rec in enumerate(sres["recs"]):
-                    if li:
-                        # the per-level crash sites keep their once-
-                        # per-level cadence (the while-loop top fired
-                        # for the superstep's first level)
-                        resilience.fault_fire("level.start")
-                    level_mult = rec["mult"]
-                    mult_per_slot = mult_per_slot + level_mult
-                    generated += int(level_mult.sum())
-                    if rec["n_new"] == 0:
-                        # the terminal fixpoint level: generated counts
-                        # (the staged loop breaks AFTER the mult add),
-                        # distinct/depth do not
-                        hit_fixpoint = True
-                        break
-                    n_new = rec["n_new"]
-                    distinct += n_new
-                    level_sizes.append(n_new)
-                    depth += 1
-                    trace_levels.append((rec["pidx"], rec["slot"]))
-                    n_f = n_new
-                    graft_obs.level_commit(
-                        depth, n_new, distinct, generated,
-                        slab_cap=(
-                            self.hstore.cap
-                            if self.use_hashstore and self.hstore
-                            is not None else 0
-                        ),
-                    )
-                    if self.progress is not None:
-                        self.progress(
-                            dict(
-                                level=depth,
-                                frontier=n_new,
-                                distinct=distinct,
-                                generated=generated,
-                                elapsed=time.monotonic() - t0,
+                with graft_obs.span("raft.level.commit"):
+                    for li, rec in enumerate(sres["recs"]):
+                        if li:
+                            # the per-level crash sites keep their once-
+                            # per-level cadence (the while-loop top fired
+                            # for the superstep's first level)
+                            resilience.fault_fire("level.start")
+                        level_mult = rec["mult"]
+                        mult_per_slot = mult_per_slot + level_mult
+                        generated += int(level_mult.sum())
+                        if rec["n_new"] == 0:
+                            # the terminal fixpoint level: generated counts
+                            # (the staged loop breaks AFTER the mult add),
+                            # distinct/depth do not
+                            hit_fixpoint = True
+                            break
+                        n_new = rec["n_new"]
+                        distinct += n_new
+                        level_sizes.append(n_new)
+                        depth += 1
+                        trace_levels.append((rec["pidx"], rec["slot"]))
+                        n_f = n_new
+                        graft_obs.level_commit(
+                            depth, n_new, distinct, generated,
+                            slab_cap=(
+                                self.hstore.cap
+                                if self.use_hashstore and self.hstore
+                                is not None else 0
+                            ),
+                        )
+                        if self.progress is not None:
+                            self.progress(
+                                dict(
+                                    level=depth,
+                                    frontier=n_new,
+                                    distinct=distinct,
+                                    generated=generated,
+                                    elapsed=time.monotonic() - t0,
+                                )
                             )
-                        )
-                    if graft_sanitize.tracking():
-                        sig = (
-                            sres["cap_f"], self.hstore.cap,
-                            sres["cap_f"], self.cap_x, self.cap_g,
-                            self.cap_m, self._san_lanes,
-                        )
-                        if sig != getattr(self, "_san_sig", None):
-                            graft_sanitize.note_shape_event(
-                                f"level shapes {sig}"
+                        if graft_sanitize.tracking():
+                            sig = (
+                                sres["cap_f"], self.hstore.cap,
+                                sres["cap_f"], self.cap_x, self.cap_g,
+                                self.cap_m, self._san_lanes,
                             )
-                            self._san_sig = sig
-                        graft_sanitize.level_tick()
-                    if checkpoint_dir and checkpoint_every:
-                        self._save_delta(
-                            checkpoint_dir, depth, rec["pidx"],
-                            rec["slot"], rec["fps"], level_mult, n_new,
+                            if sig != getattr(self, "_san_sig", None):
+                                graft_sanitize.note_shape_event(
+                                    f"level shapes {sig}"
+                                )
+                                self._san_sig = sig
+                            graft_sanitize.level_tick()
+                        if checkpoint_dir and checkpoint_every:
+                            self._save_delta(
+                                checkpoint_dir, depth, rec["pidx"],
+                                rec["slot"], rec["fps"], level_mult, n_new,
+                            )
+                    if sres["n_total"] or hit_fixpoint:
+                        # adopt the committed prefix's slab in one step
+                        self.hstore.adopt(sres["slab"], sres["n_total"])
+                        # free conservation check: the driver counted the
+                        # returned slab's live slots — they must equal the
+                        # distinct set after the committed prefix (or, once
+                        # generations exist, the HOT-tier count: the slab
+                        # holds only the post-demotion residue, and every
+                        # committed level was sieve-clean so its fresh
+                        # count is insert-exact)
+                        resilience.integrity.occupancy_check(
+                            "device hash slab", sres["slab_live"],
+                            self.hstore.count if self._tier_active()
+                            else distinct,
+                            level=depth,
                         )
-                if sres["n_total"] or hit_fixpoint:
-                    # adopt the committed prefix's slab in one step
-                    self.hstore.adopt(sres["slab"], sres["n_total"])
-                    # free conservation check: the driver counted the
-                    # returned slab's live slots — they must equal the
-                    # distinct set after the committed prefix (or, once
-                    # generations exist, the HOT-tier count: the slab
-                    # holds only the post-demotion residue, and every
-                    # committed level was sieve-clean so its fresh
-                    # count is insert-exact)
-                    resilience.integrity.occupancy_check(
-                        "device hash slab", sres["slab_live"],
-                        self.hstore.count if self._tier_active()
-                        else distinct,
-                        level=depth,
-                    )
-                if checkpoint_dir and checkpoint_every and sres["recs"]:
-                    dump_every = hashstore.dump_interval(
-                        self.hstore.cap * 8
-                    ) if self.use_hashstore else 0
-                    # floor-crossing, not ==: the window advanced depth
-                    # by up to span levels, and any cadence point it
-                    # crossed earns the (one, end-of-window) dump —
-                    # keeping the per-level path's snapshot cadence
-                    if (self.use_hashstore and dump_every
-                            and (depth // dump_every)
-                            > (depth0 // dump_every)):
-                        self.hstore.dump(
-                            os.path.join(checkpoint_dir, "hslab.npz"),
-                            depth, int(self.orbit),
-                            run_fp=self._run_fp,
-                        )
+                    if checkpoint_dir and checkpoint_every and sres["recs"]:
+                        dump_every = hashstore.dump_interval(
+                            self.hstore.cap * 8
+                        ) if self.use_hashstore else 0
+                        # floor-crossing, not ==: the window advanced depth
+                        # by up to span levels, and any cadence point it
+                        # crossed earns the (one, end-of-window) dump —
+                        # keeping the per-level path's snapshot cadence
+                        if (self.use_hashstore and dump_every
+                                and (depth // dump_every)
+                                > (depth0 // dump_every)):
+                            self.hstore.dump(
+                                os.path.join(checkpoint_dir, "hslab.npz"),
+                                depth, int(self.orbit),
+                                run_fp=self._run_fp,
+                            )
                 if hit_fixpoint:
                     if self.watchdog is not None:
                         self.watchdog.disarm(levels=len(sres["recs"]))
@@ -4546,6 +4561,7 @@ class JaxChecker:
             # ledgered fetch per level (engine/megakernel.py); every
             # overflow redoes inside, a mid-level hash-store
             # degradation falls through to the staged path below -----
+            spans.push("raft.level.single")
             mres = None
             if self._mega_segs_ok(frontier, n_f):
                 # spilled frontier: stream the parent through the fused
@@ -4645,6 +4661,8 @@ class JaxChecker:
                     self.cap_g *= 2
                     graft_obs.grow("cap_g", self.cap_g)
             if abort_at < n_f:
+                spans.pop_to(0)
+                spans.push("raft.check.finish")
                 # action_counts stays None on violations, like the oracle:
                 # coverage of a partially-expanded level is ill-defined
                 return CheckResult(
@@ -4965,6 +4983,8 @@ class JaxChecker:
                     bad_tree = jax.tree.map(
                         lambda x: x[bad_idx : bad_idx + 1], frontier
                     )
+                spans.pop_to(0)
+                spans.push("raft.check.finish")
                 one = self._inflate(bad_tree)
                 name = self._bad_invariant_name(one, 0)
                 return CheckResult(
@@ -5065,6 +5085,8 @@ class JaxChecker:
             if self.watchdog is not None:
                 self.watchdog.disarm()
 
+        spans.pop_to(0)
+        spans.push("raft.check.finish")
         return CheckResult(
             True, distinct, generated, depth, tuple(level_sizes), None,
             self._action_counts(mult_per_slot),

@@ -115,9 +115,11 @@ def fetch(tree):
     from ..obs import telemetry as _obs
 
     t0 = _time.monotonic() if _obs.current() is not None else 0.0
-    # graftlint: waive[GL006] — THE intended sync point of the async
-    # pipeline: every window fetch funnels through this one site
-    out = jax.device_get(tree)
+    with _obs.span("raft.fetch"):
+        # THE intended sync point of the async pipeline: every window
+        # fetch funnels through this site
+        # graftlint: waive[GL006]
+        out = jax.device_get(tree)
     if _obs.current() is not None:
         _obs.fetch_done(
             _time.monotonic() - t0, graft_sanitize._nbytes(out)

@@ -75,6 +75,14 @@ Rotation: the stream is capped at ``TLA_RAFT_TELEMETRY_BYTES``
 (default 64 MiB, 0 = unbounded); past the cap the file rotates to
 ``events.1.jsonl`` (older generations shift up) at level/superstep
 boundaries, and :func:`read_events` follows the chain oldest-first.
+
+Spans (:func:`span`, :class:`SpanStack`): named host intervals of the
+check path, recorded as profiler ``TraceMe`` events so a profiler
+capture holds them beside the device ops, on one clock.  They write no
+events and feed no aggregate: the profiler trace is their only sink.
+The annotator that records them is injected
+(``analysis/sanitize.obs_watch_compiles``), which keeps this module
+jax-free.  Every name is in :data:`SPANS`.
 """
 
 from __future__ import annotations
@@ -95,6 +103,75 @@ EVENTS_NAME = "events.jsonl"
 DEFAULT_MAX_BYTES = 64 << 20
 
 CURRENT: "TelemetryHub | None" = None
+
+# the span catalogue (docs/OBSERVABILITY.md "Spans"), outermost first
+SPANS = (
+    "raft.check",                # one run_check call
+    "raft.check.construct",      # JaxChecker construction
+    "raft.check.init",           # init state, invariant, slab set-up
+    "raft.check.levels",         # the level loop
+    "raft.superstep.prepare",    # forecast, reserve, program, operands
+    "raft.superstep.dispatch",   # handing superstep_body to the device
+    "raft.superstep.unpack",     # host unpack of the ring
+    "raft.level.commit",         # host replay of a superstep's levels
+    "raft.level.single",         # a level the superstep did not run
+    "raft.fetch",                # the ledgered fetch (pipeline.fetch)
+    # action counts, trace, run_end, summary; two sites, the engine's
+    # tail (bfs.py) then the summary (check.py)
+    "raft.check.finish",
+)
+
+
+class _NoSpan:
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+_NO_SPAN = _NoSpan()
+
+
+def _no_annotator(name: str):
+    return _NO_SPAN
+
+
+# name -> context manager recording one profiler span (set_annotator)
+_ANNOTATOR = _no_annotator
+
+
+def set_annotator(annotator) -> None:
+    """Install the span recorder (``jax.profiler.TraceAnnotation``)."""
+    global _ANNOTATOR
+    _ANNOTATOR = annotator
+
+
+def span(name: str):
+    """A context manager timing ``name`` (one of :data:`SPANS`) as a
+    profiler span; the shared no-op while no hub is installed."""
+    if CURRENT is None:
+        return _NO_SPAN
+    return _ANNOTATOR(name)
+
+
+class SpanStack:
+    """Spans opened and closed at points no ``with`` block spans: a
+    phase that ends past a long loop, or in a loop body's tail.
+    ``push`` opens a span inside those open; ``pop_to(n)`` closes all
+    but the outer ``n``.  One thread only, like the spans themselves."""
+
+    def __init__(self):
+        self._open: list = []
+
+    def push(self, name: str) -> None:
+        s = span(name)
+        s.__enter__()
+        self._open.append(s)
+
+    def pop_to(self, depth: int = 0) -> None:
+        while len(self._open) > depth:
+            self._open.pop().__exit__(None, None, None)
 
 
 def enabled_by_env() -> bool:
@@ -309,8 +386,8 @@ def _last_event_t(path: str, tail_bytes: int = 1 << 16) -> float | None:
 class TelemetryHub:
     """One run's flight recorder + host-side aggregate accounting.
 
-    ``run_dir=None`` keeps the stream in memory only (the aggregates —
-    the ``--json`` ``telemetry`` block — still work); with a run dir
+    ``run_dir=None`` keeps no stream, only the aggregates (the
+    ``--json`` ``telemetry`` block); with a run dir
     the stream appends to ``<run_dir>/events.jsonl``.  Usable as a
     context manager: installs itself as the process hub on enter,
     uninstalls + flushes on exit.
@@ -490,6 +567,12 @@ class TelemetryHub:
         doc = {"t": t, "ev": ev}
         for k, v in fields.items():
             doc[k] = _clean(v)
+        if self.path is None:
+            # in-memory hub: nothing would ever read an encoded line
+            with self._lock:
+                self.n_events += 1
+                self._aggregate(ev, t, doc)
+            return
         line = encode_event(doc) + "\n"
         with self._lock:
             self._buf.append(line)
