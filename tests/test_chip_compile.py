@@ -122,7 +122,7 @@ def test_megakernel_level_depth19(engine, one_chip, on_tpu):
     from tla_raft_tpu.engine import megakernel
 
     eng, fr0 = engine
-    prog = megakernel.build_level_program(eng, donate=True)
+    prog = megakernel.build_level_program(eng.level_spec(), donate=True)
     compiled = prog.lower(
         _frontier(fr0, 1_048_576, one_chip),
         _sds((SLAB_ROWS,), jnp.uint64, one_chip),
@@ -143,7 +143,9 @@ def test_superstep_depth19(engine, one_chip, on_tpu):
     eng.cap_x = 49152
     eng._jit_expand_programs()
     try:
-        prog = superstep.build_superstep_program(eng, 4, donate=True)
+        prog = superstep.build_superstep_program(
+            eng.level_spec(), 4, donate=True
+        )
         compiled = prog.lower(
             _frontier(fr0, 393_216, one_chip),
             _sds((SLAB_ROWS,), jnp.uint64, one_chip),

@@ -308,6 +308,20 @@ def test_sanitize_smoke_one_dispatch_one_fetch(tmp_path):
     ), proc.stdout
 
 
+def test_summary_reports_program_cache_counters():
+    """A check after a same-config check in one process builds no
+    program: the summary's program-cache counters show only hits, for
+    the superstep driver and the per-level program alike."""
+    from tla_raft_tpu.check import run_check, summary_public
+
+    run_check(S2, chunk=64, telemetry=False)
+    s = summary_public(run_check(S2, chunk=64, telemetry=False))
+    assert s["distinct"] == 50
+    for block in ("superstep_stats", "megakernel_stats"):
+        assert s[block]["program_builds"] == 0, s[block]
+        assert s[block]["program_hits"] >= 1, s[block]
+
+
 def test_dispatch_log_counts_fused_levels():
     """The choke-point dispatch ledger (GL011's measurement) sees the
     fused path as exactly one program per level."""

@@ -131,6 +131,150 @@ def test_dispatch_log_superstep_amortization():
     assert sum(log.superstep_levels) == 13
 
 
+# -- shared program caches: one program per LevelSpec, no engine pinned --
+
+# the two fused paths and their cache/stats: superstep=1 runs every
+# level through the per-level megakernel program, superstep=4 through
+# the resident driver; both share fused_level_core and the cache scheme
+FUSED_PATHS = [
+    pytest.param(1, "_mega_stats", id="megakernel"),
+    pytest.param(4, "_ss_stats", id="superstep"),
+]
+
+
+def _path_cache(span):
+    from tla_raft_tpu.engine import megakernel
+
+    return megakernel._PROG_CACHE if span == 1 else superstep_mod._PROG_CACHE
+
+
+@pytest.fixture
+def empty_caches():
+    from tla_raft_tpu.engine import megakernel
+
+    megakernel._PROG_CACHE.clear()
+    superstep_mod._PROG_CACHE.clear()
+    yield
+    megakernel._PROG_CACHE.clear()
+    superstep_mod._PROG_CACHE.clear()
+
+
+@pytest.fixture
+def traced_cap_x(monkeypatch):
+    """The ``cap_x`` of every trace of the fused level body, in order."""
+    from tla_raft_tpu.engine import megakernel
+
+    seen = []
+    real = megakernel.fused_level_core
+
+    def spy(spec, *args, **kw):
+        seen.append(spec.cap_x)
+        return real(spec, *args, **kw)
+
+    monkeypatch.setattr(megakernel, "fused_level_core", spy)
+    return seen
+
+
+@pytest.mark.parametrize("span,stats", FUSED_PATHS)
+def test_program_shared_across_checkers(span, stats, empty_caches,
+                                        traced_cap_x):
+    """Two checkers on one config, back to back: the second finds the
+    first's program and re-traces none of its shape rungs, though the
+    first grew cap_x during its check (the benchmark's back-to-back
+    checks grow 4096 -> 6144)."""
+    from bench import GOLDEN_LEVELS
+
+    ref = RaftConfig(n_servers=3, n_vals=2, max_election=3, max_restart=3)
+    golden = GOLDEN_LEVELS[(3, 2, 3, 3)][:7]
+    a = JaxChecker(ref, chunk=64, cap_x=64, superstep=span)
+    assert list(a.run(max_depth=6).level_sizes) == golden
+    assert a.cap_x > 64
+    assert getattr(a, stats)["program_builds"] >= 1
+    cache = _path_cache(span)
+    progs = dict(cache)
+    rungs = {k: p._cache_size() for k, p in progs.items()}
+    n_traces = len(traced_cap_x)
+    assert n_traces > 0
+
+    b = JaxChecker(ref, chunk=64, cap_x=64, superstep=span)
+    assert list(b.run(max_depth=6).level_sizes) == golden
+    assert getattr(b, stats)["program_builds"] == 0
+    assert getattr(b, stats)["program_hits"] >= 1
+    assert len(traced_cap_x) == n_traces  # no trace of the level body
+    assert dict(cache) == progs
+    assert {k: p._cache_size() for k, p in cache.items()} == rungs
+
+
+@pytest.mark.parametrize("span,stats", FUSED_PATHS)
+def test_grown_cap_x_gets_its_own_program(span, stats, empty_caches):
+    chk = JaxChecker(S2, chunk=64, cap_x=16, superstep=span)
+    res = chk.run()
+    assert (res.distinct, res.depth) == (50, 12)
+    assert chk.cap_x > 16
+    caps = {key[0].cap_x for key in _path_cache(span)}
+    # the overflowing budget's program and the grown one's, side by side
+    assert {16, chk.cap_x} <= caps
+    assert getattr(chk, stats)["program_builds"] >= 2
+
+
+@pytest.mark.parametrize("span,stats", FUSED_PATHS)
+def test_rung_traced_after_growth_keeps_its_budgets(span, stats,
+                                                    empty_caches,
+                                                    traced_cap_x):
+    """A program's creator grows cap_x; a rung of the creator's old
+    program traced afterwards still reads the old budgets, and a second
+    checker at the old budgets reuses that program and lands the exact
+    counts."""
+    import jax
+    import jax.numpy as jnp
+
+    from tla_raft_tpu.models.raft import init_batch
+
+    grower = JaxChecker(S2, chunk=64, cap_x=16, superstep=span)
+    old_spec = grower.level_spec()
+    grower.run()
+    assert grower.cap_x > 16  # the creator moved on, and stays alive
+    prog = next(p for k, p in _path_cache(span).items() if k[0] == old_spec)
+    fr0, _ovf = jax.jit(grower._deflate)(init_batch(S2, 1))
+    cap = 1 << 10  # a frontier rung the S2 run never reaches
+    fr = grower._frontier_struct(fr0, cap)
+    slab = jax.ShapeDtypeStruct((hashstore.MIN_CAP,), jnp.uint64)
+    s_i64 = jax.ShapeDtypeStruct((), jnp.int64)
+    sieve = jax.ShapeDtypeStruct((1,), jnp.uint64)
+    del traced_cap_x[:]
+    if span == 1:
+        prog.lower(fr, slab, s_i64, sieve, cap_out=cap)
+    else:
+        prog.lower(fr, slab, s_i64, s_i64, sieve, cap_f=cap, ring=2 * cap)
+    assert traced_cap_x and set(traced_cap_x) == {16}
+
+    again = JaxChecker(S2, chunk=64, cap_x=16, superstep=span)
+    res = again.run()
+    assert (res.distinct, res.depth) == (50, 12)
+    assert getattr(again, stats)["program_hits"] >= 1
+
+
+@pytest.mark.parametrize("span,stats", FUSED_PATHS)
+def test_program_cache_pins_no_engine(span, stats, empty_caches):
+    import gc
+    import weakref
+
+    chk = JaxChecker(S2, chunk=64, superstep=span)
+    assert chk.run().distinct == 50
+    assert getattr(chk, stats)["program_builds"] >= 1
+    assert _path_cache(span)  # the program outlives its check ...
+    gc.collect()
+    ref = weakref.ref(chk)
+    gc.disable()
+    try:
+        # ... and the checker does not: no cache and no cycle holds it,
+        # so its device hash slab goes with its last reference
+        del chk
+        assert ref() is None
+    finally:
+        gc.enable()
+
+
 # -- watchdog: the N-level budget math ------------------------------------
 
 def test_watchdog_superstep_budget_math():

@@ -96,7 +96,9 @@ def compiled_registry():
                          megakernel=True)
         fr0, _ovf = eng._deflate(init_batch(cfg, 1))
         fr = eng._frontier_struct(fr0, 64)
-        prog = megakernel_mod.build_level_program(eng, donate=False)
+        prog = megakernel_mod.build_level_program(
+            eng.level_spec(), donate=False
+        )
         return prog.lower(
             fr, jax.ShapeDtypeStruct((hashstore.MIN_CAP,), jnp.uint64),
             jax.ShapeDtypeStruct((), jnp.int64),
@@ -109,7 +111,7 @@ def compiled_registry():
         fr0, _ovf = eng._deflate(init_batch(cfg, 1))
         fr = eng._frontier_struct(fr0, 64)
         prog = superstep_mod.build_superstep_program(
-            eng, span=2, donate=False
+            eng.level_spec(), span=2, donate=False
         )
         s_i64 = jax.ShapeDtypeStruct((), jnp.int64)
         return prog.lower(

@@ -276,15 +276,6 @@ def summarize(res, chk=None, seconds: float | None = None,
             cold_load_s=round(tiered.stats["cold_load_s"], 6),
         )
         out["sieve"] = bool(getattr(chk, "sieve_enabled", False))
-        # superstep window accounting (supersteps/levels/stop causes):
-        # under spill this is the span-survival evidence — sieve-clean
-        # windows keep committing levels, sieve_stops count the exact
-        # per-level corrections (ops/sieve.py)
-        ss = getattr(chk, "_ss_stats", None)
-        if ss and ss.get("supersteps"):
-            out["superstep_stats"] = {
-                k: int(v) for k, v in sorted(ss.items())
-            }
         # spilled-frontier paging (store/tiered.py FrontierPager)
         fpager = getattr(chk, "_fpager", None)
         if fpager is not None and fpager.stats["fseg_spills"]:
@@ -292,6 +283,23 @@ def summarize(res, chk=None, seconds: float | None = None,
                 fpager.stats,
                 fseg_load_s=round(fpager.stats["fseg_load_s"], 6),
             )
+    # superstep window accounting (supersteps/levels/stop causes): under
+    # spill this is the span-survival evidence — sieve-clean windows keep
+    # committing levels, sieve_stops count the exact per-level
+    # corrections (ops/sieve.py).  program_hits/program_builds count this
+    # check's lookups in the shared program caches (a check after a
+    # same-config one in the process builds nothing), for the superstep
+    # driver here and the per-level megakernel in megakernel_stats
+    ss = getattr(chk, "_ss_stats", None)
+    if ss and ss.get("supersteps"):
+        out["superstep_stats"] = {
+            k: int(v) for k, v in sorted(ss.items())
+        }
+    mega = getattr(chk, "_mega_stats", None)
+    if mega and getattr(chk, "megakernel", False):
+        out["megakernel_stats"] = {
+            k: int(v) for k, v in sorted(mega.items())
+        }
     # adaptive sieve governor (tune/adaptive.py): present whenever the
     # measured arm/stand-down policy saw a window or flipped state —
     # the BENCH_SIEVE_AB record's evidence that the policy engaged

@@ -54,7 +54,9 @@ dtyped (u8 state, u64 fingerprints, i64 payloads).
 
 from __future__ import annotations
 
+import dataclasses
 import functools
+import inspect
 import os
 import sys
 import time
@@ -263,6 +265,27 @@ def _pad_axis0(x: jnp.ndarray, cap: int) -> jnp.ndarray:
     return jnp.concatenate([x, jnp.zeros((pad, *x.shape[1:]), x.dtype)])
 
 
+def _weak_method(method):
+    """``method`` as a plain function that holds its instance weakly.
+
+    A checker keeps its jitted programs as attributes; a jitted bound
+    method would be a reference cycle (checker -> program -> method ->
+    checker), so a finished checker, and the device hash slab it holds,
+    would outlive its last user until the cyclic collector happened to
+    run — the next checks of a process would then run beside dead
+    slabs in HBM.  Name and signature follow ``method`` (jit's static
+    argument names and program names read them)."""
+    ref = weakref.WeakMethod(method)
+
+    def call(*args, **kwargs):
+        return ref()(*args, **kwargs)
+
+    call.__name__ = method.__name__
+    call.__qualname__ = method.__qualname__
+    call.__signature__ = inspect.signature(method)
+    return call
+
+
 
 
 @functools.partial(jax.jit, static_argnames=("cap_x",))
@@ -459,345 +482,16 @@ def _merge_sorted(visited, new_fps):
 # ignores a donation would hold two destination copies).
 
 
-class JaxChecker:
-    """The TPU model checker for one RaftConfig.
+class LevelOps:
+    """The level's traced building blocks: message-set conversion, the
+    chunk expand, the materialize slice and the invariant scan.
 
-    Parameters:
-      chunk: max parents expanded per kernel launch (memory knob; the
-        per-launch working set is ~chunk * K * (F + hash) bytes).
-      cap_x: per-chunk compacted-survivor lanes (grows on overflow).
-      progress: optional callable(level_stats_dict) for per-level logging.
+    Shared by ``JaxChecker`` and its frozen ``LevelSpec`` snapshot, so
+    the fused level programs trace the same code as the staged path
+    while closing over no engine.  They read only ``cfg``, ``kern``,
+    ``fpr``, ``K``, ``uni_words``, ``id_dtype``, ``cap_m``, ``cap_x``,
+    ``canon``, ``orbit`` and ``inv_fns``.
     """
-
-    def __init__(
-        self,
-        cfg: RaftConfig,
-        chunk: int = 1024,
-        cap_x: int | None = None,
-        progress: Callable[[dict], None] | None = None,
-        host_store=None,
-        cap_m: int = 96,
-        canon: str = "late",
-        use_hashstore: bool | None = None,
-        pipeline: bool | None = None,
-        pipeline_window: int | None = None,
-        prewarm: bool | None = None,
-        use_mxu: bool | None = None,
-        megakernel: bool | None = None,
-        superstep: int | None = None,
-        audit: int = 0,
-        audit_retries: int = 3,
-        watchdog=None,
-        store_bytes: int | None = None,
-        warm_bytes: int | None = None,
-        sieve: bool | None = None,
-    ):
-        # canon="late": expand computes guards only; the compacted
-        # candidates are materialized and fingerprinted with the full-state
-        # path — the P-wide symmetry fold runs over ~3.5 candidates/state
-        # instead of all K fan-out lanes (the enabler for big symmetry
-        # groups, and faster even at S=3).  canon="expand": fold the
-        # symmetry hash into every fan-out lane (the round-2 formulation,
-        # kept as a differential reference).
-        assert canon in ("late", "expand")
-        self.canon = canon
-        self.cfg = cfg
-        # MXU-native expand (ops/mxu_expand.py): guards as the coefficient
-        # matmul, materialize as gather-free select-matrix products.
-        # Default ON; TLA_RAFT_MXU=0 / --no-mxu-expand / use_mxu=False
-        # reverts to the legacy per-lane kernels — counts are
-        # bit-identical either way (the MXU parity suite diffs the two).
-        self.kern: SuccessorKernel = get_kernel(cfg, mxu=use_mxu)
-        self.use_mxu = self.kern.use_mxu
-        self.fpr = self.kern.fpr
-        self.K = self.kern.K
-        self.uni_words = self.kern.uni.n_words
-        # sparse-frontier width: max message-set size per reachable state
-        # (grows ~1/level, saturating near a structural bound — 96 on the
-        # reference family; overflow auto-grows it and re-materializes
-        # the level, see _materialize_grow).  TLA_RAFT_CAP_M overrides —
-        # deep sweeps start with headroom so growth never fires after
-        # parent segments are released.
-        env_capm = os.environ.get("TLA_RAFT_CAP_M")
-        if env_capm is not None and cap_m == 96:
-            # env overrides only the DEFAULT: a caller passing an explicit
-            # cap_m (tests bounding HBM, the growth suite) keeps it
-            cap_m = int(env_capm)
-        self.cap_m = min(cap_m, self.kern.uni.M)
-        self.id_dtype = jnp.int16 if self.kern.uni.M < (1 << 15) else jnp.int32
-        if chunk & (chunk - 1):
-            # power-of-two capacities divide evenly into the pow4-padded
-            # materialize buffer; arbitrary chunks would mis-slice it
-            raise ValueError(f"chunk must be a power of two, got {chunk}")
-        self.chunk = chunk
-        # a chunk's valid fan-out lanes average ~3.5 per parent on the
-        # reference config, so chunk*4 covers the mean and overflow
-        # detection grows the budget (with a re-jit) on skewed chunks
-        self.cap_x = cap_x or 4 * chunk
-        # chunks per visited-filter group, and the per-group post-filter
-        # survivor budget (deep levels see <=50% fresh candidates;
-        # overflow grows cap_g like cap_x)
-        self.G = 16
-        self.cap_g = self.G * self.cap_x // 2
-        # chunks dispatched between queue-draining scalar fetches
-        env_sync = os.environ.get("TLA_RAFT_SYNC_EVERY")
-        self.sync_every = max(1, int(env_sync)) if env_sync else 8
-        self.progress = progress
-        # optional native external-memory visited store (native/fpstore.cpp);
-        # when set, the device keeps no visited table at all — the level's
-        # deduped candidates are filtered through the host store instead
-        self.host_store = host_store
-        # device-resident open-addressing visited store (ops/hashstore.py):
-        # replaces the level's 3-key lexsort + searchsorted + sorted store
-        # merge with one fused O(1) probe-and-insert on the device-store
-        # path.  Default ON; TLA_RAFT_HASHSTORE=0 (or --no-hashstore /
-        # use_hashstore=False) reverts to the sort-based path.  Moot when
-        # an external host store is attached (membership lives host-side).
-        if use_hashstore is None:
-            use_hashstore = hashstore.enabled_by_env()
-        self.use_hashstore = bool(use_hashstore) and host_store is None
-        self.hstore = None  # DeviceHashStore, built in run()/resume
-        self._hs_pending = None  # a level's updated slab awaiting adoption
-        # tiered visited store (store/tiered.py): a device-byte budget
-        # for the hot slab; growth past it DEMOTES a whole generation
-        # to host RAM / disk instead of growing (or dying), and the
-        # level tail probes the demoted runs host-side, dropping their
-        # revisits from the fresh set — |visited| becomes
-        # storage-bounded, TLC's disk FPSet move.  0 = off (the
-        # hot-only engine, bit-identical counts either way).
-        if store_bytes is None:
-            store_bytes = graft_tiered.store_bytes_from_env()
-        self.store_bytes = int(store_bytes)
-        self.warm_bytes = warm_bytes  # None = TLA_RAFT_WARM_BYTES
-        self.tiered = None  # TieredVisitedStore, built in run()/resume
-        # device-resident spill sieve (ops/sieve.py): a blocked bloom
-        # over every demoted fingerprint, probed INSIDE the fused
-        # megakernel/superstep body — a level with zero sieve hits
-        # provably has no spilled revisits and commits in-window, which
-        # restores span-N supersteps under spill (the PR 12 stand-down
-        # becomes the sieve-off fallback).  Default ON wherever tiering
-        # is; TLA_RAFT_SIEVE=0 / sieve=False reverts to span-1.
-        # the arm decision is governed at RUNTIME when TLA_RAFT_SIEVE
-        # is unset: recent sieve-dirty windows stand the span down
-        # (the replay tax never amortizes — BENCH_SIEVE_AB_r20's ~14%),
-        # a probation of per-level progress re-arms it.  =0 / =1 (or an
-        # explicit argument) still force either mode unconditionally.
-        self.sieve_governor = graft_adaptive.SieveGovernor(
-            graft_adaptive.mode_from_env(sieve)
-        )
-        self.sieve_enabled = self.sieve_governor.mode != "off"
-        self._dev_sieve = None      # device u64[M] copy of the filter
-        self._dev_sieve_ver = -1    # host filter version it mirrors
-        self._dev_sieve_empty = None  # the 1-word all-miss sentinel
-        # device-byte budget for frontier segments (0 = paging off): when
-        # one level's parent+child segments would exceed it, sealed child
-        # segments demote to host RAM and page back in on demand — the
-        # tier that breaks the single-frontier-in-HBM wall at level 29 of
-        # the reference sweep (BASELINE.md).  The budget prices LIVE
-        # buffers only — MULTI-SEGMENT HEADROOM IS REQUIRED: the expand
-        # walk's one-entry parent page cache and the paged-parent fetch
-        # buffer are transient extras the estimate does not count, and
-        # with the async pipeline on, each in-flight window group pins
-        # its group-output fetch buffers and keeps its parent segment
-        # referenced ~window groups longer (the estimate below adds the
-        # window to the live count, the page caches stay uncounted) —
-        # so set the budget several segments below physical HBM
-        # (run_sweep.sh's 11 GB of 16 GB leaves ~45 segments' worth)
-        self.dev_budget = int(float(os.environ.get("TLA_RAFT_DEV_BYTES", "0")))
-        # spilled frontiers (the tier BELOW _HostSeg's host RAM): a
-        # FrontierPager built in run() when a spill directory exists;
-        # host segments past TLA_RAFT_FSEG_BYTES commit to the warm
-        # tier (kind="fseg") and reload on demand.  fseg_rows is the
-        # uniform segment size the streamed megakernel path cuts
-        # oversized parents into (default SEG_ROWS; override for tests)
-        self._fpager = None
-        self.fseg_host_bytes = graft_tiered.fseg_bytes_from_env()
-        fsr = int(os.environ.get("TLA_RAFT_FSEG_ROWS", "0") or 0)
-        self.fseg_rows = max(
-            -(-fsr // chunk) * chunk if fsr else SEG_ROWS, chunk
-        )
-        self._fseg_live = []    # weakrefs of admitted host segments
-        self._fseg_retire = []  # consumed segments' tokens (retired at
-        #                         the next level top — never mid-level,
-        #                         so a degrade-redo still has parents)
-        # async intra-level pipeline (engine/pipeline.py): overlap the
-        # device expand dispatch, the device->host group fetches and the
-        # host-side tail under a bounded in-flight window.  Default ON;
-        # TLA_RAFT_PIPELINE=0 (or pipeline=False / a window < 1) reverts
-        # to the serial fetch-after-dispatch chain — counts are
-        # bit-identical either way (the parity tests diff the two).
-        if pipeline is None:
-            pipeline = graft_pipeline.enabled_by_env()
-        if pipeline_window is None:
-            pipeline_window = graft_pipeline.window_from_env()
-        self.pipeline_window = int(pipeline_window)
-        self.pipeline = bool(pipeline) and self.pipeline_window >= 1
-        # forecast-driven AOT prewarm (engine/pipeline.Prewarmer): once
-        # the growth model has signal, compile the deep-level program
-        # set at the forecast capacity ladder in a background thread
-        # while the cheap shallow levels run (the payoff routes through
-        # the persistent compilation cache, so supervised relaunches
-        # also skip them).  Off by default: local compiles are cheap.
-        env_pw = os.environ.get("TLA_RAFT_PREWARM")
-        if prewarm is None:
-            prewarm = bool(int(env_pw)) if env_pw else False
-        self.prewarm = bool(prewarm)
-        self._prewarmer = None  # built lazily at first plan submit
-        self.paged_out = 0   # sealed child segments demoted to host RAM
-        self.paged_disk = 0  # host segments spilled on to the warm tier
-        if host_store is not None and chunk > SEG_ROWS:
-            # the segment walkers assume chunks never straddle segment
-            # boundaries (chunk is pow2 and <= SEG_ROWS => SEG_ROWS % chunk
-            # == 0); a larger chunk would make divmod-based slices read
-            # past segment bounds (clamped dynamic_slice re-reads wrong
-            # parent rows silently)
-            raise ValueError(
-                f"chunk ({chunk}) must be <= SEG_ROWS ({SEG_ROWS}) "
-                "when an external host store is attached"
-            )
-        self.inv_fns = [
-            (n, resolve_invariant_kernel(n)) for n in cfg.invariants
-        ]
-        self._mat_slice = jax.jit(self._mat_slice_impl)
-        self._mat_slice_seg = jax.jit(self._mat_slice_seg_impl)
-        self._inv_scan = jax.jit(self._inv_scan_impl)
-        # G-chunk span programs replace per-chunk dispatch at real chunk
-        # sizes: each per-chunk round costs ~13 host->device dispatches
-        # (12 eager field slices + the program), which was most of the
-        # warm steady-state cost on the round-5 device.  Tests drive tiny
-        # chunks through the
-        # per-chunk path (some monkeypatch _expand_chunk); lower this to
-        # exercise spans at test scale.
-        self.span_min_chunk = 2048
-        # predictive capacity pre-sizing (VERDICT r4 #7): forecast-floor
-        # the frontier/visited pow2 ladders from the measured growth
-        # model (engine/forecast.py) so a deep run compiles each program
-        # ONCE instead of once per magnitude.  Floors only ratchet up
-        # (shrinking would mint new shapes).  Off by default: local
-        # compiles are cheap and tests drive tiny shapes.
-        env_ps = os.environ.get("TLA_RAFT_PRESIZE")
-        self.presize = bool(int(env_ps)) if env_ps else False
-        self._presize_fcap = 0  # frontier-capacity floor (pow2, >= chunk)
-        self._presize_vcap = 0  # visited-store trim floor (pow4)
-        self._presize_merge = 0  # store merge-input width floor (pow2)
-        # orbit pruning (VERDICT r4 #6, ops/fingerprint.py "orbit
-        # pruning"): canonical-relabel fingerprints for color-discrete
-        # candidates; only the (few) tied states pay the P-fold, on a
-        # cap_x/4 compacted budget.  Changes fingerprint VALUES (not
-        # counts), so it must stay consistent across a run and its
-        # checkpoints — opt-in via TLA_RAFT_ORBIT=1, late canon only.
-        env_orb = os.environ.get("TLA_RAFT_ORBIT")
-        self.orbit = bool(int(env_orb)) if env_orb else False
-        if self.orbit and canon != "late":
-            raise ValueError("TLA_RAFT_ORBIT requires canon='late'")
-        # whole-level megakernel (engine/megakernel.py): expand ->
-        # probe-and-insert -> materialize -> invariant scan fused into
-        # ONE jitted program per level, with one ledgered control fetch.
-        # Default ON; --megakernel 0 / TLA_RAFT_MEGAKERNEL=0 reverts to
-        # the staged program chain (retained as the A/B and audit
-        # reference — counts are bit-identical either way).  The fused
-        # program needs the functional hash store (its probe-and-insert
-        # IS the dedup stage) and the single-program orbit split is
-        # structurally incompatible; the host-store path gets the
-        # partial fusion (expand span + group dedup in one program —
-        # everything up to the host-store probe) under the same flag.
-        if megakernel is None:
-            megakernel = graft_megakernel.enabled_by_env()
-        self._mega_flag = bool(megakernel) and not self.orbit
-        self.megakernel = (
-            self._mega_flag and self.use_hashstore and host_store is None
-        )
-        self._mega_donate = (
-            self.megakernel and graft_megakernel.donation_supported()
-        )
-        self._mega_stats = dict(
-            levels=0, redo_out=0, redo_x=0, redo_slab=0, redo_m=0,
-        )
-        # multi-level resident supersteps (engine/superstep.py): run up
-        # to N fused levels inside ONE device program + ONE ledgered
-        # ring fetch wherever the per-level megakernel is eligible.
-        # Default span DEFAULT_SPAN; --superstep 1 / TLA_RAFT_SUPERSTEP
-        # reverts to the per-level fused path.  The --audit legacy
-        # re-expansion needs every level's parent frontier on device,
-        # which the resident loop consumes — audit runs stay per-level.
-        if superstep is None:
-            superstep = graft_superstep.span_from_env()
-        self.superstep_span = (
-            max(1, int(superstep)) if self.megakernel and not audit
-            else 1
-        )
-        self._ss_stats = dict(
-            supersteps=0, levels=0, stops=0, ring_stops=0,
-        )
-        self._ss_sig = None  # declared superstep static-shape signature
-        self._degraded_visited = None  # sorted store handoff on degrade
-        # why the hash store degraded (None = never): reported in the
-        # --json summary so a grow failure (device OOM) is never silent
-        self.hashstore_degraded = None
-        # semantic run fingerprint for the checkpoint manifests: spec
-        # constants only — NOT tunables like chunk (a resume may retune
-        # those freely), NOT the store tier (the three tiers share one
-        # delta-log format), and NOT the fingerprint definition: orbit
-        # mixing is guarded one layer down by the per-record fp_def
-        # check, whose specific "fingerprint-definition mismatch" error
-        # tells the operator which knob to flip.
-        self._run_fp = resilience.run_config_fingerprint(cfg, log="delta")
-        # sampled recomputation audit (resilience/integrity.py): every
-        # level, ``audit`` deterministic new-frontier rows re-expand
-        # through the retained *_legacy kernels and cross-check guards/
-        # fingerprints against the (MXU) hot path AND the frontier as
-        # materialized on device; a mismatch quarantines the level and
-        # rewinds to the last committed checkpoint, fail-stopping after
-        # ``audit_retries`` reproducible strikes.
-        self.audit = max(0, int(audit))
-        self.audit_retries = max(1, int(audit_retries))
-        self.audit_stats = dict(
-            levels=0, sampled=0, mismatches=0, rewinds=0
-        )
-        self._audit_strikes = 0
-        self._audit_strike_depth = None  # level the strikes belong to
-        self._audit_keys: set = set()  # declared audit program shapes
-        # per-level hang watchdog (resilience/elastic.py), armed by the
-        # level loop; None = off
-        self.watchdog = watchdog
-        self._jit_expand_programs()
-
-    def _jit_expand_programs(self):
-        """(Re-)jit the chunk expand programs (cap_x is baked in).
-
-        Orbit runs the chunk as TWO programs — guards/compact/materialize,
-        then fingerprints — because the fused variant (canonical-relabel
-        machinery + the exact-fold fallback on top of the expand) made
-        the S=7 compile too large for the round-5 device's compile window
-        (the s7 campaign step died mid-compile).  Split, each program
-        is no bigger than the non-orbit fused one, and at S=7 rates the
-        extra dispatch is noise.  Spans stay off under orbit for the same
-        reason (the scan multiplies program size by G).
-        """
-        self._expand_span = jax.jit(self._expand_span_impl)
-        if self.orbit:
-            self._expand_chunk_core = jax.jit(self._expand_chunk_core_impl)
-            self._orbit_fps = jax.jit(self._orbit_fps_impl)
-            self._expand_chunk = self._expand_chunk_split
-        else:
-            self._expand_chunk = jax.jit(self._expand_chunk_impl)
-        # the fused whole-level program (and the host path's fused
-        # span+dedup slice) close over cap_x — rebuild with it
-        if getattr(self, "megakernel", False):
-            self._mega_prog = graft_megakernel.level_program_for(
-                self, self._mega_donate
-            )
-        if getattr(self, "_mega_flag", False) and not self.orbit:
-            self._expand_group_fused = jax.jit(
-                self._expand_group_fused_impl
-            )
-            # grouped ultra-deep regime: span + visited pre-filter in
-            # one program per group (cap_g static so its growth
-            # retraces like the staged _group_filter_hash)
-            self._expand_group_gfused = jax.jit(
-                self._expand_group_gfused_impl,
-                static_argnames=("cap_g",),
-            )
 
     # -- sparse <-> dense message-set conversion ---------------------------
 
@@ -1004,6 +698,404 @@ class JaxChecker:
         children = self.kern.materialize(parents, slots)
         return children, lane, cp_raw, mult_slots, abort_at, overflow
 
+    def _inv_scan_impl(self, children: RaftState, n_valid):
+        """All configured invariants over a level; (first_bad_idx|-1)."""
+        N = children.voted_for.shape[0]
+        in_range = jnp.arange(N, dtype=I64) < n_valid
+        bad = jnp.zeros(N, bool)
+        for _name, fn in self.inv_fns:
+            bad = bad | (~fn(self.cfg, children, self.kern.tables) & in_range)
+        return jnp.where(bad.any(), jnp.argmax(bad).astype(I64), -1)
+
+
+@dataclasses.dataclass(frozen=True)
+class LevelSpec(LevelOps):
+    """Everything the fused level body reads at trace time, frozen at
+    one engine's budgets (``JaxChecker.level_spec``).
+
+    The megakernel and superstep programs close over a spec, never an
+    engine, and their caches key on it: equal specs trace equal
+    programs, so every checker on one config at one set of budgets
+    shares one jitted program and its traced shape rungs, and a checker
+    whose budgets grow gets a new spec and a program of its own.  The
+    fields after ``orbit`` follow from ``kern`` (one per config,
+    ``ops/successor.get_kernel``) and stay out of the comparison."""
+
+    kern: SuccessorKernel
+    chunk: int
+    cap_x: int
+    cap_m: int
+    canon: str
+    orbit: bool
+    cfg: RaftConfig = dataclasses.field(compare=False)
+    K: int = dataclasses.field(compare=False)
+    fpr: object = dataclasses.field(compare=False)
+    uni_words: int = dataclasses.field(compare=False)
+    id_dtype: object = dataclasses.field(compare=False)
+    inv_fns: tuple = dataclasses.field(compare=False)
+
+
+class JaxChecker(LevelOps):
+    """The TPU model checker for one RaftConfig.
+
+    Parameters:
+      chunk: max parents expanded per kernel launch (memory knob; the
+        per-launch working set is ~chunk * K * (F + hash) bytes).
+      cap_x: per-chunk compacted-survivor lanes (grows on overflow).
+      progress: optional callable(level_stats_dict) for per-level logging.
+    """
+
+    def __init__(
+        self,
+        cfg: RaftConfig,
+        chunk: int = 1024,
+        cap_x: int | None = None,
+        progress: Callable[[dict], None] | None = None,
+        host_store=None,
+        cap_m: int = 96,
+        canon: str = "late",
+        use_hashstore: bool | None = None,
+        pipeline: bool | None = None,
+        pipeline_window: int | None = None,
+        prewarm: bool | None = None,
+        use_mxu: bool | None = None,
+        megakernel: bool | None = None,
+        superstep: int | None = None,
+        audit: int = 0,
+        audit_retries: int = 3,
+        watchdog=None,
+        store_bytes: int | None = None,
+        warm_bytes: int | None = None,
+        sieve: bool | None = None,
+    ):
+        # canon="late": expand computes guards only; the compacted
+        # candidates are materialized and fingerprinted with the full-state
+        # path — the P-wide symmetry fold runs over ~3.5 candidates/state
+        # instead of all K fan-out lanes (the enabler for big symmetry
+        # groups, and faster even at S=3).  canon="expand": fold the
+        # symmetry hash into every fan-out lane (the round-2 formulation,
+        # kept as a differential reference).
+        assert canon in ("late", "expand")
+        self.canon = canon
+        self.cfg = cfg
+        # MXU-native expand (ops/mxu_expand.py): guards as the coefficient
+        # matmul, materialize as gather-free select-matrix products.
+        # Default ON; TLA_RAFT_MXU=0 / --no-mxu-expand / use_mxu=False
+        # reverts to the legacy per-lane kernels — counts are
+        # bit-identical either way (the MXU parity suite diffs the two).
+        self.kern: SuccessorKernel = get_kernel(cfg, mxu=use_mxu)
+        self.use_mxu = self.kern.use_mxu
+        self.fpr = self.kern.fpr
+        self.K = self.kern.K
+        self.uni_words = self.kern.uni.n_words
+        # sparse-frontier width: max message-set size per reachable state
+        # (grows ~1/level, saturating near a structural bound — 96 on the
+        # reference family; overflow auto-grows it and re-materializes
+        # the level, see _materialize_grow).  TLA_RAFT_CAP_M overrides —
+        # deep sweeps start with headroom so growth never fires after
+        # parent segments are released.
+        env_capm = os.environ.get("TLA_RAFT_CAP_M")
+        if env_capm is not None and cap_m == 96:
+            # env overrides only the DEFAULT: a caller passing an explicit
+            # cap_m (tests bounding HBM, the growth suite) keeps it
+            cap_m = int(env_capm)
+        self.cap_m = min(cap_m, self.kern.uni.M)
+        self.id_dtype = jnp.int16 if self.kern.uni.M < (1 << 15) else jnp.int32
+        if chunk & (chunk - 1):
+            # power-of-two capacities divide evenly into the pow4-padded
+            # materialize buffer; arbitrary chunks would mis-slice it
+            raise ValueError(f"chunk must be a power of two, got {chunk}")
+        self.chunk = chunk
+        # a chunk's valid fan-out lanes average ~3.5 per parent on the
+        # reference config, so chunk*4 covers the mean and overflow
+        # detection grows the budget (with a re-jit) on skewed chunks
+        self.cap_x = cap_x or 4 * chunk
+        # chunks per visited-filter group, and the per-group post-filter
+        # survivor budget (deep levels see <=50% fresh candidates;
+        # overflow grows cap_g like cap_x)
+        self.G = 16
+        self.cap_g = self.G * self.cap_x // 2
+        # chunks dispatched between queue-draining scalar fetches
+        env_sync = os.environ.get("TLA_RAFT_SYNC_EVERY")
+        self.sync_every = max(1, int(env_sync)) if env_sync else 8
+        self.progress = progress
+        # optional native external-memory visited store (native/fpstore.cpp);
+        # when set, the device keeps no visited table at all — the level's
+        # deduped candidates are filtered through the host store instead
+        self.host_store = host_store
+        # device-resident open-addressing visited store (ops/hashstore.py):
+        # replaces the level's 3-key lexsort + searchsorted + sorted store
+        # merge with one fused O(1) probe-and-insert on the device-store
+        # path.  Default ON; TLA_RAFT_HASHSTORE=0 (or --no-hashstore /
+        # use_hashstore=False) reverts to the sort-based path.  Moot when
+        # an external host store is attached (membership lives host-side).
+        if use_hashstore is None:
+            use_hashstore = hashstore.enabled_by_env()
+        self.use_hashstore = bool(use_hashstore) and host_store is None
+        self.hstore = None  # DeviceHashStore, built in run()/resume
+        self._hs_pending = None  # a level's updated slab awaiting adoption
+        # tiered visited store (store/tiered.py): a device-byte budget
+        # for the hot slab; growth past it DEMOTES a whole generation
+        # to host RAM / disk instead of growing (or dying), and the
+        # level tail probes the demoted runs host-side, dropping their
+        # revisits from the fresh set — |visited| becomes
+        # storage-bounded, TLC's disk FPSet move.  0 = off (the
+        # hot-only engine, bit-identical counts either way).
+        if store_bytes is None:
+            store_bytes = graft_tiered.store_bytes_from_env()
+        self.store_bytes = int(store_bytes)
+        self.warm_bytes = warm_bytes  # None = TLA_RAFT_WARM_BYTES
+        self.tiered = None  # TieredVisitedStore, built in run()/resume
+        # device-resident spill sieve (ops/sieve.py): a blocked bloom
+        # over every demoted fingerprint, probed INSIDE the fused
+        # megakernel/superstep body — a level with zero sieve hits
+        # provably has no spilled revisits and commits in-window, which
+        # restores span-N supersteps under spill (the PR 12 stand-down
+        # becomes the sieve-off fallback).  Default ON wherever tiering
+        # is; TLA_RAFT_SIEVE=0 / sieve=False reverts to span-1.
+        # the arm decision is governed at RUNTIME when TLA_RAFT_SIEVE
+        # is unset: recent sieve-dirty windows stand the span down
+        # (the replay tax never amortizes — BENCH_SIEVE_AB_r20's ~14%),
+        # a probation of per-level progress re-arms it.  =0 / =1 (or an
+        # explicit argument) still force either mode unconditionally.
+        self.sieve_governor = graft_adaptive.SieveGovernor(
+            graft_adaptive.mode_from_env(sieve)
+        )
+        self.sieve_enabled = self.sieve_governor.mode != "off"
+        self._dev_sieve = None      # device u64[M] copy of the filter
+        self._dev_sieve_ver = -1    # host filter version it mirrors
+        self._dev_sieve_empty = None  # the 1-word all-miss sentinel
+        # device-byte budget for frontier segments (0 = paging off): when
+        # one level's parent+child segments would exceed it, sealed child
+        # segments demote to host RAM and page back in on demand — the
+        # tier that breaks the single-frontier-in-HBM wall at level 29 of
+        # the reference sweep (BASELINE.md).  The budget prices LIVE
+        # buffers only — MULTI-SEGMENT HEADROOM IS REQUIRED: the expand
+        # walk's one-entry parent page cache and the paged-parent fetch
+        # buffer are transient extras the estimate does not count, and
+        # with the async pipeline on, each in-flight window group pins
+        # its group-output fetch buffers and keeps its parent segment
+        # referenced ~window groups longer (the estimate below adds the
+        # window to the live count, the page caches stay uncounted) —
+        # so set the budget several segments below physical HBM
+        # (run_sweep.sh's 11 GB of 16 GB leaves ~45 segments' worth)
+        self.dev_budget = int(float(os.environ.get("TLA_RAFT_DEV_BYTES", "0")))
+        # spilled frontiers (the tier BELOW _HostSeg's host RAM): a
+        # FrontierPager built in run() when a spill directory exists;
+        # host segments past TLA_RAFT_FSEG_BYTES commit to the warm
+        # tier (kind="fseg") and reload on demand.  fseg_rows is the
+        # uniform segment size the streamed megakernel path cuts
+        # oversized parents into (default SEG_ROWS; override for tests)
+        self._fpager = None
+        self.fseg_host_bytes = graft_tiered.fseg_bytes_from_env()
+        fsr = int(os.environ.get("TLA_RAFT_FSEG_ROWS", "0") or 0)
+        self.fseg_rows = max(
+            -(-fsr // chunk) * chunk if fsr else SEG_ROWS, chunk
+        )
+        self._fseg_live = []    # weakrefs of admitted host segments
+        self._fseg_retire = []  # consumed segments' tokens (retired at
+        #                         the next level top — never mid-level,
+        #                         so a degrade-redo still has parents)
+        # async intra-level pipeline (engine/pipeline.py): overlap the
+        # device expand dispatch, the device->host group fetches and the
+        # host-side tail under a bounded in-flight window.  Default ON;
+        # TLA_RAFT_PIPELINE=0 (or pipeline=False / a window < 1) reverts
+        # to the serial fetch-after-dispatch chain — counts are
+        # bit-identical either way (the parity tests diff the two).
+        if pipeline is None:
+            pipeline = graft_pipeline.enabled_by_env()
+        if pipeline_window is None:
+            pipeline_window = graft_pipeline.window_from_env()
+        self.pipeline_window = int(pipeline_window)
+        self.pipeline = bool(pipeline) and self.pipeline_window >= 1
+        # forecast-driven AOT prewarm (engine/pipeline.Prewarmer): once
+        # the growth model has signal, compile the deep-level program
+        # set at the forecast capacity ladder in a background thread
+        # while the cheap shallow levels run (the payoff routes through
+        # the persistent compilation cache, so supervised relaunches
+        # also skip them).  Off by default: local compiles are cheap.
+        env_pw = os.environ.get("TLA_RAFT_PREWARM")
+        if prewarm is None:
+            prewarm = bool(int(env_pw)) if env_pw else False
+        self.prewarm = bool(prewarm)
+        self._prewarmer = None  # built lazily at first plan submit
+        self.paged_out = 0   # sealed child segments demoted to host RAM
+        self.paged_disk = 0  # host segments spilled on to the warm tier
+        if host_store is not None and chunk > SEG_ROWS:
+            # the segment walkers assume chunks never straddle segment
+            # boundaries (chunk is pow2 and <= SEG_ROWS => SEG_ROWS % chunk
+            # == 0); a larger chunk would make divmod-based slices read
+            # past segment bounds (clamped dynamic_slice re-reads wrong
+            # parent rows silently)
+            raise ValueError(
+                f"chunk ({chunk}) must be <= SEG_ROWS ({SEG_ROWS}) "
+                "when an external host store is attached"
+            )
+        self.inv_fns = [
+            (n, resolve_invariant_kernel(n)) for n in cfg.invariants
+        ]
+        self._mat_slice = jax.jit(_weak_method(self._mat_slice_impl))
+        self._mat_slice_seg = jax.jit(
+            _weak_method(self._mat_slice_seg_impl)
+        )
+        self._inv_scan = jax.jit(_weak_method(self._inv_scan_impl))
+        # G-chunk span programs replace per-chunk dispatch at real chunk
+        # sizes: each per-chunk round costs ~13 host->device dispatches
+        # (12 eager field slices + the program), which was most of the
+        # warm steady-state cost on the round-5 device.  Tests drive tiny
+        # chunks through the
+        # per-chunk path (some monkeypatch _expand_chunk); lower this to
+        # exercise spans at test scale.
+        self.span_min_chunk = 2048
+        # predictive capacity pre-sizing (VERDICT r4 #7): forecast-floor
+        # the frontier/visited pow2 ladders from the measured growth
+        # model (engine/forecast.py) so a deep run compiles each program
+        # ONCE instead of once per magnitude.  Floors only ratchet up
+        # (shrinking would mint new shapes).  Off by default: local
+        # compiles are cheap and tests drive tiny shapes.
+        env_ps = os.environ.get("TLA_RAFT_PRESIZE")
+        self.presize = bool(int(env_ps)) if env_ps else False
+        self._presize_fcap = 0  # frontier-capacity floor (pow2, >= chunk)
+        self._presize_vcap = 0  # visited-store trim floor (pow4)
+        self._presize_merge = 0  # store merge-input width floor (pow2)
+        # orbit pruning (VERDICT r4 #6, ops/fingerprint.py "orbit
+        # pruning"): canonical-relabel fingerprints for color-discrete
+        # candidates; only the (few) tied states pay the P-fold, on a
+        # cap_x/4 compacted budget.  Changes fingerprint VALUES (not
+        # counts), so it must stay consistent across a run and its
+        # checkpoints — opt-in via TLA_RAFT_ORBIT=1, late canon only.
+        env_orb = os.environ.get("TLA_RAFT_ORBIT")
+        self.orbit = bool(int(env_orb)) if env_orb else False
+        if self.orbit and canon != "late":
+            raise ValueError("TLA_RAFT_ORBIT requires canon='late'")
+        # whole-level megakernel (engine/megakernel.py): expand ->
+        # probe-and-insert -> materialize -> invariant scan fused into
+        # ONE jitted program per level, with one ledgered control fetch.
+        # Default ON; --megakernel 0 / TLA_RAFT_MEGAKERNEL=0 reverts to
+        # the staged program chain (retained as the A/B and audit
+        # reference — counts are bit-identical either way).  The fused
+        # program needs the functional hash store (its probe-and-insert
+        # IS the dedup stage) and the single-program orbit split is
+        # structurally incompatible; the host-store path gets the
+        # partial fusion (expand span + group dedup in one program —
+        # everything up to the host-store probe) under the same flag.
+        if megakernel is None:
+            megakernel = graft_megakernel.enabled_by_env()
+        self._mega_flag = bool(megakernel) and not self.orbit
+        self.megakernel = (
+            self._mega_flag and self.use_hashstore and host_store is None
+        )
+        self._mega_donate = (
+            self.megakernel and graft_megakernel.donation_supported()
+        )
+        # program_hits/program_builds: this engine's lookups in the
+        # shared program caches (megakernel/superstep ``program_for``)
+        self._mega_stats = dict(
+            levels=0, redo_out=0, redo_x=0, redo_slab=0, redo_m=0,
+            program_hits=0, program_builds=0,
+        )
+        # multi-level resident supersteps (engine/superstep.py): run up
+        # to N fused levels inside ONE device program + ONE ledgered
+        # ring fetch wherever the per-level megakernel is eligible.
+        # Default span DEFAULT_SPAN; --superstep 1 / TLA_RAFT_SUPERSTEP
+        # reverts to the per-level fused path.  The --audit legacy
+        # re-expansion needs every level's parent frontier on device,
+        # which the resident loop consumes — audit runs stay per-level.
+        if superstep is None:
+            superstep = graft_superstep.span_from_env()
+        self.superstep_span = (
+            max(1, int(superstep)) if self.megakernel and not audit
+            else 1
+        )
+        self._ss_stats = dict(
+            supersteps=0, levels=0, stops=0, ring_stops=0,
+            program_hits=0, program_builds=0,
+        )
+        self._ss_sig = None  # declared superstep static-shape signature
+        self._degraded_visited = None  # sorted store handoff on degrade
+        # why the hash store degraded (None = never): reported in the
+        # --json summary so a grow failure (device OOM) is never silent
+        self.hashstore_degraded = None
+        # semantic run fingerprint for the checkpoint manifests: spec
+        # constants only — NOT tunables like chunk (a resume may retune
+        # those freely), NOT the store tier (the three tiers share one
+        # delta-log format), and NOT the fingerprint definition: orbit
+        # mixing is guarded one layer down by the per-record fp_def
+        # check, whose specific "fingerprint-definition mismatch" error
+        # tells the operator which knob to flip.
+        self._run_fp = resilience.run_config_fingerprint(cfg, log="delta")
+        # sampled recomputation audit (resilience/integrity.py): every
+        # level, ``audit`` deterministic new-frontier rows re-expand
+        # through the retained *_legacy kernels and cross-check guards/
+        # fingerprints against the (MXU) hot path AND the frontier as
+        # materialized on device; a mismatch quarantines the level and
+        # rewinds to the last committed checkpoint, fail-stopping after
+        # ``audit_retries`` reproducible strikes.
+        self.audit = max(0, int(audit))
+        self.audit_retries = max(1, int(audit_retries))
+        self.audit_stats = dict(
+            levels=0, sampled=0, mismatches=0, rewinds=0
+        )
+        self._audit_strikes = 0
+        self._audit_strike_depth = None  # level the strikes belong to
+        self._audit_keys: set = set()  # declared audit program shapes
+        # per-level hang watchdog (resilience/elastic.py), armed by the
+        # level loop; None = off
+        self.watchdog = watchdog
+        self._jit_expand_programs()
+
+    def _jit_expand_programs(self):
+        """(Re-)jit the chunk expand programs (cap_x is baked in).
+
+        Orbit runs the chunk as TWO programs — guards/compact/materialize,
+        then fingerprints — because the fused variant (canonical-relabel
+        machinery + the exact-fold fallback on top of the expand) made
+        the S=7 compile too large for the round-5 device's compile window
+        (the s7 campaign step died mid-compile).  Split, each program
+        is no bigger than the non-orbit fused one, and at S=7 rates the
+        extra dispatch is noise.  Spans stay off under orbit for the same
+        reason (the scan multiplies program size by G).
+        """
+        self._expand_span = jax.jit(_weak_method(self._expand_span_impl))
+        if self.orbit:
+            self._expand_chunk_core = jax.jit(
+                _weak_method(self._expand_chunk_core_impl)
+            )
+            self._orbit_fps = jax.jit(_weak_method(self._orbit_fps_impl))
+            self._expand_chunk = _weak_method(self._expand_chunk_split)
+        else:
+            self._expand_chunk = jax.jit(
+                _weak_method(self._expand_chunk_impl)
+            )
+        # the fused whole-level program (and the host path's fused
+        # span+dedup slice) close over cap_x — rebuild with it
+        if getattr(self, "megakernel", False):
+            self._mega_prog = graft_megakernel.level_program_for(
+                self.level_spec(), self._mega_donate, self._mega_stats
+            )
+        if getattr(self, "_mega_flag", False) and not self.orbit:
+            self._expand_group_fused = jax.jit(
+                _weak_method(self._expand_group_fused_impl)
+            )
+            # grouped ultra-deep regime: span + visited pre-filter in
+            # one program per group (cap_g static so its growth
+            # retraces like the staged _group_filter_hash)
+            self._expand_group_gfused = jax.jit(
+                _weak_method(self._expand_group_gfused_impl),
+                static_argnames=("cap_g",),
+            )
+
+    def level_spec(self) -> LevelSpec:
+        """What the fused level programs read, frozen at the current
+        budgets: their cache key and the only state they close over."""
+        return LevelSpec(
+            kern=self.kern, chunk=self.chunk, cap_x=self.cap_x,
+            cap_m=self.cap_m, canon=self.canon, orbit=self.orbit,
+            cfg=self.cfg, K=self.K, fpr=self.fpr,
+            uni_words=self.uni_words, id_dtype=self.id_dtype,
+            inv_fns=tuple(self.inv_fns),
+        )
+
     def _expand_chunk_core_impl(self, part_f: Frontier, start, n_f):
         """Jit target for the orbit split's first program."""
         part = self._inflate(part_f)
@@ -1208,13 +1300,12 @@ class JaxChecker:
             cap_out = self._mega_cap_out(
                 n_f, level_sizes, max_depth, n_lanes, out_floor
             )
-            # re-resolve through the shared cache EVERY attempt: the
-            # staleness guard compares this engine's budgets against
-            # the cached creator's, so a creator that grew cap_x/cap_m
-            # after we borrowed its program can never hand us a trace
-            # against its mutated state (a dict hit costs nothing)
+            # re-resolve through the shared cache EVERY attempt: a grown
+            # budget is a new spec and so a new program, and a flushed
+            # cache (hashstore.set_probe_window) is never dispatched
+            # from a stale binding (a dict hit costs nothing)
             self._mega_prog = graft_megakernel.level_program_for(
-                self, self._mega_donate
+                self.level_spec(), self._mega_donate, self._mega_stats
             )
             # device-cost observatory: harvest the fused program's XLA
             # cost/memory ledger once per shape (compile-time only —
@@ -1304,9 +1395,8 @@ class JaxChecker:
                 )
                 frontier = self._widen_msg_ids(frontier)
                 # re-resolve the fused program under the grown cap_m:
-                # the widened shapes drive the retrace, but the shared
-                # program cache keys on cap_m, so a stale binding from
-                # another engine's key must not be retraced through
+                # the widened shapes drive the retrace, and the shared
+                # program cache keys on the spec, which holds cap_m
                 self._jit_expand_programs()
                 self._mega_stats["redo_m"] += 1
                 graft_obs.grow("cap_m", self.cap_m)
@@ -1698,7 +1788,8 @@ class JaxChecker:
                 self._degraded_visited = self._degrade_hashstore(e)
                 return dict(degraded=True, frontier=frontier)
             prog = ss.superstep_program_for(
-                self, self.superstep_span, self._mega_donate
+                self.level_spec(), self.superstep_span, self._mega_donate,
+                self._ss_stats,
             )
             # cap_cur (the input frontier's capacity) is part of the traced
             # shape via the in-program padding — a changed input rung is a
@@ -1764,15 +1855,6 @@ class JaxChecker:
             cap_f=cap_f,
             span=span,
         )
-
-    def _inv_scan_impl(self, children: RaftState, n_valid):
-        """All configured invariants over a level; (first_bad_idx|-1)."""
-        N = children.voted_for.shape[0]
-        in_range = jnp.arange(N, dtype=I64) < n_valid
-        bad = jnp.zeros(N, bool)
-        for _name, fn in self.inv_fns:
-            bad = bad | (~fn(self.cfg, children, self.kern.tables) & in_range)
-        return jnp.where(bad.any(), jnp.argmax(bad).astype(I64), -1)
 
     def _action_counts(self, mult_per_slot: np.ndarray) -> dict:
         """Fold per-slot fired-transition counts to action names (the TLC
@@ -1885,7 +1967,7 @@ class JaxChecker:
 
     @functools.cached_property
     def _audit_prog(self):
-        return jax.jit(self._audit_impl)
+        return jax.jit(_weak_method(self._audit_impl))
 
     def _gather_frontier_rows(self, frontier, idx_np) -> Frontier:
         """Sampled rows of a frontier (tree or segment list) as one
@@ -2361,7 +2443,8 @@ class JaxChecker:
                     fut_w, span, prev_rows, prev_cap
                 )
                 prog = graft_superstep.superstep_program_for(
-                    self, span, self._mega_donate
+                    self.level_spec(), span, self._mega_donate,
+                    self._ss_stats,
                 )
                 fs = self._frontier_struct(frontier, prev_cap)
                 for sc in scaps:
@@ -2407,8 +2490,9 @@ class JaxChecker:
                     plan.append((
                         ("mega", prev_cap, cout, sc, self.cap_x,
                          self.cap_m, self.use_mxu, sv_struct.shape[0]),
-                        lambda fs=fs, sc=sc, cout=cout:
-                            self._mega_prog.lower(
+                        lambda fs=fs, sc=sc, cout=cout,
+                               prog=self._mega_prog:
+                            prog.lower(
                                 fs, u64(sc), s_i64, sv_struct,
                                 cap_out=cout
                             ).compile(),

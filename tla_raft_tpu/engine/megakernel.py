@@ -119,65 +119,57 @@ def mat_slice_width(cap_out: int, chunk: int) -> int:
     return chunk
 
 
-# shared jit cache for the fused program: the traced body is fully
-# determined by (kernel identity, chunk, cap_x, cap_m, canon, donation)
-# — the kernel itself is lru-cached per config (ops/successor
-# .get_kernel), so two engines on the same config at the same budgets
-# share ONE jitted program and its compiled executables instead of
-# re-tracing per instance (the test suite builds dozens of same-config
-# checkers; a per-instance cache would pay the fused program's compile
-# each time).  Bounded LRU: a cached program's closure pins its creator
-# engine (and through it the device hash slab), so unbounded growth in
-# a many-config sweep process would be a device-memory leak — eviction
-# caps the pinned set (the service's BucketPrograms cache uses the
-# same bound-the-closure-pins discipline).
+# shared jit cache for the fused program, keyed on the engine's
+# ``LevelSpec`` (engine/bfs.py: everything the traced body reads) and
+# the donation flag.  The program closes over the spec alone, so every
+# engine on one config at the same budgets shares ONE jitted program
+# and its traced shape rungs — a new checker re-traces nothing its
+# predecessors traced (the benchmark's back-to-back checks, the test
+# suite's dozens of same-config checkers) — and no entry pins an
+# engine.  Bounded LRU: each entry holds its compiled executables.
 _PROG_CACHE: "dict" = {}
 _PROG_CACHE_MAX = 16
 
 
-def level_program_for(eng, donate: bool):
-    key = (eng.kern, eng.chunk, eng.cap_x, eng.cap_m, eng.canon,
-           bool(donate))
-    entry = _PROG_CACHE.get(key)
-    if entry is not None:
-        prog, owner = entry
-        # staleness guard: the traced body reads the CREATOR's state at
-        # trace time (new shapes trace lazily), so the cached program is
-        # reusable only while the creator still matches the key — a
-        # cap_x/cap_m growth mutates the creator and re-registers it
-        # under its new key, orphaning this entry
-        if (owner.kern is eng.kern and owner.chunk == eng.chunk
-                and owner.cap_x == eng.cap_x
-                and owner.cap_m == eng.cap_m
-                and owner.canon == eng.canon):
-            # LRU touch
-            _PROG_CACHE.pop(key)
-            _PROG_CACHE[key] = (prog, owner)
-            return prog
-    prog = build_level_program(eng, donate)
-    # flight-recorder breadcrumb: a fresh fused program was built (the
-    # compile itself lands on the compile track when it first runs)
-    from ..obs import telemetry as _obs
-
-    _obs.emit("program", kind="megakernel", chunk=eng.chunk,
-              cap_x=eng.cap_x, cap_m=eng.cap_m)
-    _PROG_CACHE[key] = (prog, eng)
-    while len(_PROG_CACHE) > _PROG_CACHE_MAX:
-        _PROG_CACHE.pop(next(iter(_PROG_CACHE)))
+def cached_program(cache: dict, key, build, stats: dict | None = None):
+    """LRU lookup shared by the level and superstep program caches: the
+    program under ``key``, made by ``build()`` on a miss.  ``stats``
+    counts the lookups as ``program_hits`` and ``program_builds``."""
+    prog = cache.pop(key, None)
+    hit = prog is not None
+    if not hit:
+        prog = build()
+    cache[key] = prog
+    while len(cache) > _PROG_CACHE_MAX:
+        cache.pop(next(iter(cache)))
+    if stats is not None:
+        stats["program_hits" if hit else "program_builds"] += 1
     return prog
 
 
-def fused_level_core(eng, frontier, slab, n_f, sieve, cap_out: int,
-                     chunk: int, cap_x: int):
+def level_program_for(spec, donate: bool, stats: dict | None = None):
+    def build():
+        # flight-recorder breadcrumb: a fresh fused program was built
+        # (the compile itself lands on the compile track when it runs)
+        from ..obs import telemetry as _obs
+
+        _obs.emit("program", kind="megakernel", chunk=spec.chunk,
+                  cap_x=spec.cap_x, cap_m=spec.cap_m)
+        return build_level_program(spec, donate)
+
+    return cached_program(_PROG_CACHE, (spec, bool(donate)), build, stats)
+
+
+def fused_level_core(spec, frontier, slab, n_f, sieve, cap_out: int):
     """The traced body of ONE fused BFS level — the shared core both the
     per-level program below and the multi-level superstep driver
     (engine/superstep.py) trace, so the two paths can never drift on
     the level semantics (same expand while_loop, same probe-and-insert,
     same materialize scan, same invariant reduce).
 
-    ``chunk``/``cap_x`` are the builder's SNAPSHOT of the engine's
-    budgets (the staleness tripwire in the callers compares them
-    against the live engine before tracing).  ``sieve`` is the spill
+    ``spec`` is the engine's ``LevelSpec``: the budgets and kernels
+    frozen when the program was built, so a rung traced later reads the
+    same ``chunk``/``cap_x`` as the first.  ``sieve`` is the spill
     sieve's device word image (``u64[M]``, M a power of two; the 1-word
     all-zero sentinel while tiering is off) — fresh lanes are probed
     in-program (ops/sieve.py) and the hit count returned, so a level
@@ -192,7 +184,9 @@ def fused_level_core(eng, frontier, slab, n_f, sieve, cap_out: int,
     from ..ops import hashstore
     from ..ops import sieve as sieve_mod
 
-    K = eng.K
+    K = spec.K
+    chunk = spec.chunk
+    cap_x = spec.cap_x
     cap_f = frontier.voted_for.shape[0]
     n_chunks = cap_f // chunk
     N = n_chunks * cap_x  # level-wide candidate lane budget
@@ -212,7 +206,7 @@ def fused_level_core(eng, frontier, slab, n_f, sieve, cap_out: int,
             ),
             frontier,
         )
-        cv, cf, cp, m, a, o = eng._expand_chunk_impl(part, start, n_f)
+        cv, cf, cp, m, a, o = spec._expand_chunk_impl(part, start, n_f)
         off = i * cap_x
         cv_b = jax.lax.dynamic_update_slice(cv_b, cv, (off,))
         cf_b = jax.lax.dynamic_update_slice(cf_b, cf, (off,))
@@ -271,7 +265,7 @@ def fused_level_core(eng, frontier, slab, n_f, sieve, cap_out: int,
 
     def live_slice(args):
         pay_slice, take = args
-        return eng._mat_slice_impl(frontier, pay_slice, take)
+        return spec._mat_slice_impl(frontier, pay_slice, take)
 
     def dead_slice(args):
         pay_slice, _take = args
@@ -308,12 +302,12 @@ def fused_level_core(eng, frontier, slab, n_f, sieve, cap_out: int,
             ovf_ms.any(), bad_global, mult, fps_out, pay_out, tier_hits)
 
 
-def build_level_program(eng, donate: bool):
-    """The jitted whole-level program for one engine configuration.
+def build_level_program(spec, donate: bool):
+    """The jitted whole-level program for one ``LevelSpec``.
 
-    Closes over the engine's chunk/cap_x/cap_m/canon/kernel state, so
-    the engine rebuilds it whenever any of those change (the same
-    re-jit discipline as ``_jit_expand_programs``).  ``cap_out`` — the
+    Closes over the spec alone (never an engine), so a grown budget —
+    a new spec — gets a new program through ``level_program_for``
+    while every rung of this one keeps its own budgets.  ``cap_out`` — the
     new frontier's static capacity — is a static argument: the shape
     ladder quantizes it through ``_frontier_cap`` and the AOT prewarmer
     compiles the forecast rungs ahead of depth.
@@ -326,28 +320,13 @@ def build_level_program(eng, donate: bool):
     aliasing makes it zero-copy; it keeps the parent frontier alive for
     redo and audit).
     """
-    chunk = eng.chunk
-    cap_x = eng.cap_x
-    K = eng.K
+    K = spec.K
     slot_dt = jnp.uint16 if K <= 0xFFFF else jnp.uint32
 
     def level_body(frontier, slab, n_f, sieve, cap_out: int):
-        # trace-time staleness tripwire: the body calls the creator
-        # engine's methods, which read its LIVE cap_x/chunk — if the
-        # creator's budgets drifted from this build's snapshot, a lazy
-        # re-trace would write wrong-width chunk outputs at the old
-        # stride (silent candidate corruption).  Callers re-resolve
-        # through level_program_for per level, so this can only fire on
-        # a plumbing regression — loudly, not silently.
-        if eng.cap_x != cap_x or eng.chunk != chunk:
-            raise RuntimeError(
-                "megakernel program stale: creator engine's budgets "
-                f"changed (cap_x {cap_x}->{eng.cap_x}, chunk "
-                f"{chunk}->{eng.chunk}); re-fetch via level_program_for"
-            )
         (new_frontier, slab2, n_new, abort_at, ovf_x, ovf_slab, ovf_m,
          bad_global, mult, fps_out, pay_out, tier_hits) = fused_level_core(
-            eng, frontier, slab, n_f, sieve, cap_out, chunk, cap_x
+            spec, frontier, slab, n_f, sieve, cap_out
         )
 
         ctrl = jnp.stack([
@@ -398,7 +377,7 @@ def ledger_trace(cfg=None):
     slab = jax.ShapeDtypeStruct((hashstore.MIN_CAP,), jnp.uint64)
     n_f = jax.ShapeDtypeStruct((), jnp.int64)
     sieve = jax.ShapeDtypeStruct((1,), jnp.uint64)
-    prog = build_level_program(eng, donate=False)
+    prog = build_level_program(eng.level_spec(), donate=False)
     return jax.make_jaxpr(
         lambda f, s, n, sv: prog(f, s, n, sv, cap_out=64)
     )(fr, slab, n_f, sieve)

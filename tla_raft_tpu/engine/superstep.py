@@ -142,44 +142,31 @@ def span_from_env(default: int = DEFAULT_SPAN) -> int:
     return max(1, int(v))
 
 
-# shared jit cache, the megakernel's bound-the-closure-pins discipline:
-# the traced body is fully determined by (kernel identity, chunk,
-# cap_x, cap_m, canon, span, donation) plus the static (cap_f, ring)
-# arguments; same-config engines share one program set
+# shared jit cache, the megakernel's (megakernel.cached_program): the
+# traced body is fully determined by the engine's LevelSpec, the span
+# and the donation flag plus the static (cap_f, ring) arguments, so
+# same-config engines at the same budgets share one program set
 _PROG_CACHE: "dict" = {}
-_PROG_CACHE_MAX = 16
 
 
-def superstep_program_for(eng, span: int, donate: bool):
-    key = (eng.kern, eng.chunk, eng.cap_x, eng.cap_m, eng.canon,
-           int(span), bool(donate))
-    entry = _PROG_CACHE.get(key)
-    if entry is not None:
-        prog, owner = entry
-        # staleness guard (see megakernel.level_program_for): the body
-        # reads the CREATOR's budgets at trace time, so a cached
-        # program is reusable only while the creator matches the key
-        if (owner.kern is eng.kern and owner.chunk == eng.chunk
-                and owner.cap_x == eng.cap_x
-                and owner.cap_m == eng.cap_m
-                and owner.canon == eng.canon):
-            _PROG_CACHE.pop(key)
-            _PROG_CACHE[key] = (prog, owner)
-            return prog
-    prog = build_superstep_program(eng, span, donate)
-    # flight-recorder breadcrumb (see megakernel.level_program_for)
-    from ..obs import telemetry as _obs
+def superstep_program_for(spec, span: int, donate: bool,
+                          stats: dict | None = None):
+    def build():
+        # flight-recorder breadcrumb (see megakernel.level_program_for)
+        from ..obs import telemetry as _obs
 
-    _obs.emit("program", kind="superstep", span=int(span),
-              chunk=eng.chunk, cap_x=eng.cap_x, cap_m=eng.cap_m)
-    _PROG_CACHE[key] = (prog, eng)
-    while len(_PROG_CACHE) > _PROG_CACHE_MAX:
-        _PROG_CACHE.pop(next(iter(_PROG_CACHE)))
-    return prog
+        _obs.emit("program", kind="superstep", span=int(span),
+                  chunk=spec.chunk, cap_x=spec.cap_x, cap_m=spec.cap_m)
+        return build_superstep_program(spec, span, donate)
+
+    return mk.cached_program(
+        _PROG_CACHE, (spec, int(span), bool(donate)), build, stats
+    )
 
 
-def build_superstep_program(eng, span: int, donate: bool):
-    """The jitted N-level driver for one engine configuration.
+def build_superstep_program(spec, span: int, donate: bool):
+    """The jitted N-level driver for one ``LevelSpec`` (closes over the
+    spec alone, never an engine — see megakernel.build_level_program).
 
     Static arguments: ``cap_f`` (the one frontier capacity every level
     of the superstep runs at — a chunk multiple >= the input frontier's
@@ -199,22 +186,13 @@ def build_superstep_program(eng, span: int, donate: bool):
     prefix is garbage by contract (the host slices by the per-level
     counts).
     """
-    chunk = eng.chunk
-    cap_x = eng.cap_x
-    K = eng.K
+    chunk = spec.chunk
+    K = spec.K
     span = int(span)
     slot_dt = jnp.uint16 if K <= 0xFFFF else jnp.uint32
 
     def superstep_body(frontier, slab, n_f, lvl_cap, sieve, cap_f: int,
                        ring: int):
-        # trace-time staleness tripwire (see megakernel.level_body)
-        if eng.cap_x != cap_x or eng.chunk != chunk:
-            raise RuntimeError(
-                "superstep program stale: creator engine's budgets "
-                f"changed (cap_x {cap_x}->{eng.cap_x}, chunk "
-                f"{chunk}->{eng.chunk}); re-fetch via "
-                "superstep_program_for"
-            )
         cap_in = frontier.voted_for.shape[0]
         if cap_in > cap_f or cap_f % chunk or ring < 1:
             raise RuntimeError(
@@ -253,7 +231,7 @@ def build_superstep_program(eng, span: int, donate: bool):
              mm) = c
             (new_fr, slab2, n_new, abort_at, ovf_x, ovf_slab, ovf_m,
              bad, mult, fps_out, pay_out, tier_hits) = mk.fused_level_core(
-                eng, fr, slab, n_f, sieve, cap_f, chunk, cap_x
+                spec, fr, slab, n_f, sieve, cap_f
             )
             abort = abort_at < n_f
             ovf_out = n_new > cap_f  # next frontier cannot seat
@@ -427,7 +405,7 @@ def ledger_trace(cfg=None, span: int = 2):
     slab = jax.ShapeDtypeStruct((hashstore.MIN_CAP,), jnp.uint64)
     n_f = jax.ShapeDtypeStruct((), jnp.int64)
     sieve = jax.ShapeDtypeStruct((1,), jnp.uint64)
-    prog = build_superstep_program(eng, span, donate=False)
+    prog = build_superstep_program(eng.level_spec(), span, donate=False)
     return jax.make_jaxpr(
         lambda f, s, n, lc, sv: prog(f, s, n, lc, sv, cap_f=64, ring=128)
     )(fr, slab, n_f, jax.ShapeDtypeStruct((), jnp.int64), sieve)
