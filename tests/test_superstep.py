@@ -192,7 +192,7 @@ def test_program_shared_across_checkers(span, stats, empty_caches,
     assert getattr(a, stats)["program_builds"] >= 1
     cache = _path_cache(span)
     progs = dict(cache)
-    rungs = {k: p._cache_size() for k, p in progs.items()}
+    rungs = {k: p.jitted._cache_size() for k, p in progs.items()}
     n_traces = len(traced_cap_x)
     assert n_traces > 0
 
@@ -202,7 +202,7 @@ def test_program_shared_across_checkers(span, stats, empty_caches,
     assert getattr(b, stats)["program_hits"] >= 1
     assert len(traced_cap_x) == n_traces  # no trace of the level body
     assert dict(cache) == progs
-    assert {k: p._cache_size() for k, p in cache.items()} == rungs
+    assert {k: p.jitted._cache_size() for k, p in cache.items()} == rungs
 
 
 @pytest.mark.parametrize("span,stats", FUSED_PATHS)

@@ -87,9 +87,9 @@ def compiled_registry():
     msum = kern.fpr.msg_hash(st.msgs)
 
     def _compile(fn, *args, **statics):
-        return jax.jit(
-            fn, static_argnames=tuple(statics) or None
-        ).lower(*args, **statics).compile()
+        if not hasattr(fn, "lower"):  # a plain function, not a program
+            fn = jax.jit(fn, static_argnames=tuple(statics) or None)
+        return fn.lower(*args, **statics).compile()
 
     def _mega():
         eng = JaxChecker(cfg, chunk=64, use_hashstore=True,

@@ -56,7 +56,6 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import inspect
 import os
 import sys
 import time
@@ -75,6 +74,7 @@ from ..config import RaftConfig
 from ..models.raft import RaftState, init_batch, to_oracle
 from ..ops import hashstore
 from ..ops import sieve as graft_sieve
+from ..ops import tables as _tables
 from ..ops.successor import SuccessorKernel, get_kernel
 from ..store import tiered as graft_tiered
 from . import megakernel as graft_megakernel
@@ -263,29 +263,6 @@ def _pad_axis0(x: jnp.ndarray, cap: int) -> jnp.ndarray:
     if pad <= 0:
         return x[:cap]
     return jnp.concatenate([x, jnp.zeros((pad, *x.shape[1:]), x.dtype)])
-
-
-def _weak_method(method):
-    """``method`` as a plain function that holds its instance weakly.
-
-    A checker keeps its jitted programs as attributes; a jitted bound
-    method would be a reference cycle (checker -> program -> method ->
-    checker), so a finished checker, and the device hash slab it holds,
-    would outlive its last user until the cyclic collector happened to
-    run — the next checks of a process would then run beside dead
-    slabs in HBM.  Name and signature follow ``method`` (jit's static
-    argument names and program names read them)."""
-    ref = weakref.WeakMethod(method)
-
-    def call(*args, **kwargs):
-        return ref()(*args, **kwargs)
-
-    call.__name__ = method.__name__
-    call.__qualname__ = method.__qualname__
-    call.__signature__ = inspect.signature(method)
-    return call
-
-
 
 
 @functools.partial(jax.jit, static_argnames=("cap_x",))
@@ -934,11 +911,6 @@ class JaxChecker(LevelOps):
         self.inv_fns = [
             (n, resolve_invariant_kernel(n)) for n in cfg.invariants
         ]
-        self._mat_slice = jax.jit(_weak_method(self._mat_slice_impl))
-        self._mat_slice_seg = jax.jit(
-            _weak_method(self._mat_slice_seg_impl)
-        )
-        self._inv_scan = jax.jit(_weak_method(self._inv_scan_impl))
         # G-chunk span programs replace per-chunk dispatch at real chunk
         # sizes: each per-chunk round costs ~13 host->device dispatches
         # (12 eager field slices + the program), which was most of the
@@ -989,10 +961,11 @@ class JaxChecker(LevelOps):
             self.megakernel and graft_megakernel.donation_supported()
         )
         # program_hits/program_builds: this engine's lookups in the
-        # shared program caches (megakernel/superstep ``program_for``)
+        # shared program caches (megakernel/superstep ``program_for``);
+        # table_bytes: the kernel tables those programs take as operand
         self._mega_stats = dict(
             levels=0, redo_out=0, redo_x=0, redo_slab=0, redo_m=0,
-            program_hits=0, program_builds=0,
+            program_hits=0, program_builds=0, table_bytes=0,
         )
         # multi-level resident supersteps (engine/superstep.py): run up
         # to N fused levels inside ONE device program + ONE ledgered
@@ -1009,7 +982,7 @@ class JaxChecker(LevelOps):
         )
         self._ss_stats = dict(
             supersteps=0, levels=0, stops=0, ring_stops=0,
-            program_hits=0, program_builds=0,
+            program_hits=0, program_builds=0, table_bytes=0,
         )
         self._ss_sig = None  # declared superstep static-shape signature
         self._degraded_visited = None  # sorted store handoff on degrade
@@ -1042,7 +1015,21 @@ class JaxChecker(LevelOps):
         # per-level hang watchdog (resilience/elastic.py), armed by the
         # level loop; None = off
         self.watchdog = watchdog
+        # every program of this checker takes the kernel's device tables
+        # as its first operand (ops/tables.py); orbit checkers add the
+        # canonical-relabel tables their fingerprints read
+        self.kernel_tables = (
+            self.kern.orbit_device_tables if self.orbit
+            else self.kern.device_tables
+        )
+        self._mat_slice = self._program(self._mat_slice_impl)
+        self._mat_slice_seg = self._program(self._mat_slice_seg_impl)
+        self._inv_scan = self._program(self._inv_scan_impl)
         self._jit_expand_programs()
+
+    def _program(self, method, **jit_kwargs):
+        """``method`` of this checker as a program over its kernel tables."""
+        return _tables.method_program(method, self.kernel_tables, **jit_kwargs)
 
     def _jit_expand_programs(self):
         """(Re-)jit the chunk expand programs (cap_x is baked in).
@@ -1056,17 +1043,17 @@ class JaxChecker(LevelOps):
         extra dispatch is noise.  Spans stay off under orbit for the same
         reason (the scan multiplies program size by G).
         """
-        self._expand_span = jax.jit(_weak_method(self._expand_span_impl))
+        self._expand_span = self._program(self._expand_span_impl)
         if self.orbit:
-            self._expand_chunk_core = jax.jit(
-                _weak_method(self._expand_chunk_core_impl)
+            self._expand_chunk_core = self._program(
+                self._expand_chunk_core_impl
             )
-            self._orbit_fps = jax.jit(_weak_method(self._orbit_fps_impl))
-            self._expand_chunk = _weak_method(self._expand_chunk_split)
+            self._orbit_fps = self._program(self._orbit_fps_impl)
+            # two programs, called in turn by the owner (weakly held)
+            ref = weakref.WeakMethod(self._expand_chunk_split)
+            self._expand_chunk = lambda *a: ref()(*a)
         else:
-            self._expand_chunk = jax.jit(
-                _weak_method(self._expand_chunk_impl)
-            )
+            self._expand_chunk = self._program(self._expand_chunk_impl)
         # the fused whole-level program (and the host path's fused
         # span+dedup slice) close over cap_x — rebuild with it
         if getattr(self, "megakernel", False):
@@ -1074,14 +1061,14 @@ class JaxChecker(LevelOps):
                 self.level_spec(), self._mega_donate, self._mega_stats
             )
         if getattr(self, "_mega_flag", False) and not self.orbit:
-            self._expand_group_fused = jax.jit(
-                _weak_method(self._expand_group_fused_impl)
+            self._expand_group_fused = self._program(
+                self._expand_group_fused_impl
             )
             # grouped ultra-deep regime: span + visited pre-filter in
             # one program per group (cap_g static so its growth
             # retraces like the staged _group_filter_hash)
-            self._expand_group_gfused = jax.jit(
-                _weak_method(self._expand_group_gfused_impl),
+            self._expand_group_gfused = self._program(
+                self._expand_group_gfused_impl,
                 static_argnames=("cap_g",),
             )
 
@@ -1967,7 +1954,7 @@ class JaxChecker(LevelOps):
 
     @functools.cached_property
     def _audit_prog(self):
-        return jax.jit(_weak_method(self._audit_impl))
+        return self._program(self._audit_impl)
 
     def _gather_frontier_rows(self, frontier, idx_np) -> Frontier:
         """Sampled rows of a frontier (tree or segment list) as one
@@ -3084,7 +3071,7 @@ class JaxChecker(LevelOps):
         else:
             st0 = init_batch(cfg, 1)
             fv0, _ff0 = self._fp_states(st0)
-            frontier, _ovf = jax.jit(self._deflate)(st0)
+            frontier, _ovf = self._program(self._deflate)(st0)
             frontier = jax.tree.map(
                 lambda x: _pad_axis0(x, self.chunk), frontier
             )
@@ -4297,7 +4284,7 @@ class JaxChecker(LevelOps):
                         self._trace(trace_levels, 0, 0),
                     ),
                 )
-            frontier, ovf0 = jax.jit(self._deflate)(st0)
+            frontier, ovf0 = self._program(self._deflate)(st0)
             if bool(jax.device_get(ovf0.any())):
                 raise RuntimeError(
                     f"initial state's message set exceeds cap_m={self.cap_m}"

@@ -65,6 +65,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..ops import tables as _tables
+
 U64 = jnp.uint64
 I64 = jnp.int64
 I32 = jnp.int32
@@ -144,6 +146,7 @@ def cached_program(cache: dict, key, build, stats: dict | None = None):
         cache.pop(next(iter(cache)))
     if stats is not None:
         stats["program_hits" if hit else "program_builds"] += 1
+        stats["table_bytes"] = prog.table_bytes
     return prog
 
 
@@ -153,9 +156,11 @@ def level_program_for(spec, donate: bool, stats: dict | None = None):
         # (the compile itself lands on the compile track when it runs)
         from ..obs import telemetry as _obs
 
+        prog = build_level_program(spec, donate)
         _obs.emit("program", kind="megakernel", chunk=spec.chunk,
-                  cap_x=spec.cap_x, cap_m=spec.cap_m)
-        return build_level_program(spec, donate)
+                  cap_x=spec.cap_x, cap_m=spec.cap_m,
+                  table_bytes=prog.table_bytes)
+        return prog
 
     return cached_program(_PROG_CACHE, (spec, bool(donate)), build, stats)
 
@@ -167,9 +172,10 @@ def fused_level_core(spec, frontier, slab, n_f, sieve, cap_out: int):
     the level semantics (same expand while_loop, same probe-and-insert,
     same materialize scan, same invariant reduce).
 
-    ``spec`` is the engine's ``LevelSpec``: the budgets and kernels
-    frozen when the program was built, so a rung traced later reads the
-    same ``chunk``/``cap_x`` as the first.  ``sieve`` is the spill
+    ``spec`` is the engine's ``LevelSpec`` bound to the program's
+    tables operand (``ops/tables.bound``): the budgets and kernels frozen
+    when the program was built, so a rung traced later reads the same
+    ``chunk``/``cap_x`` as the first.  ``sieve`` is the spill
     sieve's device word image (``u64[M]``, M a power of two; the 1-word
     all-zero sentinel while tiering is off) — fresh lanes are probed
     in-program (ops/sieve.py) and the hit count returned, so a level
@@ -307,10 +313,12 @@ def build_level_program(spec, donate: bool):
 
     Closes over the spec alone (never an engine), so a grown budget —
     a new spec — gets a new program through ``level_program_for``
-    while every rung of this one keeps its own budgets.  ``cap_out`` — the
-    new frontier's static capacity — is a static argument: the shape
-    ladder quantizes it through ``_frontier_cap`` and the AOT prewarmer
-    compiles the forecast rungs ahead of depth.
+    while every rung of this one keeps its own budgets.  The kernel's
+    device tables are the program's first operand, never captured
+    (``ops/tables.TabledProgram`` passes them; callers omit it).
+    ``cap_out`` — the new frontier's static capacity — is a static
+    argument: the shape ladder quantizes it through ``_frontier_cap``
+    and the AOT prewarmer compiles the forecast rungs ahead of depth.
 
     Returns outputs
       ``(new_frontier, slab2, ctrl i64[CTRL_LEN], mult i64[K],
@@ -323,10 +331,10 @@ def build_level_program(spec, donate: bool):
     K = spec.K
     slot_dt = jnp.uint16 if K <= 0xFFFF else jnp.uint32
 
-    def level_body(frontier, slab, n_f, sieve, cap_out: int):
+    def level_body(tables, frontier, slab, n_f, sieve, cap_out: int):
         (new_frontier, slab2, n_new, abort_at, ovf_x, ovf_slab, ovf_m,
          bad_global, mult, fps_out, pay_out, tier_hits) = fused_level_core(
-            spec, frontier, slab, n_f, sieve, cap_out
+            _tables.bound(spec, tables), frontier, slab, n_f, sieve, cap_out
         )
 
         ctrl = jnp.stack([
@@ -349,10 +357,10 @@ def build_level_program(spec, donate: bool):
             outs = outs + (frontier,)
         return outs
 
-    return jax.jit(
-        level_body,
+    return _tables.TabledProgram(
+        level_body, spec.kern.device_tables,
         static_argnames=("cap_out",),
-        donate_argnums=(0,) if donate else (),
+        donate_argnums=(1,) if donate else (),
     )
 
 

@@ -62,6 +62,7 @@ import numpy as np
 
 from . import forecast
 from . import megakernel as mk
+from ..ops import tables as _tables
 
 U64 = jnp.uint64
 I64 = jnp.int64
@@ -155,9 +156,11 @@ def superstep_program_for(spec, span: int, donate: bool,
         # flight-recorder breadcrumb (see megakernel.level_program_for)
         from ..obs import telemetry as _obs
 
+        prog = build_superstep_program(spec, span, donate)
         _obs.emit("program", kind="superstep", span=int(span),
-                  chunk=spec.chunk, cap_x=spec.cap_x, cap_m=spec.cap_m)
-        return build_superstep_program(spec, span, donate)
+                  chunk=spec.chunk, cap_x=spec.cap_x, cap_m=spec.cap_m,
+                  table_bytes=prog.table_bytes)
+        return prog
 
     return mk.cached_program(
         _PROG_CACHE, (spec, int(span), bool(donate)), build, stats
@@ -166,7 +169,8 @@ def superstep_program_for(spec, span: int, donate: bool,
 
 def build_superstep_program(spec, span: int, donate: bool):
     """The jitted N-level driver for one ``LevelSpec`` (closes over the
-    spec alone, never an engine — see megakernel.build_level_program).
+    spec alone, never an engine, and takes the kernel's tables as its
+    first operand — see megakernel.build_level_program).
 
     Static arguments: ``cap_f`` (the one frontier capacity every level
     of the superstep runs at — a chunk multiple >= the input frontier's
@@ -191,8 +195,9 @@ def build_superstep_program(spec, span: int, donate: bool):
     span = int(span)
     slot_dt = jnp.uint16 if K <= 0xFFFF else jnp.uint32
 
-    def superstep_body(frontier, slab, n_f, lvl_cap, sieve, cap_f: int,
-                       ring: int):
+    def superstep_body(tables, frontier, slab, n_f, lvl_cap, sieve,
+                       cap_f: int, ring: int):
+        ops = _tables.bound(spec, tables)
         cap_in = frontier.voted_for.shape[0]
         if cap_in > cap_f or cap_f % chunk or ring < 1:
             raise RuntimeError(
@@ -231,7 +236,7 @@ def build_superstep_program(spec, span: int, donate: bool):
              mm) = c
             (new_fr, slab2, n_new, abort_at, ovf_x, ovf_slab, ovf_m,
              bad, mult, fps_out, pay_out, tier_hits) = mk.fused_level_core(
-                spec, fr, slab, n_f, sieve, cap_f
+                ops, fr, slab, n_f, sieve, cap_f
             )
             abort = abort_at < n_f
             ovf_out = n_new > cap_f  # next frontier cannot seat
@@ -315,10 +320,10 @@ def build_superstep_program(spec, span: int, donate: bool):
         ])
         return fr, slab, ctrl, mn, mm, rf, rp, rs
 
-    return jax.jit(
-        superstep_body,
+    return _tables.TabledProgram(
+        superstep_body, spec.kern.device_tables,
         static_argnames=("cap_f", "ring"),
-        donate_argnums=(0,) if donate else (),
+        donate_argnums=(1,) if donate else (),
     )
 
 

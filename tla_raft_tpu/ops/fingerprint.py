@@ -56,6 +56,7 @@ import numpy as np
 
 from ..config import RaftConfig
 from .msg_universe import MsgUniverse, get_universe
+from . import tables as _tables
 
 _SEED = 0x7C3A_11E5
 FP_SENTINEL = np.uint64(0xFFFFFFFFFFFFFFFF)
@@ -374,6 +375,12 @@ class Fingerprinter:
         oh[rows, cols] = 1.0
         self._ppfold = jnp.asarray(oh)  # f32 [P, NP*NP]
 
+    def bind(self, arrays: dict, orbit: dict | None = None) -> "Fingerprinter":
+        """A view reading ``arrays`` (and ``orbit``'s canonical-relabel
+        tables) in place of its own device tables (``ops/tables.py``)."""
+        extra = {} if orbit is None else {"orbit_tables": orbit}
+        return _tables.view(self, arrays, **extra)
+
     # -- the ONE definition of the computed message coefficient ------------
 
     def raw_msg_coef(self, ids):
@@ -520,10 +527,12 @@ class Fingerprinter:
         ``msum`` (the message-set hash partial) is returned so successor
         fingerprints can be computed incrementally from it.
         """
-        feats = self.spec.features(st)
-        msum = self.msg_hash(st.msgs)
-        fp_view, fp_full = self.finalize(self.feat_hash(feats) + msum)
-        return fp_view, fp_full, msum
+        # the name groups the P-fold's device ops in a profile
+        with jax.named_scope("raft.fp.fold"):
+            feats = self.spec.features(st)
+            msum = self.msg_hash(st.msgs)
+            fp_view, fp_full = self.finalize(self.feat_hash(feats) + msum)
+            return fp_view, fp_full, msum
 
     def child_fingerprints(
         self, feats: jnp.ndarray, parent_msum: jnp.ndarray, ids: jnp.ndarray, live: jnp.ndarray
@@ -563,7 +572,7 @@ class Fingerprinter:
     # covariant function of the view projection.
 
     @functools.cached_property
-    def _orbit_tables(self):
+    def orbit_tables(self):
         """Device tables for the canonical-relabel path (built on demand)."""
         from .msg_universe import _dst_idx
 
@@ -613,13 +622,13 @@ class Fingerprinter:
         return dict(
             psi=jnp.asarray(psi), ppinv=jnp.asarray(ppinv),
             qidx=jnp.asarray(qidx), W=W, C0=C0, G0=G0,
-            fact=jnp.asarray(fact), NP=NP,
+            fact=jnp.asarray(fact),
         )
 
     def _orbit_pairh(self, bits):
         """Per-(src,dst)-pair message multiset hash: i8[..., M] -> u32[..., NP]."""
-        tb = self._orbit_tables
-        NP = tb["NP"]
+        tb = self.orbit_tables
+        NP = self.cfg.S * (self.cfg.S - 1)
         lead = bits.shape[:-1]
         acc = jnp.zeros((*lead, NP), jnp.int32)
         for (off, stride), W in zip(
@@ -634,7 +643,7 @@ class Fingerprinter:
     def _orbit_colors(self, st, pairh):
         """View-covariant WL colors u32[..., S] (3 refinement rounds)."""
         u32, S, L = jnp.uint32, self.cfg.S, self.cfg.L
-        tb = self._orbit_tables
+        tb = self.orbit_tables
         ct = st.current_term.astype(u32)
         role = st.role.astype(u32)
         ll = st.log_len.astype(u32)
@@ -695,7 +704,7 @@ class Fingerprinter:
         ``server_perms()`` (itertools lexicographic order) is the Lehmer
         rank of the image sequence.  Only meaningful where ``discrete``.
         """
-        tb = self._orbit_tables
+        tb = self.orbit_tables
         ci = colors[..., :, None]
         cj = colors[..., None, :]
         S = self.cfg.S
@@ -727,7 +736,7 @@ class Fingerprinter:
         hash evaluated at the canonical perm (bit-identical to that
         column of the folded table path — asserted in tests/test_orbit).
         """
-        tb = self._orbit_tables
+        tb = self.orbit_tables
         bits = self.unpack_bits(st.msgs)
         pairh = self._orbit_pairh(bits)
         colors = self._orbit_colors(st, pairh)
@@ -741,7 +750,7 @@ class Fingerprinter:
         # an id moves under a server perm, so permute the q axis of each
         # type block by the inverse pair map and hash against base coeffs
         ppinv_row = tb["ppinv"][rank]  # [..., NP]
-        NP = tb["NP"]
+        NP = self.cfg.S * (self.cfg.S - 1)
         parts = []
         for off, stride in zip(self.uni.type_offsets, self.uni.type_strides):
             bt = jax.lax.slice_in_dim(

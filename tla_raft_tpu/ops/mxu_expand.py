@@ -60,6 +60,7 @@ import os
 
 import numpy as np
 
+import jax
 import jax.numpy as jnp
 
 from ..config import CANDIDATE, FOLLOWER, LEADER
@@ -405,11 +406,13 @@ class MXUExpand:
         factors partition exactly the conjuncts of each scalar guard.
         """
         t = self.tables
-        msg_ok, mult, abort = self.dense.msg_guard_parts(st)
-        phi = self._guard_features(st)
-        cnt = jnp.einsum("bf,fk->bk", phi, t.W)
-        static_ok = (cnt == t.theta[None, :]) & t.slot_ok[None, :]
-        return static_ok & msg_ok, mult, abort
+        # the name groups the guard pass's device ops in a profile
+        with jax.named_scope("raft.guards"):
+            msg_ok, mult, abort = self.dense.msg_guard_parts(st)
+            phi = self._guard_features(st)
+            cnt = jnp.einsum("bf,fk->bk", phi, t.W)
+            static_ok = (cnt == t.theta[None, :]) & t.slot_ok[None, :]
+            return static_ok & msg_ok, mult, abort
 
     # ---- pass 2: materialize as select-matrix products ------------------
 

@@ -67,6 +67,7 @@ from ..models.raft import RaftState
 from .dense_expand import DenseExpand
 from .fingerprint import Fingerprinter, get_fingerprinter
 from .msg_universe import get_universe
+from . import tables as _tables
 from .mxu_expand import MXUExpand, mxu_enabled_by_env
 
 I32 = jnp.int32
@@ -310,13 +311,6 @@ class SuccessorKernel:
         # pass-1 expand: dense/tensorized formulation (ops/dense_expand.py);
         # the scalar vmap formulation is kept as the differential reference
         self.dense = DenseExpand(cfg, self.uni, self.fpr)
-        self.expand = jax.jit(self._expand_dense)
-        self.expand_reference = jax.jit(self._expand)
-        # legacy guards/materialize kernels, always jitted: the A/B
-        # reference the MXU parity gates and the probe microbench diff
-        self.expand_guards_legacy = jax.jit(self._expand_guards)
-        self.materialize_legacy = jax.jit(self._materialize)
-        self.materialize_added_legacy = jax.jit(self._materialize_added)
         if mxu is None:
             mxu = mxu_enabled_by_env()
         self.use_mxu = bool(mxu)
@@ -324,13 +318,68 @@ class SuccessorKernel:
         if self.use_mxu:
             self.mxu = MXUExpand(self)
             self.tables.mxu = self.mxu.tables  # GuardTables extension
-            self.expand_guards = jax.jit(self._expand_guards_mxu)
-            self.materialize = jax.jit(self.mxu.materialize)
-            self.materialize_added = jax.jit(self.mxu.materialize_added)
+        self.device_tables = self._device_tables()
+        # the kernels as programs of their own (tests, the audit, the
+        # cost probes; the engine's programs trace the methods inline).
+        # The legacy guards/materialize kernels are always built: the
+        # A/B reference the MXU parity gates and the probe microbench
+        # diff.  Off the MXU path the default kernels ARE the legacy ones.
+        for name, fn in self._kernels().items():
+            setattr(self, name, _kernel_program(self, name, fn.__name__))
+
+    def _kernels(self) -> dict:
+        """Kernel name -> the method of this kernel (or view) it runs."""
+        k = dict(
+            expand=self._expand_dense,
+            expand_reference=self._expand,
+            expand_guards_legacy=self._expand_guards,
+            materialize_legacy=self._materialize,
+            materialize_added_legacy=self._materialize_added,
+        )
+        if self.use_mxu:
+            k.update(expand_guards=self._expand_guards_mxu,
+                     materialize=self.mxu.materialize,
+                     materialize_added=self.mxu.materialize_added)
         else:
-            self.expand_guards = self.expand_guards_legacy
-            self.materialize = self.materialize_legacy
-            self.materialize_added = self.materialize_added_legacy
+            k.update(expand_guards=self._expand_guards,
+                     materialize=self._materialize,
+                     materialize_added=self._materialize_added)
+        return k
+
+    def _device_tables(self, orbit: bool = False) -> _tables.KernelTables:
+        return _tables.KernelTables(
+            kern=_tables.owned(self),
+            guard=_tables.owned(self.tables),
+            mxu=_tables.owned(self.mxu.tables) if self.mxu else {},
+            dense=_tables.owned(self.dense),
+            fpr=_tables.owned(self.fpr),
+            orbit=self.fpr.orbit_tables if orbit else None,
+        )
+
+    @functools.cached_property
+    def orbit_device_tables(self) -> _tables.KernelTables:
+        """``device_tables`` plus the orbit-pruning tables (built on
+        first use: only orbit checkers read them)."""
+        return self._device_tables(orbit=True)
+
+    def bind(self, tables: _tables.KernelTables) -> "SuccessorKernel":
+        """This kernel as a traced program sees it: a view whose device
+        arrays, and those of its fingerprinter, guard tables, dense and
+        MXU expands, are ``tables``' leaves (the program's operand), and
+        whose kernels are the plain methods of the view, traced inline.
+        """
+        fpr = self.fpr.bind(tables.fpr, tables.orbit)
+        dense = _tables.view(self.dense, tables.dense, fpr=fpr)
+        guard = _tables.view(self.tables, tables.guard)
+        kern = _tables.view(self, tables.kern, fpr=fpr, dense=dense,
+                            tables=guard)
+        if self.mxu is not None:
+            guard.mxu = _tables.view(self.mxu.tables, tables.mxu)
+            kern.mxu = _tables.view(self.mxu, {}, tables=guard.mxu,
+                                    dense=dense)
+        for name, fn in kern._kernels().items():
+            setattr(kern, name, fn)
+        return kern
 
     def _expand_dense(self, st: RaftState, msum: jnp.ndarray) -> Expansion:
         valid, mult, fpv, fpf, abort = self.dense(st, msum)
@@ -740,6 +789,16 @@ class SuccessorKernel:
         a per-row top_k over the whole message universe (the measured
         dominator of the materialize pass, docs/PERF.md round 5)."""
         return jax.vmap(self._materialize_one)(parents, slots)
+
+
+def _kernel_program(kern: SuccessorKernel, name: str, fn_name: str):
+    """The kernel ``name`` as a program over the kernel's tables."""
+
+    def run(tables, *args):
+        return getattr(kern.bind(tables), name)(*args)
+
+    run.__name__ = run.__qualname__ = fn_name
+    return _tables.TabledProgram(run, kern.device_tables)
 
 
 @functools.lru_cache(maxsize=8)

@@ -62,6 +62,7 @@ from ..engine.bfs import _compact_payloads
 from ..engine.forecast import pow2ceil
 from ..engine.invariants import resolve_invariant_kernel
 from ..ops import hashstore, radix
+from ..ops import tables as _tables
 from ..ops.radix import stable_order, true_first
 from ..models.raft import RaftState, init_batch, to_oracle
 from ..ops.successor import get_kernel
@@ -382,6 +383,12 @@ class ShardedChecker:
         self.fpr = self.kern.fpr
         self.K = self.kern.K
         self.D = mesh.devices.size
+        # the mesh programs take the kernel's device tables as their
+        # first operand (ops/tables.py), replicated on every device
+        # graftlint: waive[GL006] — one-time upload at construction
+        self.kernel_tables = jax.device_put(
+            self.kern.device_tables, NamedSharding(mesh, P())
+        )
         if cap_x is None:
             # deep mode: a segment round routes ~4 candidates per parent
             # (3.9 on the reference config, PR 21), so 8 x seg_rows
@@ -413,6 +420,16 @@ class ShardedChecker:
         self.skew = resilience.integrity.SkewMeter(self.D)
         # per-level hang watchdog (resilience/elastic.py); None = off
         self.watchdog = watchdog
+
+    def _mesh_program(self, body, in_specs, out_specs):
+        """The method ``body`` as a jitted mesh program whose first
+        operand is the replicated kernel tables (ops/tables.py)."""
+        return _tables.method_program(
+            body, self.kernel_tables,
+            wrap=lambda fn: _shard_map(
+                fn, self.mesh, (P(),) + tuple(in_specs), out_specs
+            ),
+        )
 
     def _store_budget_entries(self) -> int:
         """Per-owner in-RAM entry budget of the external stores (0 =
@@ -891,32 +908,26 @@ class ShardedChecker:
     @functools.cached_property
     def level_phase1(self):
         spec_state = jax.tree.map(lambda _: P("d"), init_batch(self.cfg, 1))
-        return jax.jit(
-            _shard_map(
-                self._body_a2a_phase1,
-                self.mesh,
-                (spec_state, P("d"), P("d")),
-                Phase1Out(
-                    P("d"), P("d"), P("d"), P("d"), P("d"), P("d"),
-                    P(), P(), P("d"), P(), P(),
-                ),
-            )
+        return self._mesh_program(
+            self._body_a2a_phase1,
+            (spec_state, P("d"), P("d")),
+            Phase1Out(
+                P("d"), P("d"), P("d"), P("d"), P("d"), P("d"),
+                P(), P(), P("d"), P(), P(),
+            ),
         )
 
     @functools.cached_property
     def level_phase2(self):
         spec_state = jax.tree.map(lambda _: P("d"), init_batch(self.cfg, 1))
-        return jax.jit(
-            _shard_map(
-                self._body_a2a_phase2,
-                self.mesh,
-                (spec_state, P("d"), P("d"), P("d"), P("d")),
-                Phase2Out(
-                    jax.tree.map(lambda _: P("d"), init_batch(self.cfg, 1)),
-                    P("d"), P("d"), P(), P("d"), P("d"), P(), P("d"),
-                    P(), P(),
-                ),
-            )
+        return self._mesh_program(
+            self._body_a2a_phase2,
+            (spec_state, P("d"), P("d"), P("d"), P("d")),
+            Phase2Out(
+                jax.tree.map(lambda _: P("d"), init_batch(self.cfg, 1)),
+                P("d"), P("d"), P(), P("d"), P("d"), P(), P("d"),
+                P(), P(),
+            ),
         )
 
     def _hosted_level(self, frontier, msum, n_f):
@@ -1392,16 +1403,13 @@ class ShardedChecker:
             spec_state = jax.tree.map(
                 lambda _: P("d"), init_batch(self.cfg, 1)
             )
-            return jax.jit(
-                _shard_map(
-                    self._deep_phase1_body,
-                    self.mesh,
-                    (spec_state, P("d"), P(), P(), P("d")),
-                    Phase1DeepOut(
-                        P("d"), P("d"), P("d"), P("d"), P("d"),
-                        P(), P(), P("d"), P(), P(), P(), P(),
-                    ),
-                )
+            return self._mesh_program(
+                self._deep_phase1_body,
+                (spec_state, P("d"), P(), P(), P("d")),
+                Phase1DeepOut(
+                    P("d"), P("d"), P("d"), P("d"), P("d"),
+                    P(), P(), P("d"), P(), P(), P(), P(),
+                ),
             )
 
         return self._dprog("p1", build)
@@ -1460,20 +1468,17 @@ class ShardedChecker:
             spec_state = jax.tree.map(
                 lambda _: P("d"), init_batch(self.cfg, 1)
             )
-            return jax.jit(
-                _shard_map(
-                    self._deep_phase2_body,
-                    self.mesh,
-                    (spec_state, P("d"), P("d"), P(None, "d"), P(),
-                     P(), P()),
-                    Phase2Out(
-                        jax.tree.map(
-                            lambda _: P("d"), init_batch(self.cfg, 1)
-                        ),
-                        P("d"), P("d"), P(), P("d"), P("d"), P(), P("d"),
-                        P(), P(),
+            return self._mesh_program(
+                self._deep_phase2_body,
+                (spec_state, P("d"), P("d"), P(None, "d"), P(),
+                 P(), P()),
+                Phase2Out(
+                    jax.tree.map(
+                        lambda _: P("d"), init_batch(self.cfg, 1)
                     ),
-                )
+                    P("d"), P("d"), P(), P("d"), P("d"), P(), P("d"),
+                    P(), P(),
+                ),
             )
 
         return self._dprog("p2", build)
@@ -2315,18 +2320,15 @@ class ShardedChecker:
         )
         spec_state = jax.tree.map(lambda _: P("d"), init_batch(self.cfg, 1))
         vspec = P("d") if self.exchange == "all_to_all" else P()
-        return jax.jit(
-            _shard_map(
-                body,
-                self.mesh,
-                (spec_state, P("d"), P("d"), vspec),
-                LevelOut(
-                    jax.tree.map(lambda _: P("d"), init_batch(self.cfg, 1)),
-                    P("d"), vspec, P("d"), P(), P(), P(),
-                    P("d"), P("d"), P(), P("d"), P(), P("d"), P(), P(),
-                    P(),
-                ),
-            )
+        return self._mesh_program(
+            body,
+            (spec_state, P("d"), P("d"), vspec),
+            LevelOut(
+                jax.tree.map(lambda _: P("d"), init_batch(self.cfg, 1)),
+                P("d"), vspec, P("d"), P(), P(), P(),
+                P("d"), P("d"), P(), P("d"), P(), P("d"), P(), P(),
+                P(),
+            ),
         )
 
     # -- trace replay (slot chains are device-agnostic) --------------------

@@ -73,6 +73,7 @@ from ..engine import superstep as graft_superstep
 from ..engine.invariants import resolve_invariant_kernel
 from ..models.raft import RaftState, init_batch
 from ..ops import hashstore
+from ..ops import tables as _tables
 from ..ops.hashstore import SENT
 from ..ops.mxu_expand import mxu_enabled_by_env
 from ..ops.successor import get_kernel
@@ -140,13 +141,15 @@ class BucketPrograms:
             (name, resolve_invariant_kernel(name))
             for name in kcfg.invariants
         ]
-        self.step = jax.jit(self._level_step)
-        self.mat = jax.jit(self._mat_step)
+        # every program takes the kernel's device tables as its first
+        # operand and traces on a view bound to them (ops/tables.py)
+        self.step = self._program(self._level_step)
+        self.mat = self._program(self._mat_step)
         # whole-level fusion (the service slice of the megakernel,
         # engine/megakernel.py): step + on-device survivor-lane
         # compaction + materialize + invariant scan as ONE program —
         # a bucket level becomes one dispatch + one fused fetch
-        self.fused = jax.jit(
+        self.fused = self._program(
             self._fused_level, static_argnames=("g_cap",)
         )
         # multi-level resident superstep (the service slice of
@@ -155,15 +158,20 @@ class BucketPrograms:
         # retirement (depth caps, aborts, fixpoints) tracked ON DEVICE
         # and per-level ledgers spooled into a ring — small configs
         # retire whole jobs in one or two dispatches
-        self.sstep = jax.jit(
+        self.sstep = self._program(
             self._superstep, static_argnames=("g_cap", "span", "ring")
         )
-        self.inv_ok = jax.jit(self._inv_ok)
+        self.inv_ok = self._program(self._inv_ok)
         # shape keys seen by the jitted entry points — the honest
         # "programs traced" ledger behind the bench's
         # configs-per-compile stat (jax's jit cache is keyed on
         # exactly these abstract shapes)
         self.shape_keys: set = set()
+
+    def _program(self, method, **jit_kwargs):
+        return _tables.method_program(
+            method, self.kern.device_tables, **jit_kwargs
+        )
 
     # -- traced bodies -----------------------------------------------------
 
