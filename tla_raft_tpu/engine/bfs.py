@@ -963,9 +963,12 @@ class JaxChecker(LevelOps):
         # program_hits/program_builds: this engine's lookups in the
         # shared program caches (megakernel/superstep ``program_for``);
         # table_bytes: the kernel tables those programs take as operand
+        # probe_lanes/lane_budget: candidate lanes the fused programs'
+        # probe-and-insert ran over, and the lanes their buffers hold
         self._mega_stats = dict(
             levels=0, redo_out=0, redo_x=0, redo_slab=0, redo_m=0,
             program_hits=0, program_builds=0, table_bytes=0,
+            probe_lanes=0, lane_budget=0,
         )
         # multi-level resident supersteps (engine/superstep.py): run up
         # to N fused levels inside ONE device program + ONE ledgered
@@ -983,6 +986,7 @@ class JaxChecker(LevelOps):
         self._ss_stats = dict(
             supersteps=0, levels=0, stops=0, ring_stops=0,
             program_hits=0, program_builds=0, table_bytes=0,
+            probe_lanes=0, lane_budget=0,
         )
         self._ss_sig = None  # declared superstep static-shape signature
         self._degraded_visited = None  # sorted store handoff on degrade
@@ -1325,6 +1329,8 @@ class JaxChecker(LevelOps):
             ctrl, mult_np, fps_np, pidx_np, slot_np = tail.get()
             ctrl = np.asarray(ctrl, np.int64)
             n_new = int(ctrl[mk.CTRL_N_NEW])
+            self._mega_stats["probe_lanes"] += int(ctrl[mk.CTRL_PROBE_LANES])
+            self._mega_stats["lane_budget"] += n_lanes
             if ctrl[mk.CTRL_OVF_SLAB]:
                 self._hs_pending = None
                 try:
@@ -1810,7 +1816,8 @@ class JaxChecker(LevelOps):
         (fr_out, slab_out, ctrl_d, mn_d, mm_d, rf_d, rp_d,
          rs_d) = outs
         graft_sanitize.note_dispatch("superstep.levels")
-        self._san_lanes = (cap_f // self.chunk) * self.cap_x
+        lanes = (cap_f // self.chunk) * self.cap_x
+        self._san_lanes = lanes
         # THE superstep fetch: control vector + per-level meta + the
         # trace/delta ring in one ledgered get through the pipeline's
         # deferred path (transfer ledger, pipeline.window fault site
@@ -1830,6 +1837,10 @@ class JaxChecker(LevelOps):
                 self._ss_stats["stops"] += 1
             elif reason == "ring":
                 self._ss_stats["ring_stops"] += 1
+            # a stopped or ring-stopped level ran without committing
+            ran = len(recs) + (reason in ("stop", "ring"))
+            self._ss_stats["probe_lanes"] += int(ctrl[ss.SS_PROBE_LANES])
+            self._ss_stats["lane_budget"] += ran * lanes
         return dict(
             recs=recs,
             frontier=fr_out,

@@ -18,11 +18,12 @@ Tensor Cores" (PAPERS.md) use to keep BFS resident on the accelerator:
    ``_expand_chunk_impl`` body (MXU guards + compact + materialize +
    fingerprints) and lands its cap_x compacted candidates in a
    preallocated lane buffer via ``dynamic_update_slice``;
-2. **fused hashstore probe-and-insert** over the whole level's lanes
-   (ops/hashstore.py ``probe_and_insert_impl`` — uniqueness, visited
-   membership and the store update in one pass, the min-(fp_full,
-   payload) representative per view fingerprint preserved, so counts
-   stay bit-identical to the staged path);
+2. **fused hashstore probe-and-insert** over the level's live lanes
+   (ops/hashstore.py ``probe_and_insert_impl``'s rounds, looped over
+   the live chunks' lane blocks by ``probe_live_prefix`` — uniqueness,
+   visited membership and the store update in one pass, the
+   min-(fp_full, payload) representative per view fingerprint
+   preserved, so counts stay bit-identical to the staged path);
 3. **materialize** of the fresh frontier as a ``lax.scan`` over
    slice-bounded ``_mat_slice_impl`` bodies (the transient message-set
    inflate stays slice-sized, exactly the staged path's memory bound);
@@ -87,7 +88,9 @@ CTRL_SLAB_LIVE = 6  # live slots of the pending slab (= distinct', free
 CTRL_TIER_HITS = 7  # fresh lanes the spill sieve flagged as POSSIBLE
 #                     generation revisits (0 = provably none: the level
 #                     can commit without any host tier correction)
-CTRL_LEN = 8
+CTRL_PROBE_LANES = 8  # candidate lanes the probe-and-insert ran over
+#                       (probe_live_prefix), of the host-known budget
+CTRL_LEN = 9
 
 
 def enabled_by_env() -> bool:
@@ -165,6 +168,144 @@ def level_program_for(spec, donate: bool, stats: dict | None = None):
     return cached_program(_PROG_CACHE, (spec, bool(donate)), build, stats)
 
 
+def probe_live_prefix(slab, cv_buf, cf_buf, cp_buf, n_f, chunk: int,
+                      cap_x: int, cap_out: int):
+    """Probe-and-insert plus survivor compaction over the level's live
+    candidate lanes only: ``hashstore.probe_and_insert_impl`` followed by
+    ``compact_fresh``, round for round, one chunk's lanes at a time.
+
+    The chunked expand writes chunk i's candidates to lanes
+    ``[i*cap_x, (i+1)*cap_x)`` only while ``i*chunk < n_f``, so every
+    lane past ``ceil(n_f / chunk) * cap_x`` is SENT, and SENT lanes are
+    inert in every phase (never pending, never in a scatter-min, never
+    fresh).  Each phase therefore runs as a data-bounded loop over the
+    live lane blocks, and no gather or scatter touches a dead block:
+
+    - a claim round probes every block against the round's slab, then
+      scatter-mins every block's claims, then checks every block's
+      claims against the result, as the whole-buffer round does;
+    - the pre-call probe and both representative scatter-mins
+      (min-(fp_full, payload)) are per lane or commutative;
+    - the compaction carries the running fresh count across blocks.
+
+    So the slab layout, the fresh lanes, ``n_new`` and the overflow are
+    the whole buffer's, bit for bit (``tests/test_probe_prefix.py``).
+    The loop bodies are one block wide, so the program's code does not
+    grow with the lane budget: XLA:TPU's code for these phases does, and
+    a loaded program's code sits in HBM.  Fresh lanes compact to a
+    prefix in LANE (= payload-ascending) order, the staged path's exact
+    contract.  Returns ``(slab2, n_new i64, ovf_slab bool,
+    fps_out u64[cap_out], pay_out i64[cap_out], probe_lanes i64)``.
+    """
+    from ..ops import hashstore as hs
+
+    cap = slab.shape[0]
+    shape = (cv_buf.shape[0] // cap_x, cap_x)
+    fps = cv_buf.reshape(shape)
+    keys = cf_buf.reshape(shape)
+    pays = cp_buf.reshape(shape)
+    live = fps != SENT
+    n_blk = jnp.minimum((n_f + chunk - 1) // chunk, shape[0]).astype(I32)
+
+    def blocks(body, init):
+        return jax.lax.fori_loop(jnp.zeros((), I32), n_blk, body, init)
+
+    def lanes(dtype, fill=0):
+        return jnp.full(shape, fill, dtype)
+
+    def put(a, b, row):
+        return jax.lax.dynamic_update_index_in_dim(a, row, b, 0)
+
+    def probe(table, b, mask):
+        return hs._probe_rounds(
+            table, jnp.where(mask[b], fps[b], SENT), hs.PROBE_DEPTH
+        )
+
+    # -- claim rounds (hashstore._claim_loop)
+    def claim_round(c):
+        table, pending, slot, ovf = c
+
+        def walk(b, acc):
+            return tuple(
+                put(a, b, r) for a, r in zip(acc, probe(table, b, pending))
+            )
+
+        idx, found, settled = blocks(
+            walk, (lanes(I32), lanes(bool), lanes(bool))
+        )
+        slot = jnp.where(pending & found, idx, slot)
+        want = pending & ~found & settled
+
+        def claim(b, t):
+            return t.at[jnp.where(want[b], idx[b], cap)].min(
+                jnp.where(want[b], fps[b], SENT), mode="drop"
+            )
+
+        table = blocks(claim, table)
+
+        def check(b, got):
+            at = table[jnp.clip(idx[b], 0, cap - 1)]
+            return put(got, b, want[b] & (at == fps[b]))
+
+        got = blocks(check, lanes(bool))
+        slot = jnp.where(got, idx, slot)
+        dead = pending & ~found & ~settled  # probe-depth overflow
+        return (table, pending & ~found & ~got & ~dead, slot,
+                ovf | dead.any())
+
+    slab2, _pending, slot, ovf = jax.lax.while_loop(
+        lambda c: c[1].any(), claim_round,
+        (slab, live, lanes(I32), jnp.zeros((), bool)),
+    )
+    slot_c = jnp.clip(slot, 0, cap - 1)
+
+    # -- representative per new group (probe_and_insert_impl): a group
+    # is new iff its fp was absent from the PRE-CALL slab; then the
+    # min key, then the min payload among the key's lanes
+    def phase1(b, acc):
+        grp_new, m1 = acc
+        g = live[b] & ~probe(slab, b, live)[1]
+        m1 = m1.at[jnp.where(g, slot[b], cap)].min(
+            jnp.where(g, keys[b], SENT), mode="drop"
+        )
+        return put(grp_new, b, g), m1
+
+    grp_new, m1 = blocks(
+        phase1, (lanes(bool), jnp.full((cap,), SENT, U64))
+    )
+    is1 = blocks(
+        lambda b, a: put(a, b, grp_new[b] & (m1[slot_c[b]] == keys[b])),
+        lanes(bool),
+    )
+    # m1 is dead before m2 exists: one slab-sized scratch at a time
+    m2 = blocks(
+        lambda b, m: m.at[jnp.where(is1[b], slot[b], cap)].min(
+            jnp.where(is1[b], pays[b], hs.BIGP), mode="drop"
+        ),
+        jnp.full((cap,), hs.BIGP, I64),
+    )
+
+    # -- survivor compaction (hashstore.compact_fresh), straight into
+    # the cap_out seats: lanes past cap_out drop, as the slice did
+    def compact(b, acc):
+        n_new, out_f, out_p = acc
+        fresh = is1[b] & (m2[slot_c[b]] == pays[b])
+        tgt = jnp.where(fresh, n_new + jnp.cumsum(fresh) - 1, cap_out)
+        return (
+            n_new + fresh.sum(),
+            out_f.at[tgt].set(fps[b], mode="drop"),
+            out_p.at[tgt].set(pays[b], mode="drop"),
+        )
+
+    n_new, fps_out, pay_out = blocks(compact, (
+        jnp.zeros((), I64),
+        jnp.full((cap_out,), SENT, U64),
+        jnp.full((cap_out,), -1, I64),
+    ))
+    return (slab2, n_new, ovf, fps_out, pay_out,
+            n_blk.astype(I64) * cap_x)
+
+
 def fused_level_core(spec, frontier, slab, n_f, sieve, cap_out: int):
     """The traced body of ONE fused BFS level — the shared core both the
     per-level program below and the multi-level superstep driver
@@ -183,11 +324,11 @@ def fused_level_core(spec, frontier, slab, n_f, sieve, cap_out: int):
     ``(new_frontier [cap_out], slab2, n_new i64, abort_at i64,
        ovf_x bool, ovf_slab bool, ovf_m bool, bad_global i64,
        mult i64[K], fps_out u64[cap_out], pay_out i64[cap_out],
-       tier_hits i64)``
+       tier_hits i64, probe_lanes i64)``
     with ``pay_out`` the survivors' raw payloads (pidx*K+slot) in lane
-    (= payload-ascending) order.
+    (= payload-ascending) order and ``probe_lanes`` the candidate lanes
+    the probe-and-insert ran over (``probe_live_prefix``).
     """
-    from ..ops import hashstore
     from ..ops import sieve as sieve_mod
 
     K = spec.K
@@ -234,25 +375,11 @@ def fused_level_core(spec, frontier, slab, n_f, sieve, cap_out: int):
     (_i, cv_buf, cf_buf, cp_buf, mult, abort_at,
      ovf_x) = jax.lax.while_loop(cond, body, init)
 
-    # -- 2. fused probe-and-insert: uniqueness + membership + store
-    # update in one pass; fresh lanes compact to a prefix in LANE
-    # (= payload-ascending) order, the staged path's exact contract
-    slab2, fresh, n_new, ovf_slab = hashstore.probe_and_insert_impl(
-        slab, cv_buf, cf_buf, cp_buf
+    # -- 2. fused probe-and-insert over the live lane prefix
+    (slab2, n_new, ovf_slab, fps_out, pay_out,
+     probe_lanes) = probe_live_prefix(
+        slab, cv_buf, cf_buf, cp_buf, n_f, chunk, cap_x, cap_out
     )
-    new_fps, new_pay = hashstore.compact_fresh(fresh, cv_buf, cp_buf, N)
-    if cap_out > N:
-        # tiny cap_x configs: the frontier-capacity quantizer's
-        # >= chunk floor can exceed the lane budget — pad with dead
-        # lanes (n_new <= N always, so nothing real is cut)
-        new_fps = jnp.concatenate(
-            [new_fps, jnp.full((cap_out - N,), SENT, U64)]
-        )
-        new_pay = jnp.concatenate(
-            [new_pay, jnp.full((cap_out - N,), -1, I64)]
-        )
-    fps_out = new_fps[:cap_out]
-    pay_out = new_pay[:cap_out]
 
     # -- spill-sieve probe over the fresh lanes: a definite-miss never
     # leaves the device; dead (SENT-padded) lanes can never count (a
@@ -305,7 +432,8 @@ def fused_level_core(spec, frontier, slab, n_f, sieve, cap_out: int):
     bad_global = jnp.where(bad_min >= BIG, jnp.asarray(-1, I64), bad_min)
 
     return (new_frontier, slab2, n_new, abort_at, ovf_x, ovf_slab,
-            ovf_ms.any(), bad_global, mult, fps_out, pay_out, tier_hits)
+            ovf_ms.any(), bad_global, mult, fps_out, pay_out, tier_hits,
+            probe_lanes)
 
 
 def build_level_program(spec, donate: bool):
@@ -333,7 +461,8 @@ def build_level_program(spec, donate: bool):
 
     def level_body(tables, frontier, slab, n_f, sieve, cap_out: int):
         (new_frontier, slab2, n_new, abort_at, ovf_x, ovf_slab, ovf_m,
-         bad_global, mult, fps_out, pay_out, tier_hits) = fused_level_core(
+         bad_global, mult, fps_out, pay_out, tier_hits,
+         probe_lanes) = fused_level_core(
             _tables.bound(spec, tables), frontier, slab, n_f, sieve, cap_out
         )
 
@@ -346,6 +475,7 @@ def build_level_program(spec, donate: bool):
             bad_global,
             (slab2 != SENT).sum().astype(I64),
             tier_hits,
+            probe_lanes,
         ])
         pidx_out = (pay_out // K).astype(jnp.uint32)
         slot_out = (pay_out % K).astype(slot_dt)

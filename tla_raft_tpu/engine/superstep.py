@@ -36,13 +36,15 @@ toward ``generated``, matching the staged loop's break order).
 **Static shapes.**  One frontier capacity ``cap_f`` (forecast max over
 the span, quantized through the engine's one capacity ladder) seats
 every level of the superstep — the expand while_loop's trip count is
-data-bounded on the live ``n_f``, so overshoot costs nothing.  The
-ring capacity chains from the forecast cap_out sequence (one rung per
-level, margin-inflated, clamped at span * cap_f); ring appends are
-drop-mode scatters at a dynamic offset, so the high-water check is
-exact (off + n_new > R) and a stopped-for-ring level is clean — the
-next superstep simply restarts there with a fresh ring (a fresh ring
-always seats at least one level, so progress is guaranteed).
+data-bounded on the live ``n_f``, and so are the probe-and-insert's
+loops over the live lane blocks (``megakernel.probe_live_prefix``), so
+overshoot costs little.  The ring capacity chains from the forecast
+cap_out sequence (one rung per level, margin-inflated, clamped at
+span * cap_f); ring appends are drop-mode scatters at a dynamic
+offset, so the high-water check is exact (off + n_new > R) and a
+stopped-for-ring level is clean — the next superstep simply restarts
+there with a fresh ring (a fresh ring always seats at least one level,
+so progress is guaranteed).
 
 Default ON at span ``DEFAULT_SPAN`` wherever the per-level megakernel
 is eligible; ``TLA_RAFT_SUPERSTEP=N`` / ``--superstep N`` set the span
@@ -86,7 +88,9 @@ SS_FLAGS = 5      # the STOPPED level's cause bits (FLAG_* below) —
 #                   the host grows the overflowed budget BEFORE the
 #                   per-level replay, so a stopped level costs one
 #                   attempt + one redo exactly like the per-level path
-SS_LEN = 6
+SS_PROBE_LANES = 6  # candidate lanes probed, summed over every level
+#                     the loop ran (committed or not)
+SS_LEN = 7
 
 FLAG_OVF_X = 1      # a chunk overflowed its cap_x compaction budget
 FLAG_OVF_SLAB = 2   # a probe window filled (grow + redo)
@@ -233,9 +237,10 @@ def build_superstep_program(spec, span: int, donate: bool):
 
         def body(c):
             (lvl, off, _reason, _flags, n_f, fr, slab, rf, rp, rs, mn,
-             mm) = c
+             mm, probed) = c
             (new_fr, slab2, n_new, abort_at, ovf_x, ovf_slab, ovf_m,
-             bad, mult, fps_out, pay_out, tier_hits) = mk.fused_level_core(
+             bad, mult, fps_out, pay_out, tier_hits,
+             probe_lanes) = mk.fused_level_core(
                 ops, fr, slab, n_f, sieve, cap_f
             )
             abort = abort_at < n_f
@@ -292,6 +297,7 @@ def build_superstep_program(spec, span: int, donate: bool):
                 fr2,
                 sel(slab2, slab),
                 rf, rp, rs, mn, mm,
+                probed + probe_lanes,
             )
 
         init = (
@@ -307,9 +313,10 @@ def build_superstep_program(spec, span: int, donate: bool):
             jnp.zeros((R,), slot_dt),                # ring slot
             jnp.zeros((span,), I64),                 # meta n_new
             jnp.zeros((span, K), I64),               # meta mult
+            jnp.zeros((), I64),                      # lanes probed
         )
         (lvl, off, reason, flags, n_f_out, fr, slab, rf, rp, rs, mn,
-         mm) = jax.lax.while_loop(cond, body, init)
+         mm, probed) = jax.lax.while_loop(cond, body, init)
         ctrl = jnp.stack([
             lvl.astype(I64),
             reason.astype(I64),
@@ -317,6 +324,7 @@ def build_superstep_program(spec, span: int, donate: bool):
             off,
             (slab != SENT).sum().astype(I64),
             flags.astype(I64),
+            probed,
         ])
         return fr, slab, ctrl, mn, mm, rf, rp, rs
 
