@@ -77,7 +77,7 @@ def main() -> int:
     sys.path.insert(0, HERE)
     from importlib.metadata import version
 
-    from bench import GOLDEN_LEVELS
+    from tla_raft_tpu.oracle.golden import GOLDEN_LEVELS
     from tla_raft_tpu.platform import CACHE_DIR
 
     n_chips = 4 if args.four_chips else 1

@@ -14,8 +14,7 @@ Usage:
       [--mr-width 16] [--chunk 64] [--dry]
 
 Importable: ``synth_jobs(n, seed, mr_width)`` returns the job list
-(cfg, max_depth, options) without touching disk — bench.py's
-BENCH_SERVICE lever builds its A/B queues through it.
+(cfg, max_depth, options) without touching disk.
 """
 
 from __future__ import annotations

@@ -1,8 +1,8 @@
 """Native CPU baseline checker (native/cpubase.cpp) — differential tests.
 
 The baseline must reproduce the oracle's per-level counts exactly: it is
-both the honest `vs_baseline` denominator in bench.py and an independent
-third implementation re-verifying the golden record (BASELINE.md).
+an independent third implementation re-verifying the golden record
+(BASELINE.md, oracle/golden.py).
 """
 
 import json

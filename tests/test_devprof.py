@@ -1,11 +1,11 @@
-"""Device-cost observatory (analysis/devprof.py, analysis/cost_audit.py,
-obs/trend.py; docs/OBSERVABILITY.md "Device-side profiling").
+"""Device-cost observatory (analysis/devprof.py, analysis/cost_audit.py;
+docs/OBSERVABILITY.md "Device-side profiling").
 
 Lean fast tier (tier-1 sits near its 870 s gate on 1-core boxes): ONE
 tiny S2 engine run with telemetry + ``--profile 1`` is shared by every
 end-to-end row (program_profile emission, hbm block, profiler-merged
 trace validity), the GL013 rule units run on dict fixtures (no
-compile), the trend/regression/rotation rows are pure host units, and
+compile), the rotation rows are pure host units, and
 the counts-parity row reuses the jit caches the shared run warmed.
 The subprocess CLI profile smoke rides ``@slow`` (CI runs its twin).
 """
@@ -22,7 +22,6 @@ import pytest
 from tla_raft_tpu.config import RaftConfig
 from tla_raft_tpu.obs import telemetry as tel
 from tla_raft_tpu.obs import tracefile
-from tla_raft_tpu.obs import trend
 from tla_raft_tpu.obs.__main__ import summarize_events, _print_table
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -193,126 +192,6 @@ def test_gl013_seeded_regression():
         current={"_meta": meta, "k": dict(entry, flops=500.0)},
     )
     assert not f and any("bank the win" in x for x in w)
-
-
-# -- perf-trend subsystem (tentpole 4) ------------------------------------
-
-def _mk(round_no, metric="m", distinct=100, wall=10.0, rate=1000.0,
-        **kw):
-    return dict(schema=trend.SCHEMA, round=round_no, metric=metric,
-                config="cfg", distinct=distinct, generated=2 * distinct,
-                depth=5, wall_s=wall, rate=rate, parity=True, ok=True,
-                **kw)
-
-
-def test_trend_normalize_dialects():
-    # legacy wrapper
-    rec = trend.normalize(
-        {"n": 1, "cmd": "x", "rc": 0, "tail": "...",
-         "parsed": {"metric": "raft", "value": 42.0,
-                    "unit": "u", "distinct": 7, "wall_s": 1.0}},
-        round_no=1, source="BENCH_r01.json",
-    )
-    assert rec["round"] == 1 and rec["rate"] == 42.0
-    assert rec["distinct"] == 7
-    # a crashed legacy round (parsed null) normalizes to nothing
-    assert trend.normalize({"n": 1, "parsed": None}, round_no=3) is None
-    # canonical bench/1
-    rec = trend.normalize(
-        {"schema": "tla-raft-bench/1", "metric": "raft",
-         "steady_rate": 9.0, "wall_s": 2.0, "distinct": 5,
-         "levels_per_dispatch": 3.0}, round_no=6,
-    )
-    assert rec["rate"] == 9.0 and rec["levels_per_dispatch"] == 3.0
-    # A/B record: arms kept, first arm promoted
-    rec = trend.normalize(
-        {"schema": "tla-raft-bench-ab/1", "counts_bit_identical": True,
-         "distinct": 5,
-         "arms": {"on": {"wall_s": 1.0, "steady_rate": 10.0},
-                  "off": {"wall_s": 2.0, "steady_rate": 5.0}}},
-        round_no=9, source="BENCH_FOO_AB_r09.json",
-    )
-    assert rec["metric"] == "ab_foo" and rec["parity"] is True
-    assert rec["arms"]["off"]["rate"] == 5.0 and rec["wall_s"] == 1.0
-    assert trend.round_from_name("BENCH_r06.json") == 6
-
-
-def test_trend_regression_detection():
-    base = [_mk(1), _mk(2)]
-    # count drift = hard
-    hard, soft = trend.regressions(base + [_mk(3, distinct=99)])
-    assert any("distinct drifted" in h for h in hard)
-    # dispatch-budget drift = hard
-    hard, _ = trend.regressions(
-        [_mk(1, levels_per_dispatch=3.0),
-         _mk(2, levels_per_dispatch=1.0)]
-    )
-    assert any("levels/dispatch regressed" in h for h in hard)
-    hard, _ = trend.regressions(
-        [_mk(1, max_dispatches_per_level=1),
-         _mk(2, max_dispatches_per_level=4)]
-    )
-    assert any("dispatches/level grew" in h for h in hard)
-    # wall regression = soft only
-    hard, soft = trend.regressions(base + [_mk(3, wall=100.0)])
-    assert not [h for h in hard if "wall" in h]
-    assert any("soft warn" in s for s in soft)
-    # clean series: nothing
-    hard, soft = trend.regressions(base + [_mk(3)])
-    assert not hard and not soft
-    # variants are independent trend keys (cold is not a regression)
-    hard, soft = trend.regressions(
-        base + [dict(_mk(3, wall=500.0), variant="cold")]
-    )
-    assert not hard and not soft
-
-
-def test_trend_series_roundtrip(tmp_path):
-    d = str(tmp_path / "bench")
-    p1 = trend.append_record(_mk(1), d)
-    p2 = trend.append_record(_mk(2, rate=2000.0), d)
-    assert p1 and p2 and os.path.basename(p1) == "r01_m.json"
-    series = trend.load_series(d)
-    assert [r["round"] for r in series] == [1, 2]
-    # same round+metric overwrites (re-run updates the point)
-    trend.append_record(_mk(2, rate=3000.0), d)
-    series = trend.load_series(d)
-    assert len(series) == 2 and series[-1]["rate"] == 3000.0
-    assert trend.sparkline([1, 2, 3]) == "▁▄█"
-    assert trend.sparkline([]) == ""
-
-
-def test_trend_committed_series_and_gate():
-    """The committed docs/bench/ history loads, renders, and passes the
-    gate; an injected count regression flips it non-zero (the CLI
-    acceptance, in process)."""
-    import io
-
-    from tla_raft_tpu.obs.__main__ import main as obs_main
-
-    bench_dir = os.path.join(REPO, "docs", "bench")
-    series = trend.load_series(bench_dir)
-    assert len(series) >= 15, "committed docs/bench series missing"
-    rounds = {r["round"] for r in series}
-    assert {1, 2, 5, 6} <= rounds  # legacy root records migrated
-    assert {13, 14, 15, 16, 17} <= rounds  # docs A/B records migrated
-    hard, _soft = trend.regressions(series)
-    assert not hard, hard
-    buf = io.StringIO()
-    trend.render(series, out=buf)
-    assert "ab_tiered" in buf.getvalue()
-    assert obs_main(["trend", bench_dir, "--check"]) == 0
-
-
-def test_trend_gate_fails_on_injected_regression(tmp_path, capsys):
-    from tla_raft_tpu.obs.__main__ import main as obs_main
-
-    d = str(tmp_path / "bench")
-    trend.append_record(_mk(1), d)
-    trend.append_record(_mk(2, distinct=99), d)
-    assert obs_main(["trend", d, "--check"]) == 1
-    assert obs_main(["trend", d]) == 0  # render-only never gates
-    capsys.readouterr()
 
 
 # -- events.jsonl rotation (satellite) ------------------------------------

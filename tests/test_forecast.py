@@ -19,7 +19,7 @@ from tla_raft_tpu.engine.forecast import (
 
 from refenv import requires_reference
 
-# the deepest verified per-level record (bench.py GOLDEN_LEVELS /
+# the deepest verified per-level record (oracle/golden.py GOLDEN_LEVELS /
 # BASELINE.md): levels 0..28 of the as-is reference config
 GOLDEN = [
     1, 1, 3, 9, 22, 57, 136, 345, 931, 2468, 5881, 12505, 24705,

@@ -195,7 +195,7 @@ def test_engine_parity_3121_prefix():
 @pytest.mark.slow
 def test_engine_parity_golden_full_3121():
     """GOLDEN_FULL acceptance: the hash-store path lands exactly on the
-    dual-verified (3,1,2,1) fixpoint totals (bench.py GOLDEN_FULL)."""
+    dual-verified (3,1,2,1) fixpoint totals (oracle/golden.py GOLDEN_FULL)."""
     cfg = RaftConfig(n_vals=1, max_election=2, max_restart=1)
     res = JaxChecker(cfg, chunk=1024, use_hashstore=True).run()
     assert (res.distinct, res.generated, res.depth) == (180_582, 747_500, 35)

@@ -34,7 +34,8 @@ MODULES = [
     "tla_raft_tpu.obs.tracefile",
     "tla_raft_tpu.obs.progress",
     "tla_raft_tpu.obs.metrics",
-    "tla_raft_tpu.obs.trend",
+    "tla_raft_tpu.oracle.golden",
+    "tla_raft_tpu.ops.sieve",
     "tla_raft_tpu.store",
     "tla_raft_tpu.store.tiered",
 ]

@@ -190,7 +190,7 @@ def test_golden_prefix_probes_live_lanes(span, block):
     summary shows fewer lanes probed than the buffers hold, and the
     dispatches and ledgered fetches are the full-width probe's: one of
     each per superstep, one of each per level and per redo."""
-    from bench import GOLDEN_LEVELS
+    from tla_raft_tpu.oracle.golden import GOLDEN_LEVELS
     from tla_raft_tpu.analysis import sanitize
     from tla_raft_tpu.check import run_check, summary_public
 

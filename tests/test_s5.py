@@ -107,7 +107,7 @@ def test_engine_parity_s5(cfg5, make, depth):
     """Full BFS parity of the engine (programs over the kernel's tables
     operand) with the oracle on a bounded 5-server space, and with the
     pinned golden prefix of the 3-server reference config."""
-    from bench import GOLDEN_LEVELS
+    from tla_raft_tpu.oracle.golden import GOLDEN_LEVELS
 
     cfg = make(cfg5)
     e = JaxChecker(cfg, chunk=64).run(max_depth=depth)

@@ -1,5 +1,6 @@
-"""Spill sieve + spilled frontiers + LSM compaction (ops/sieve.py,
-store/tiered.py side-cars/compaction, engine/bfs.py FrontierPager).
+"""Spill sieve + its arm/stand-down governor + spilled frontiers + LSM
+compaction (ops/sieve.py, store/tiered.py side-cars/compaction,
+engine/bfs.py FrontierPager).
 
 Fast rows share ONE (3,1,2,1) depth-14 forced-spill engine pair — the
 hot budget ~5x under |visited| forces >= 2 whole-generation demotions,
@@ -337,6 +338,76 @@ def test_sweep_clears_orphan_fsegs_and_sidecars(tmp_path):
     # the delta log is the source of truth on resume)
     assert tiered.sweep_gens(d) == 2
     assert not glob.glob(os.path.join(d, "gen_*"))
+
+
+# -- adaptive sieve governor ----------------------------------------------
+
+def test_governor_modes_from_env(monkeypatch):
+    monkeypatch.delenv("TLA_RAFT_SIEVE", raising=False)
+    assert sieve_mod.mode_from_env() == "auto"
+    assert sieve_mod.mode_from_env(True) == "on"
+    assert sieve_mod.mode_from_env(False) == "off"
+    monkeypatch.setenv("TLA_RAFT_SIEVE", "0")
+    assert sieve_mod.mode_from_env() == "off"
+    monkeypatch.setenv("TLA_RAFT_SIEVE", "1")
+    assert sieve_mod.mode_from_env() == "on"
+    # explicit argument still forces over env
+    assert sieve_mod.mode_from_env(False) == "off"
+
+
+def test_governor_stand_down_and_rearm():
+    gov = sieve_mod.SieveGovernor("auto")
+    assert gov.armed
+    # clean windows: stays armed forever
+    for i in range(10):
+        gov.note_window(sieve_stop=False, level=i)
+    assert gov.armed and gov.stats["stand_downs"] == 0
+    # dense sieve-dirty stops: stands down at the density threshold
+    for i in range(sieve_mod.MIN_WINDOWS):
+        gov.note_window(sieve_stop=True, level=20 + i)
+    assert not gov.armed and gov.stats["stand_downs"] == 1
+    # stood down: windows are not recorded, probation ticks are
+    gov.note_window(sieve_stop=True, level=30)
+    assert gov.stats["stand_downs"] == 1
+    gov.note_level(30)
+    assert not gov.armed
+    gov.note_level(23 + sieve_mod.REARM_LEVELS)
+    assert gov.armed and gov.stats["rearms"] == 1
+    snap = gov.snapshot()
+    assert snap["mode"] == "auto" and snap["armed"] is True
+
+
+def test_governor_forced_modes_never_move():
+    on = sieve_mod.SieveGovernor("on")
+    for i in range(20):
+        on.note_window(sieve_stop=True, level=i)
+    assert on.armed and on.stats["stand_downs"] == 0
+    off = sieve_mod.SieveGovernor("off")
+    assert not off.armed
+    off.note_level(100)
+    assert not off.armed and off.stats["rearms"] == 0
+
+
+def test_governor_emits_events(tmp_path):
+    from tla_raft_tpu.obs import telemetry as tel
+
+    d = str(tmp_path)
+    with tel.TelemetryHub(run_dir=d) as hub:
+        tel.install(hub)
+        try:
+            gov = sieve_mod.SieveGovernor("auto")
+            for i in range(sieve_mod.MIN_WINDOWS):
+                gov.note_window(sieve_stop=True, level=i)
+            gov.note_level(sieve_mod.MIN_WINDOWS - 1
+                           + sieve_mod.REARM_LEVELS)
+        finally:
+            tel.install(None)
+    events, _ = tel.read_events(os.path.join(d, "events.jsonl"))
+    kinds = [e["ev"] for e in events]
+    assert "sieve_standdown" in kinds and "sieve_arm" in kinds
+    sd = next(e for e in events if e["ev"] == "sieve_standdown")
+    assert sd["density"] >= sieve_mod.STAND_DOWN_DENSITY
+    assert sd["windows"] >= sieve_mod.MIN_WINDOWS
 
 
 # -- subprocess rows (slow tier) ------------------------------------------

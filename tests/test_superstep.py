@@ -182,7 +182,7 @@ def test_program_shared_across_checkers(span, stats, empty_caches,
     first's program and re-traces none of its shape rungs, though the
     first grew cap_x during its check (the benchmark's back-to-back
     checks grow 4096 -> 6144)."""
-    from bench import GOLDEN_LEVELS
+    from tla_raft_tpu.oracle.golden import GOLDEN_LEVELS
 
     ref = RaftConfig(n_servers=3, n_vals=2, max_election=3, max_restart=3)
     golden = GOLDEN_LEVELS[(3, 2, 3, 3)][:7]

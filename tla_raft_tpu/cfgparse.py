@@ -26,7 +26,7 @@ import re
 from .config import RaftConfig
 
 # the reference Raft.cfg, committed at the repo root: the default of every
-# entry point (check.py, myrun.sh, bench.py, the tuner)
+# entry point (check.py, myrun.sh, the benchmark)
 REPO_CFG = os.path.join(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "Raft.cfg"
 )

@@ -36,6 +36,9 @@ import time
 from .config import MSG_TYPE_NAMES, ROLE_NAMES, RaftConfig
 from .cfgparse import REPO_CFG, load_raft_config
 
+# expand rows per device dispatch when the caller names none
+DEFAULT_CHUNK = 1024
+
 
 class Tee:
     def __init__(self, *streams):
@@ -300,9 +303,8 @@ def summarize(res, chk=None, seconds: float | None = None,
         out["megakernel_stats"] = {
             k: int(v) for k, v in sorted(mega.items())
         }
-    # adaptive sieve governor (tune/adaptive.py): present whenever the
-    # measured arm/stand-down policy saw a window or flipped state —
-    # the BENCH_SIEVE_AB record's evidence that the policy engaged
+    # adaptive sieve governor (ops/sieve.py): present whenever the
+    # measured arm/stand-down policy saw a window or flipped state
     gov = getattr(chk, "sieve_governor", None)
     if gov is not None and (
         gov.stats["windows"] or gov.stats["stand_downs"]
@@ -346,7 +348,7 @@ def run_check(
     *,
     backend: str = "jax",
     max_depth: int | None = None,
-    chunk: int | None = None,
+    chunk: int = DEFAULT_CHUNK,
     checkpoint_dir: str | None = None,
     checkpoint_every: int = 1,
     recover: str | None = None,
@@ -373,7 +375,6 @@ def run_check(
     profile: int = 0,
     dev_bytes: int | None = None,
     warm_bytes: int | None = None,
-    plan=None,
     progress=None,
     out=None,
     install_signals: bool = False,
@@ -392,17 +393,6 @@ def run_check(
     carry the raw objects for callers that need the violation trace,
     the exchange meter or the telemetry hub; ``summary_public`` strips
     them.
-
-    ``plan`` selects the autotuned knob plan (tune/plans.py):
-    ``None`` resolves the run's shape regime against the active plan
-    cache (``TLA_RAFT_PLAN``: ``0`` disables, unset/``1`` reads the
-    committed default cache, a path reads that file); ``False``/``"0"``
-    forces the hand-set defaults; a dict is used as the knob set
-    directly; a path string resolves against that file.  Explicit
-    arguments (``chunk``, ``superstep``, ``pipeline_window``, ...)
-    always beat the plan — it only fills values the caller left unset —
-    and counts are bit-identical under any plan (knobs move shapes and
-    schedules, never semantics).
 
     ``telemetry`` (default: ``TLA_RAFT_TELEMETRY``, on) installs the
     process-wide flight recorder (obs/telemetry.py) for the run: every
@@ -451,44 +441,8 @@ def run_check(
 
         obs_watch_compiles()
     with obs_telemetry.span("raft.check"):
-        # -- autotuned plan resolution (tune/plans.py) --------------------
-        # Resolved AFTER the hub install so the plan_applied event lands in
-        # this run's flight recorder; installed only when no outer caller
-        # (the tuner's probe loop, the service bucket pass) already holds
-        # the registry, and fully restored on exit either way.
-        from .ops import hashstore as _hashstore
-        from .tune import active as _plan_active
-        from .tune import plans as _plans
-
-        if plan is False or plan == "0":
-            plan_knobs: dict = {}
-        elif isinstance(plan, dict):
-            plan_knobs = _plans.clamp(plan)
-        elif isinstance(plan, str) and plan not in ("", "1"):
-            plan_knobs = _plans.resolve(cfg, backend, path=plan)
-        else:
-            plan_knobs = _plans.resolve(cfg, backend)
-        own_plan = bool(plan_knobs) and _plan_active.installed() is None
-        prev_pw = None
-        if own_plan:
-            _plan_active.install(plan_knobs)
-            if "probe_window" in plan_knobs:
-                prev_pw = _hashstore.probe_window()
-                _hashstore.set_probe_window(int(plan_knobs["probe_window"]))
-            obs_telemetry.emit(
-                "plan_applied",
-                regime=_plans.regime_key(cfg, backend),
-                knobs=dict(plan_knobs),
-            )
-            if out is not None:
-                print(
-                    f"Autotuned plan: {_plans.regime_key(cfg, backend)} -> "
-                    f"{plan_knobs} (TLA_RAFT_PLAN=0 reverts)", file=out,
-                )
-        if chunk is None:
-            chunk = int(plan_knobs.get("chunk", 1024)) if own_plan else 1024
         try:
-            summary = _run_check_impl(
+            return _run_check_impl(
                 cfg, backend=backend, max_depth=max_depth, chunk=chunk,
                 checkpoint_dir=checkpoint_dir,
                 checkpoint_every=checkpoint_every, recover=recover,
@@ -505,14 +459,7 @@ def run_check(
                 hub=hub, progress=progress, out=out,
                 install_signals=install_signals,
             )
-            if own_plan:
-                summary["plan"] = dict(plan_knobs)
-            return summary
         finally:
-            if own_plan:
-                _plan_active.clear()
-                if prev_pw is not None:
-                    _hashstore.set_probe_window(prev_pw)
             if own_hub:
                 obs_telemetry.install(None)
                 hub.close()
@@ -859,9 +806,9 @@ def main(argv=None) -> int:
     p.add_argument("--workers", type=int, default=None,
                    help="accepted for myrun.sh compatibility; ignored")
     p.add_argument("--max-depth", type=int, default=None)
-    p.add_argument("--chunk", type=int, default=None,
-                   help="expand rows per device dispatch (default: the "
-                        "autotuned plan's chunk, else 1024)")
+    p.add_argument("--chunk", type=int, default=DEFAULT_CHUNK,
+                   help="expand rows per device dispatch (default "
+                        f"{DEFAULT_CHUNK})")
     p.add_argument("--invariant", action="append", default=None,
                    help="override INVARIANT (repeatable; ~Name negates)")
     p.add_argument("--no-symmetry", action="store_true")
@@ -1041,16 +988,6 @@ def main(argv=None) -> int:
                         "lanes into trace.json beside the host lanes. "
                         "Default off; counts are bit-identical either "
                         "way")
-    p.add_argument("--plan", default=None, metavar="PATH|0|1",
-                   help="autotuned knob plan (tune/plans.py): 0 forces "
-                        "the hand-set defaults, 1 (or unset) resolves "
-                        "the committed plan cache, a path resolves that "
-                        "file; TLA_RAFT_PLAN is the env twin")
-    p.add_argument("--tune", type=int, default=0, metavar="DEPTH",
-                   help="probe-search this config's knob regime to "
-                        "depth DEPTH before the run (tune/search.py) "
-                        "and commit the winner to the plan cache; the "
-                        "run then executes under it")
     p.add_argument("--progress", action="store_true",
                    help="live one-line progress display (states/s, "
                         "frontier, slab load, levels/dispatch, "
@@ -1156,24 +1093,6 @@ def main(argv=None) -> int:
         return 2
     from . import resilience
 
-    if args.tune and args.backend == "jax":
-        # probe-search this regime first, commit the winner, then run
-        # under it (the commit target is the --plan path when given,
-        # else the TLA_RAFT_PLAN-active cache)
-        from .tune import plans as _plans
-        from .tune import search as _tune_search
-
-        tune_path = (
-            args.plan if args.plan and args.plan not in ("0", "1")
-            else _plans.plan_path()
-        )
-        _tune_search.tune(
-            cfg, backend=args.backend, path=tune_path,
-            commit=tune_path is not None,
-            max_depth=args.tune, out=out,
-            dev_bytes=int(args.dev_bytes) if args.dev_bytes else None,
-        )
-
     try:
         summary = run_check(
             cfg,
@@ -1216,7 +1135,6 @@ def main(argv=None) -> int:
             warm_bytes=(
                 int(args.warm_bytes) if args.warm_bytes else None
             ),
-            plan=args.plan,
             progress=progress,
             out=out,
             install_signals=(args.backend != "oracle"),

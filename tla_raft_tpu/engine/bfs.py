@@ -82,7 +82,6 @@ from . import superstep as graft_superstep
 from . import pipeline as graft_pipeline
 from ..analysis import devprof as graft_devprof
 from . import forecast as graft_forecast
-from ..tune import adaptive as graft_adaptive
 from .forecast import MIN_LEVELS as PRESIZE_MIN_LEVELS, pow2ceil as _pow2
 from .invariants import resolve_invariant_kernel
 
@@ -832,11 +831,11 @@ class JaxChecker(LevelOps):
         # is; TLA_RAFT_SIEVE=0 / sieve=False reverts to span-1.
         # the arm decision is governed at RUNTIME when TLA_RAFT_SIEVE
         # is unset: recent sieve-dirty windows stand the span down
-        # (the replay tax never amortizes — BENCH_SIEVE_AB_r20's ~14%),
+        # (the replay tax never amortizes — ~14% in a round-20 CPU A/B),
         # a probation of per-level progress re-arms it.  =0 / =1 (or an
         # explicit argument) still force either mode unconditionally.
-        self.sieve_governor = graft_adaptive.SieveGovernor(
-            graft_adaptive.mode_from_env(sieve)
+        self.sieve_governor = graft_sieve.SieveGovernor(
+            graft_sieve.mode_from_env(sieve)
         )
         self.sieve_enabled = self.sieve_governor.mode != "off"
         self._dev_sieve = None      # device u64[M] copy of the filter
@@ -1292,9 +1291,8 @@ class JaxChecker(LevelOps):
                 n_f, level_sizes, max_depth, n_lanes, out_floor
             )
             # re-resolve through the shared cache EVERY attempt: a grown
-            # budget is a new spec and so a new program, and a flushed
-            # cache (hashstore.set_probe_window) is never dispatched
-            # from a stale binding (a dict hit costs nothing)
+            # budget is a new spec and so a new program (a dict hit
+            # costs nothing)
             self._mega_prog = graft_megakernel.level_program_for(
                 self.level_spec(), self._mega_donate, self._mega_stats
             )
@@ -4398,7 +4396,7 @@ class JaxChecker(LevelOps):
             # can replay its parents
             self._fseg_retire_consumed()
             # adaptive sieve: per-level tick drives the stood-down
-            # governor's re-arm probation (tune/adaptive.py)
+            # governor's re-arm probation (ops/sieve.py)
             self.sieve_governor.note_level(depth)
             # --- multi-level resident superstep: up to N fused levels
             # in ONE device program + ONE ledgered ring fetch

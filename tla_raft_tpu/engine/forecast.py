@@ -40,21 +40,16 @@ PRESIZE_HORIZON = 8
 # capacity inflation over the raw forecast: the margin every presize
 # floor, the prewarm ladder and the superstep ring share.  Hand-set at
 # 1.25 (forecast error lands inside pow2 rounding at that inflation);
-# TLA_RAFT_CAP_MARGIN overrides, else the installed autotuner plan's
-# ``cap_margin`` knob (tune/plans.py) — one accessor so the three
-# consumers cannot drift on the value.
+# TLA_RAFT_CAP_MARGIN overrides — one accessor so the three consumers
+# cannot drift on the value.
 DEFAULT_CAP_MARGIN = 1.25
 
 
-def cap_margin(default: float = DEFAULT_CAP_MARGIN) -> float:
+def cap_margin() -> float:
     import os
 
     env = os.environ.get("TLA_RAFT_CAP_MARGIN")
-    if env:
-        return max(1.0, float(env))
-    from ..tune import active
-
-    return max(1.0, float(active.get("cap_margin", default)))
+    return max(1.0, float(env) if env else DEFAULT_CAP_MARGIN)
 
 
 def pow2ceil(n: int) -> int:

@@ -67,13 +67,9 @@ def enabled_by_env() -> bool:
     return os.environ.get("TLA_RAFT_PIPELINE", "1") != "0"
 
 
-def window_from_env(default: int = DEFAULT_WINDOW) -> int:
+def window_from_env() -> int:
     v = os.environ.get("TLA_RAFT_PIPELINE_WINDOW")
-    if v:
-        return int(v)
-    from ..tune import active
-
-    return int(active.get("pipeline_window", default))
+    return int(v) if v else DEFAULT_WINDOW
 
 
 def async_start(tree) -> None:

@@ -135,16 +135,10 @@ REASON_NAMES = {
 }
 
 
-def span_from_env(default: int = DEFAULT_SPAN) -> int:
-    """Levels per dispatch; <= 1 reverts to the per-level megakernel.
-    Env wins; an installed autotuner plan's ``superstep_span`` is the
-    fallback (tune/plans.py precedence)."""
+def span_from_env() -> int:
+    """Levels per dispatch; <= 1 reverts to the per-level megakernel."""
     v = os.environ.get("TLA_RAFT_SUPERSTEP")
-    if v is None or v == "":
-        from ..tune import active
-
-        return max(1, int(active.get("superstep_span", default)))
-    return max(1, int(v))
+    return max(1, int(v)) if v else DEFAULT_SPAN
 
 
 # shared jit cache, the megakernel's (megakernel.cached_program): the

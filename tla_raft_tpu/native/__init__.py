@@ -77,8 +77,7 @@ def build_cpubase(force: bool = False) -> str:
     """Compile cpubase.cpp -> the native CPU baseline checker binary.
 
     The multithreaded C++ explicit-state checker of the same spec family
-    (the honest stand-in for `tlc -workers N`, BASELINE.md) — bench.py
-    measures `vs_baseline` against it.  Built with ``-march=native``,
+    (the honest stand-in for `tlc -workers N`, BASELINE.md).  Built with ``-march=native``,
     so the cache is keyed by the host's CPU as well as the source."""
     return _build(
         _BASE_SRC, _BASE_BIN,

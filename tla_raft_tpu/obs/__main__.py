@@ -3,7 +3,6 @@
     python -m tla_raft_tpu.obs report RUN_DIR [BASELINE_RUN_DIR] [--json]
     python -m tla_raft_tpu.obs trace  RUN_DIR [-o OUT.json]
     python -m tla_raft_tpu.obs metrics ROOT
-    python -m tla_raft_tpu.obs trend  [BENCH_DIR] [--check] [--json]
 
 ``report`` renders a per-level table (wall, new states, dispatches,
 fetch wait, grows) from a run directory's ``events.jsonl``; with a
@@ -12,9 +11,7 @@ total deltas (the overhead/regression A/B view).  ``trace`` exports
 the Chrome trace-event JSON timeline (load it in
 https://ui.perfetto.dev), merging any ``--profile`` device capture
 beside the host lanes.  ``metrics`` pretty-prints a service root's
-``metrics.json``.  ``trend`` renders the normalized ``docs/bench/``
-perf series (obs/trend.py) and, with ``--check``, exits non-zero on a
-hard regression (count drift, dispatch-budget drift).
+``metrics.json``.
 
 Runs missing optional event kinds (no superstep windows at
 ``--superstep 1``, no tier events on untiered runs, no device capture
@@ -31,7 +28,6 @@ import sys
 
 from . import metrics as obs_metrics
 from . import tracefile
-from . import trend as obs_trend
 from .telemetry import EVENTS_NAME, read_events
 
 
@@ -291,30 +287,6 @@ def _cmd_trace(args) -> int:
     return 0
 
 
-def _cmd_trend(args) -> int:
-    series = obs_trend.load_series(args.bench_dir)
-    hard, soft = obs_trend.regressions(series)
-    if args.json:
-        print(json.dumps(dict(
-            records=len(series), hard=hard, soft=soft, series=series,
-        )))
-    else:
-        obs_trend.render(series)
-        for w in soft:
-            print(f"warning: trend: {w}")
-        for f in hard:
-            print(f"FAIL: trend: {f}")
-        print(
-            f"trend: {len(series)} record(s), {len(hard)} hard "
-            f"regression(s), {len(soft)} warning(s) — "
-            + ("FAIL" if hard else "OK")
-        )
-    if not series and args.check:
-        print(f"{args.bench_dir}: no trend records", file=sys.stderr)
-        return 2
-    return 1 if hard and args.check else 0
-
-
 def _cmd_metrics(args) -> int:
     doc = obs_metrics.load(args.root)
     if doc is None:
@@ -350,24 +322,11 @@ def main(argv=None) -> int:
     pm.add_argument("root")
     pm.add_argument("--json", action="store_true")
 
-    pd = sub.add_parser(
-        "trend", help="render the docs/bench/ perf-trend series"
-    )
-    pd.add_argument("bench_dir", nargs="?",
-                    default=obs_trend.BENCH_DIRNAME,
-                    help="series directory (default: docs/bench)")
-    pd.add_argument("--check", action="store_true",
-                    help="exit non-zero on a hard regression "
-                         "(count/dispatch-budget drift) — the CI gate")
-    pd.add_argument("--json", action="store_true")
-
     args = p.parse_args(argv)
     if args.cmd == "report":
         return _cmd_report(args)
     if args.cmd == "trace":
         return _cmd_trace(args)
-    if args.cmd == "trend":
-        return _cmd_trend(args)
     return _cmd_metrics(args)
 
 

@@ -14,8 +14,8 @@ run events into one process-global hub (:func:`install` /
 * aggregates the per-level accounting (level wall times, dispatches,
   ledgered fetch waits, grow/redo counts, checkpoint I/O, compiles,
   superstep amortization) host-side, so ``check.py --json``'s
-  ``telemetry`` block and bench.py read ONE bookkeeping instead of
-  three ad-hoc meters.
+  ``telemetry`` block reads ONE bookkeeping instead of three ad-hoc
+  meters.
 
 Host-purity contract (graftlint GL012): this module — the whole
 ``obs/`` package — must never import jax, touch a device array, or
@@ -725,8 +725,7 @@ class TelemetryHub:
     # -- reporting --------------------------------------------------------
 
     def snapshot(self) -> dict:
-        """The ``--json`` ``telemetry`` block (also bench.py's source
-        for ``level_seconds`` / ``dispatches_per_level``)."""
+        """The ``--json`` ``telemetry`` block."""
         with self._lock:
             out = dict(
                 events=self.n_events,

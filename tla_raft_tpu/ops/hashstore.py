@@ -90,7 +90,6 @@ PROBE_DEPTH = 64
 # are the cheapest gather class on the vector units (same row
 # neighborhood), so the wider fetch costs far less than W round trips.
 PROBE_WINDOW = 8
-DEFAULT_PROBE_WINDOW = PROBE_WINDOW
 MIN_CAP = 1 << 10
 SLAB_VERSION = 1
 
@@ -421,37 +420,6 @@ def insert_np(slab: np.ndarray, fps: np.ndarray) -> np.ndarray:
 @jax.jit
 def _live_count(slab):
     return (slab != jnp.uint64(SENT)).sum()
-
-
-def probe_window() -> int:
-    return PROBE_WINDOW
-
-
-def set_probe_window(w: int | None) -> int:
-    """Set the per-round gather width (None restores the hand-set
-    default) and return the value now in force.
-
-    ``PROBE_WINDOW`` is read at TRACE time inside ``_probe_rounds`` but
-    none of the caches that hold traced programs key on it — the module
-    jits here, and the megakernel/superstep ``_PROG_CACHE`` ladders —
-    so changing it without flushing them would keep dispatching
-    old-width programs (an autotuner probe would silently measure the
-    previous candidate).  Exact semantics at any width: the walk still
-    covers PROBE_DEPTH slots, only the gather batching changes."""
-    global PROBE_WINDOW
-    w = DEFAULT_PROBE_WINDOW if w is None else max(2, min(64, int(w)))
-    if w == PROBE_WINDOW:
-        return PROBE_WINDOW
-    PROBE_WINDOW = w
-    for fn in (probe, probe_and_insert, insert_only):
-        fn.clear_cache()
-    # lazy import: engine modules import this one at module scope
-    from ..engine import megakernel as _mega
-    from ..engine import superstep as _sstep
-
-    _mega._PROG_CACHE.clear()
-    _sstep._PROG_CACHE.clear()
-    return PROBE_WINDOW
 
 
 class DeviceHashStore:

@@ -43,7 +43,12 @@ budget by default (``TLA_RAFT_SIEVE_BYTES`` overrides), allocated at
 FULL size on first demotion and never rebuilt — growing a bloom needs
 every spilled fingerprint re-hashed (cold-generation reloads), so the
 filter trades graceful fp-rate degradation past its design load for
-never touching disk.  Host-purity: building and the numpy mirror are
+never touching disk.
+
+Arming is a runtime policy (:class:`SieveGovernor`, driven by
+:func:`mode_from_env`): the engine stands the sieve down when recent
+superstep windows keep stopping on sieve hits, and re-arms it after a
+probation of per-level progress.  Host-purity: building and the numpy mirror are
 pure numpy (GL007-safe); the only device code is :func:`probe_impl`,
 registered under the GL010 gather budget as ``ops.sieve_probe``.
 """
@@ -51,8 +56,11 @@ registered under the GL010 gather budget as ``ops.sieve_probe``.
 from __future__ import annotations
 
 import os
+from collections import deque
 
 import numpy as np
+
+from ..obs import telemetry as obs
 
 SIEVE_VERSION = 1
 
@@ -102,12 +110,7 @@ def sieve_words_for(dev_bytes: int) -> int:
     if env:
         nbytes = int(float(env))
     else:
-        # plan fallback: the autotuner's sieve_shift knob spends
-        # dev_bytes >> shift (hand-set shift 3 == the 1/8 default)
-        from ..tune import active
-
-        shift = int(active.get("sieve_shift", 3))
-        nbytes = max(int(dev_bytes) >> shift, 1 << 13)
+        nbytes = max(int(dev_bytes) >> 3, 1 << 13)
     words = max(nbytes // 8, 1)
     return 1 << max(words.bit_length() - 1, 0)
 
@@ -226,3 +229,108 @@ def ledger_trace(cfg=None):
     sieve = jax.ShapeDtypeStruct((64,), jnp.uint64)
     fps = jax.ShapeDtypeStruct((256,), jnp.uint64)
     return jax.make_jaxpr(probe_impl)(sieve, fps)
+
+
+# -- adaptive arm/stand-down policy ------------------------------------
+#
+# A round-20 CPU A/B measured both regimes (docs/PERF.md, round 20): in
+# sieve-clean post-spill sweeps the armed sieve restores span-N
+# residency (6 supersteps vs stand-down's 3 at the forced-spill
+# reference), but in revisit-dense regimes every window stops on
+# FLAG_TIER and replays per-level — the replays never amortize and cost
+# ~14% wall over just standing down.  Which regime a run is in is a
+# RUNTIME property (it shifts as generations accumulate), so the arm
+# decision is driven by the measured signal, not a hand-set env: the
+# governor watches the same per-window sieve-stop outcomes the telemetry
+# hub records as ``sieve_stop`` events and
+#
+# * **stands down** when recent windows stop sieve-dirty at high density
+#   (>= half of the last few windows): span drops to 1 — the tiered
+#   store's sieve-less stand-down — and the replay tax stops accruing;
+# * **re-arms** after a probation of per-level progress: revisit density
+#   decays as the frontier outruns the demoted generations, and one
+#   probing window is cheap against the span-N upside it may restore.
+#
+# ``TLA_RAFT_SIEVE=1`` / ``=0`` still force either mode unconditionally
+# (mode ``on`` / ``off``); the governor only owns the unset (``auto``)
+# default.  Arming is pure schedule: counts stay bit-identical in every
+# mode (a stood-down run replays through the exact per-level tier probe
+# — the parity tests in tests/test_sieve.py pin both arms).
+
+# recent superstep windows consulted for the stand-down decision
+WINDOW = 8
+# stand down once this fraction of recent windows stopped sieve-dirty
+# (at the measured ~14% per-replay tax, half-dirty windows already burn
+# more than span residency saves)
+STAND_DOWN_DENSITY = 0.5
+# minimum windows observed before the density is trusted
+MIN_WINDOWS = 4
+# per-level probation while stood down before one re-arm probe
+REARM_LEVELS = 16
+
+
+def mode_from_env(explicit: bool | None = None) -> str:
+    """``auto`` | ``on`` | ``off`` — the one TLA_RAFT_SIEVE parse.
+
+    An explicit engine argument forces; else env ``0`` forces off, any
+    other non-empty value forces on, unset/empty is the governed
+    auto mode."""
+    if explicit is not None:
+        return "on" if explicit else "off"
+    env = os.environ.get("TLA_RAFT_SIEVE")
+    if env is None or env == "":
+        return "auto"
+    return "off" if env == "0" else "on"
+
+
+class SieveGovernor:
+    """Measured arm/stand-down state machine for the spill sieve."""
+
+    __slots__ = ("mode", "_armed", "_recent", "_standdown_level", "stats")
+
+    def __init__(self, mode: str = "auto"):
+        assert mode in ("auto", "on", "off"), mode
+        self.mode = mode
+        self._armed = mode != "off"
+        self._recent: deque = deque(maxlen=WINDOW)
+        self._standdown_level: int | None = None
+        self.stats = {"stand_downs": 0, "rearms": 0, "windows": 0}
+
+    @property
+    def armed(self) -> bool:
+        return self._armed
+
+    def note_window(self, *, sieve_stop: bool, level: int) -> None:
+        """One superstep window's outcome (called once per window while
+        armed): ``sieve_stop`` is whether it stopped on FLAG_TIER."""
+        if self.mode != "auto" or not self._armed:
+            return
+        self.stats["windows"] += 1
+        self._recent.append(bool(sieve_stop))
+        n = len(self._recent)
+        if n < MIN_WINDOWS:
+            return
+        density = sum(self._recent) / n
+        if density >= STAND_DOWN_DENSITY:
+            self._armed = False
+            self._standdown_level = int(level)
+            self._recent.clear()
+            self.stats["stand_downs"] += 1
+            obs.emit("sieve_standdown", level=int(level),
+                     density=round(density, 3), windows=n)
+
+    def note_level(self, level: int) -> None:
+        """Per-level tick (the engine's loop top): drives the re-arm
+        probation while stood down."""
+        if self.mode != "auto" or self._armed:
+            return
+        if (self._standdown_level is not None
+                and int(level) - self._standdown_level >= REARM_LEVELS):
+            self._armed = True
+            self._standdown_level = None
+            self._recent.clear()
+            self.stats["rearms"] += 1
+            obs.emit("sieve_arm", level=int(level))
+
+    def snapshot(self) -> dict:
+        return dict(mode=self.mode, armed=self._armed, **self.stats)

@@ -353,9 +353,8 @@ def main(argv=None) -> int:
     pd.add_argument("--no-batch", action="store_true",
                     help="disable config-batched execution (A/B lever; "
                          "every job runs sequentially)")
-    pd.add_argument("--min-bucket", type=int, default=None,
-                    help="smallest bucket worth batching (default: the "
-                         "regime's autotuned plan, else 2)")
+    pd.add_argument("--min-bucket", type=int, default=2,
+                    help="smallest bucket worth batching (default 2)")
     pd.add_argument("--lease-ttl", type=float, default=30.0,
                     help="seconds without a heartbeat before a "
                          "worker's claim is presumed dead")

@@ -32,10 +32,8 @@ import threading
 import time
 
 from .. import resilience
-from ..check import run_check, summary_public, trace_doc
+from ..check import DEFAULT_CHUNK, run_check, summary_public, trace_doc
 from ..obs import metrics as obs_metrics
-from ..tune import active as tune_active
-from ..tune import plans as tune_plans
 from .bucket import BatchedChecker, bucket_key
 from .queue import JobQueue, LeaseLost, doc_to_cfg
 
@@ -109,7 +107,7 @@ class Scheduler:
         self,
         queue: JobQueue,
         batch: bool = True,
-        min_bucket: int | None = None,
+        min_bucket: int = 2,
         out=None,
         use_mxu: bool | None = None,
         registry=None,
@@ -118,12 +116,7 @@ class Scheduler:
     ):
         self.q = queue
         self.batch = batch
-        # None = per-regime: the plan cache's tuned min_bucket for that
-        # bucket's shape regime (falls back to 2); an explicit argument
-        # (or --min-bucket) pins one floor for every bucket
-        self.min_bucket = (
-            max(1, int(min_bucket)) if min_bucket is not None else None
-        )
+        self.min_bucket = max(1, int(min_bucket))
         self.out = out if out is not None else sys.stderr
         self.use_mxu = use_mxu
         # pool membership (service/pool.py): registered/beaten/swept
@@ -218,24 +211,12 @@ class Scheduler:
         # preemption that cuts the pass short
         out = []
         for key, jobs in buckets.items():
-            if len(jobs) >= self._min_bucket_for(jobs[0][1]):
+            if len(jobs) >= self.min_bucket:
                 out.append((key, jobs))
             else:
                 singles.extend(jobs)
         out.sort(key=lambda kv: -len(kv[1]))
         return out, singles
-
-    def _min_bucket_for(self, spec: dict) -> int:
-        """Bucket-size floor for one shape regime: the explicit
-        ``--min-bucket`` when given, else the plan cache's tuned
-        ``min_bucket`` for that regime (default 2)."""
-        if self.min_bucket is not None:
-            return self.min_bucket
-        opt = spec.get("options") or {}
-        knobs = tune_plans.resolve(
-            doc_to_cfg(spec["config"]), opt.get("backend", "jax")
-        )
-        return max(1, int(knobs.get("min_bucket", 2)))
 
     # -- execution -----------------------------------------------------
 
@@ -278,40 +259,9 @@ class Scheduler:
             import contextlib
 
             hubctx = contextlib.nullcontext()
-        # per-bucket autotuned plan: install the regime's cached knobs
-        # for the batched run so the core's span/window readers (and the
-        # hash-slab probe window) resolve the tuned values; restored
-        # before any sequential fallback so _run_one's own run_check
-        # plan resolution stays the single owner there
-        plan_knobs = (
-            tune_plans.resolve(cfgs[0], "jax")
-            if tune_active.installed() is None else {}
-        )
         try:
             with _Beater(self.q, jids), hubctx:
-                if plan_knobs:
-                    from ..ops import hashstore
-
-                    self._say(
-                        f"bucket {key.describe()}: autotuned plan "
-                        f"{plan_knobs}"
-                    )
-                    obs_telemetry.emit(
-                        "plan_applied", scope="bucket",
-                        regime=tune_plans.regime_key(cfgs[0], "jax"),
-                        knobs=dict(plan_knobs),
-                    )
-                    tune_active.install(plan_knobs)
-                    if "probe_window" in plan_knobs:
-                        hashstore.set_probe_window(
-                            int(plan_knobs["probe_window"])
-                        )
-                try:
-                    summaries = bc.run(checkpoint_dir=bdir)
-                finally:
-                    if plan_knobs:
-                        tune_active.clear()
-                        hashstore.set_probe_window(None)
+                summaries = bc.run(checkpoint_dir=bdir)
         except resilience.Preempted:
             for j in jids:
                 self.q.release(j, note="preempted mid-bucket")
@@ -382,7 +332,7 @@ class Scheduler:
             full = run_check(
                 cfg,
                 max_depth=spec.get("max_depth"),
-                chunk=int(opt["chunk"]) if opt.get("chunk") else None,
+                chunk=int(opt.get("chunk") or DEFAULT_CHUNK),
                 checkpoint_dir=self.q.ck_dir(jid),
                 use_mxu=self.use_mxu,
             )
@@ -415,9 +365,7 @@ class Scheduler:
                     cfg,
                     backend=opt.get("backend", "jax"),
                     max_depth=spec.get("max_depth"),
-                    # unset -> run_check's plan resolution picks the
-                    # regime's tuned chunk (or the 1024 default)
-                    chunk=int(opt["chunk"]) if opt.get("chunk") else None,
+                    chunk=int(opt.get("chunk") or DEFAULT_CHUNK),
                     checkpoint_dir=ck,
                     recover=recover,
                     mesh=int(opt.get("mesh", 0)),

@@ -112,24 +112,15 @@ FSEG_PREFIX = "fseg_"
 
 def compact_fanout_from_env() -> int:
     v = os.environ.get("TLA_RAFT_COMPACT_FANOUT")
-    if v:
-        return max(1, int(v))
-    from ..tune import active
-
-    return max(1, int(active.get("compact_fanout", DEFAULT_COMPACT_FANOUT)))
+    return max(1, int(v)) if v else DEFAULT_COMPACT_FANOUT
 
 
 def fseg_bytes_from_env() -> int:
     """Host-RAM budget for paged-out frontier segments before they
     spill on to the warm tier (``TLA_RAFT_FSEG_BYTES``; 0 = disk spill
-    off, host RAM is the only frontier overflow tier).  The env wins;
-    an installed autotuner plan's ``fseg_bytes`` is the fallback."""
+    off, host RAM is the only frontier overflow tier)."""
     v = os.environ.get("TLA_RAFT_FSEG_BYTES")
-    if v:
-        return int(float(v))
-    from ..tune import active
-
-    return int(active.get("fseg_bytes", 0))
+    return int(float(v)) if v else 0
 
 
 def store_bytes_from_env() -> int:
@@ -141,11 +132,7 @@ def store_bytes_from_env() -> int:
 
 def warm_bytes_from_env() -> int:
     v = os.environ.get("TLA_RAFT_WARM_BYTES")
-    if v:
-        return int(float(v))
-    from ..tune import active
-
-    return int(active.get("warm_bytes", DEFAULT_WARM_BYTES))
+    return int(float(v)) if v else DEFAULT_WARM_BYTES
 
 
 class Generation:
