@@ -160,6 +160,29 @@ def test_superstep_depth19(engine, one_chip, on_tpu):
     assert _hbm_bytes(compiled) < HBM
 
 
+def test_guard_pass_cycles(one_chip):
+    """The guard pass over one chunk (1,024 rows) of the reference config.
+
+    The message-side terms select each guarded bit by its flat offset in
+    the message row; as chains of one-hot int32 einsums the pass was
+    estimated at 9.29M cycles with 12.8 MB of code.  Summed over the
+    compiled ops' estimates, it must stay under 2.5M cycles."""
+    from tla_raft_tpu.ops.successor import get_kernel
+
+    cfg = RaftConfig()
+    st = jax.tree.map(
+        lambda x: _sds(x.shape, x.dtype, one_chip), init_batch(cfg, 1024)
+    )
+    compiled = jax.jit(get_kernel(cfg, mxu=True).mxu.guards).lower(st).compile()
+    cycles = sum(
+        int(c) for c in re.findall(
+            r'"estimated_cycles":"(\d+)"', compiled.as_text()
+        )
+    )
+    assert 0 < cycles <= 2_500_000
+    assert compiled.memory_analysis().generated_code_size_in_bytes <= 12_800_000
+
+
 def test_owner_dedup_order_radix(one_chip):
     """The mesh owner dedup's stable (fp_view, fp_full) order over 2^20
     lanes (ops/radix.py): no sort op, which XLA:TPU takes minutes to

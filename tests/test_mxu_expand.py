@@ -26,7 +26,7 @@ import pytest
 
 from tla_raft_tpu.config import RaftConfig
 from tla_raft_tpu.engine import JaxChecker
-from tla_raft_tpu.models.raft import from_oracle
+from tla_raft_tpu.models.raft import from_oracle, init_batch
 from tla_raft_tpu.ops.successor import SuccessorKernel, get_kernel
 from tla_raft_tpu.oracle.explicit import collect_reachable as collect
 
@@ -45,6 +45,14 @@ CFGS = [
                mutations=("legacy-append",)),
 ]
 CFG_IDS = ["s2", "s3", "double-vote", "become-follower", "legacy-append"]
+# the benchmark cells' constants (raft-ref; raft-s5 is its S=5 dial) on
+# corpora deep enough to put AppendEntries requests, responses and
+# rejects in flight
+GUARD_CASES = [(cfg, 120) for cfg in CFGS] + [
+    (REF, 2000),
+    (RaftConfig(n_servers=5, n_vals=2, max_election=3, max_restart=3), 800),
+]
+GUARD_IDS = CFG_IDS + ["raft-ref", "raft-s5"]
 
 
 def _triple(res):
@@ -53,10 +61,10 @@ def _triple(res):
 
 # -- kernel-level parity --------------------------------------------------
 
-@pytest.mark.parametrize("cfg", CFGS, ids=CFG_IDS)
-def test_guards_match_legacy(cfg):
+@pytest.mark.parametrize("cfg,n_states", GUARD_CASES, ids=GUARD_IDS)
+def test_guards_match_legacy(cfg, n_states):
     kern = SuccessorKernel(cfg, mxu=True)
-    batch = from_oracle(cfg, collect(cfg, 120))
+    batch = from_oracle(cfg, collect(cfg, n_states))
     gv, gm, ga = kern.expand_guards(batch)
     lv, lm, la = kern.expand_guards_legacy(batch)
     assert np.array_equal(np.asarray(gv), np.asarray(lv)), (
@@ -64,6 +72,35 @@ def test_guards_match_legacy(cfg):
     )
     assert np.array_equal(np.asarray(gm), np.asarray(lm))
     assert np.array_equal(np.asarray(ga), np.asarray(la))
+
+
+def _random_lanes(cfg, n, seed):
+    """``n`` arbitrary lanes: every field a small random byte (clips and
+    out-of-range digits included) and message bits at up to 5% density,
+    so the exact-request guards meet messages behind guards that hold."""
+    rng = np.random.default_rng(seed)
+    init = init_batch(cfg, n)
+    fields = {
+        f: jnp.asarray(rng.integers(0, 6, x.shape), x.dtype)
+        for f, x in init._asdict().items() if f != "msgs"
+    }
+    n_words = init.msgs.shape[1]
+    bits = rng.uniform(size=(n, n_words, 32)) < rng.uniform(0, 0.05, (n, 1, 1))
+    words = (bits.astype(np.uint64) << np.arange(32, dtype=np.uint64)).sum(-1)
+    return init._replace(**fields, msgs=jnp.asarray(words, jnp.uint32))
+
+
+@pytest.mark.parametrize("cfg", [c for c, _ in GUARD_CASES], ids=GUARD_IDS)
+def test_guards_match_legacy_random_lanes(cfg):
+    """The guards on arbitrary lanes, as the engines' padded chunks hold:
+    bit for bit on EVERY input, not just reachable states."""
+    kern = get_kernel(cfg, mxu=True)
+    batch = _random_lanes(cfg, 256, seed=cfg.S)
+    for a, b in zip(kern.expand_guards(batch), kern.expand_guards_legacy(batch)):
+        assert a.dtype == b.dtype
+        assert np.array_equal(np.asarray(a), np.asarray(b)), (
+            np.argwhere(np.asarray(a) != np.asarray(b))[:10]
+        )
 
 
 @pytest.mark.parametrize("cfg", CFGS, ids=CFG_IDS)

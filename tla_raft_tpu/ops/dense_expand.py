@@ -16,6 +16,8 @@ fingerprints, split-brain abort) in fully dense form:
   of the unpacked bit vector (``[B, pair, term, ...]`` per message type),
   so guard existence/counting is reductions plus tiny one-hot
   contractions over the data-dependent digits (term, prevLogTerm, ...);
+  ``msg_guard_parts``, the MXU guard pass's half, selects those digits
+  by flat offset instead;
 * **fingerprints are incremental** — ``h(child) = h(parent) +
   sum C_eff[changed] * delta`` over the byte-plane-linearized
   multilinear hash (ops/fingerprint.py); added-message coefficients are
@@ -50,6 +52,29 @@ U32 = jnp.uint32
 def _oh(x, n):
     """One-hot over a tiny digit range; i32 for exact integer contraction."""
     return (x[..., None] == jnp.arange(n, dtype=x.dtype)).astype(I32)
+
+
+def _flat(*digits):
+    """Mixed-radix offset of ``(digit, radix)`` pairs, most significant
+    first; -1 where any digit lies outside ``0..radix-1``."""
+    off, ok = 0, True
+    for d, r in digits:
+        off = off * r + d
+        ok = ok & (d >= 0) & (d < r)
+    return jnp.where(ok, off, -1)
+
+
+def _pick(blk, off):
+    """``blk[b, n, off[b, n, ...]]`` by ``iota == off`` and a masked sum.
+
+    blk [B, N, R, *U], off i32[B, N, *O] -> i32[B, N, *O, *U].  No gather:
+    every offset compares against the R positions.  An offset outside
+    0..R-1 picks 0, as a one-hot of an out-of-range digit does."""
+    R, no, nu = blk.shape[2], off.ndim - 2, blk.ndim - 3
+    hit = off[..., None] == jnp.arange(R, dtype=off.dtype)
+    hit = hit.reshape(hit.shape + (1,) * nu)
+    blk = blk.reshape(blk.shape[:2] + (1,) * no + blk.shape[2:])
+    return jnp.where(hit, blk, 0).sum(2 + no, dtype=I32)
 
 
 class DenseExpand:
@@ -181,18 +206,47 @@ class DenseExpand:
 
     # ---- message-side guard terms (the MXU split) -----------------------
 
+    def _pairs(self, x):
+        """[B, S, S, ...] -> [B, NP, ...]: x[b, a, c] at pair digit a->c.
+
+        The pair digit is the row-major rank of (a, c) off the diagonal,
+        so dropping the diagonal is a static slice of the flat grid."""
+        S, B, rest = self.S, x.shape[0], x.shape[3:]
+        x = x.reshape(B, S * S, *rest)[:, 1:]
+        x = x.reshape(B, S - 1, S + 1, *rest)[:, :, :S]
+        return x.reshape(B, self.NP, *rest)
+
+    def _grid(self, xp):
+        """[B, NP, ...] -> [B, S(src), S(dst), ...], zero on the diagonal
+        (the inverse of ``_pairs``: static pads and reshapes)."""
+        S, B, rest = self.S, xp.shape[0], xp.shape[2:]
+        pad = [(0, 0)] * len(rest)
+        x = xp.reshape(B, S - 1, S, *rest)
+        x = jnp.pad(x, [(0, 0), (0, 0), (0, 1)] + pad)
+        x = jnp.pad(x.reshape(B, S * S - 1, *rest), [(0, 0), (1, 0)] + pad)
+        return x.reshape(B, S, S, *rest)
+
     def msg_guard_parts(self, st):
         """(msg_ok bool[B,K], mult i32[B,K], abort bool[B]).
 
-        The message-dependent half of every guard — existence/count
-        reductions over the mixed-radix blocks, including the terms
-        whose digits are data-indexed (term/prevLogTerm one-hots) —
-        mirrored term for term from ``__call__``.  The static
-        (message-independent) half lives in ops/mxu_expand.py as the
-        guard coefficient matmul; the two factors partition exactly the
+        The message-dependent half of every guard, the same values as
+        ``__call__``'s terms at every lane.  The static (message-
+        independent) half lives in ops/mxu_expand.py as the guard
+        coefficient matmul; the two factors partition exactly the
         conjuncts of each scalar guard in ops/successor.py, so
         ``static & msg`` is bit-identical to the fused ``valid``.
         Families with no message guard emit all-true / mult 1.
+
+        Each message type is a static column range of the unpacked row,
+        viewed as ``[B, pair, rows, run]`` with the widest contiguous
+        digit run last.  A guarded message's digits (term, prevLogIndex,
+        prevLogTerm, entry, leaderCommit, ...) come from the row's own
+        fields; their mixed-radix flat offset inside the pair block is
+        selected by ``iota == offset`` and a masked sum (``_pick``), so no
+        one-hot operand is contracted.  Counts sum contiguous columns
+        first and select the term digit after.  Per-server fields reach
+        the pair axis, and per-pair results the ``[s, c]`` witness grid,
+        by static slices and pads (``_pairs`` / ``_grid``).
         """
         cfg, uni = self.cfg, self.uni
         S, T, L, V, E, NP = self.S, self.T, self.L, self.V, self.E, self.NP
@@ -203,33 +257,48 @@ class DenseExpand:
         ll = i32(st.log_len)
         lt = i32(st.log_term)
         ci = i32(st.commit_index)
-
-        bits = self.fpr.unpack_bits(st.msgs).astype(I32)
-        vq = bits[:, : uni.vp_off].reshape(B, NP, T, L, T)
-        vp = bits[:, uni.vp_off : uni.aq_off].reshape(B, NP, T)
-        aq = bits[:, uni.aq_off : uni.ap_off].reshape(
-            B, NP, T, L, T + 1, E, L
-        )
         NPLI = uni.ap_npli
         legacy_ae = "legacy-append" in cfg.mutations
-        ap = bits[:, uni.ap_off :].reshape(B, NP, T, NPLI, 2)
 
-        vq_r = vq.sum((3, 4), dtype=I32)
-        aq_r = aq.sum((3, 4, 5, 6), dtype=I32)
-        ap_r = ap.sum((3, 4), dtype=I32)
-        to_cnt = jnp.einsum("bpt,dp->bdt", vq_r + vp + aq_r + ap_r, self.SELD)
-        aq_to_cnt = jnp.einsum("bpt,dp->bdt", aq_r, self.SELD)
-        AQR = aq.sum((5, 6), dtype=I32)
-        ap0, ap1 = ap[..., 0], ap[..., 1]
+        # message blocks: per pair, the term digit leads every block
+        bits = self.fpr.unpack_bits(st.msgs)  # i8 [B, M]
+        vq = bits[:, : uni.vp_off].reshape(B, NP, T * L * T)  # (t, lli, llt)
+        vp = bits[:, uni.vp_off : uni.aq_off].reshape(B, NP, T)
+        aq = bits[:, uni.aq_off : uni.ap_off].reshape(
+            B, NP, T * L * (T + 1), E * L
+        )  # (t, pli, plt) x (entry, lc)
+        ap = bits[:, uni.ap_off :].reshape(B, NP, T * NPLI, 2)  # (t, pli) x succ
 
-        oh_ct = _oh(jnp.clip(ct - 1, 0, T - 1), T)
+        # per-server fields on the pair axis: the sender's, the receiver's
+        grid = lambda x: jnp.broadcast_to(x, (B, S, S) + x.shape[3:])
+        at_src = lambda x: self._pairs(grid(x[:, :, None]))
+        at_dst = lambda x: self._pairs(grid(x[:, None]))
+        # per-pair results on the witness grid [B, s, c]: the pair s->c,
+        # or the pair c->s (s receives)
+        from_s = self._grid
+        to_s = lambda xp: jnp.swapaxes(self._grid(xp), 1, 2)
+
+        tsel = jnp.clip(ct - 1, 0, T - 1)  # cur-term digit
         has_term = ct >= 1
         oh_ll_pos = _oh(jnp.clip(ll - 1, 0, L - 1), L)
         llt_val = (oh_ll_pos * lt).sum(-1, dtype=I32)
         tcur1 = jnp.clip(ct, 1, T)
         pli_ax = jnp.arange(1, L + 1, dtype=I32)
+        t_src, t_dst = at_src(tsel), at_dst(tsel)  # [B, NP]
         true_ = lambda *sh: jnp.ones((B, *sh), bool)
         one_ = lambda *sh: jnp.ones((B, *sh), I32)
+
+        # per-(pair, term) counts: static sums over contiguous columns
+        aq_rows = aq.sum(3, dtype=I32)  # [B, NP, T*L*(T+1)]
+        aq_r = aq_rows.reshape(B, NP, T, L * (T + 1)).sum(3, dtype=I32)
+        tot = (
+            vq.reshape(B, NP, T, L * T).sum(3, dtype=I32)
+            + vp
+            + aq_r
+            + ap.reshape(B, NP, T, NPLI * 2).sum(3, dtype=I32)
+        )
+        to_cnt = from_s(tot).sum(1, dtype=I32)  # [B, d, T]: pairs to d
+        aq_to_cnt = from_s(aq_r).sum(1, dtype=I32)
 
         ok_parts, mult_parts = [], []
 
@@ -242,78 +311,85 @@ class DenseExpand:
         # F1 UpdateTerm (a): any message to s at term t
         emit(to_cnt > 0, to_cnt)
         # F2 UpdateTerm (b) + the split-brain Assert (Raft.tla:185)
-        cnt2 = jnp.einsum("bdt,bdt->bd", aq_to_cnt, oh_ct)
+        cnt2 = _pick(aq_to_cnt, tsel)
         has2 = has_term & (cnt2 > 0)
         if "become-follower" in cfg.mutations:
             abort = jnp.zeros((B,), bool)
         else:
             abort = (has2 & (role == LEADER)).any(1)
         emit(cnt2 > 0, cnt2)
-        # F3 ResponseVote: up-to-date VoteReq present, grant not re-sent
-        UP = jnp.einsum("bptlk,klmj->bptmj", vq, self.QUAL)
-        oh_myllt = _oh(jnp.clip(llt_val, 0, T), T + 1)
-        qual_cnt = jnp.einsum(
-            "bptmj,csp,bst,bsm,bsj->bsc",
-            UP, self.SELP, oh_ct, oh_myllt, oh_ll_pos,
-        )
-        grant_bit = jnp.einsum("bpt,scp,bst->bsc", vp, self.SELP, oh_ct)
+        # F3 ResponseVote: an up-to-date VoteReq c->s at s's term (llt >
+        # myllt, or equal with lli >= mylli), grant s->c not re-sent
+        w = np.arange(T * L * T, dtype=np.int32)
+        w_t, w_l, w_k = w // (L * T), (w // T) % L, w % T
+        m_ = jnp.clip(llt_val, 0, T)[..., None]
+        j_ = jnp.clip(ll - 1, 0, L - 1)[..., None]
+        qual = (w_t == tsel[..., None]) & (
+            (w_k > m_) | ((w_k == m_) & (w_l >= j_))
+        )  # [B, s, T*L*T]
+        qual_cnt = to_s(jnp.where(at_dst(qual), vq, 0).sum(2, dtype=I32))
+        grant_bit = from_s(_pick(vp, t_src))
         emit((qual_cnt > 0) & (grant_bit == 0), qual_cnt)
         # F4 BecomeLeader: the vote-count threshold (Raft.tla:160-164)
-        votes = jnp.einsum("bpt,sp,bst->bs", vp, self.SELD, oh_ct)
+        votes = from_s(_pick(vp, t_dst)).sum(1, dtype=I32)
         emit(votes + 1 >= cfg.majority, one_(S))
         # F5 ClientReq: no message guard
         emit(true_(S, V), one_(S, V))
-        # F6 LeaderAppendEntry: the exact request not already in flight
+        # F6 LeaderAppendEntry: the exact request s->d not already in flight
         ni = i32(st.next_index)
         lv = i32(st.log_val)
         pli6 = jnp.clip(ni - 1, 1, L)
         prev_oh = _oh(jnp.clip(ni - 2, 0, L - 1), L)
-        plt6 = jnp.clip(jnp.einsum("bsdl,bsl->bsd", prev_oh, lt), 0, T)
+        plt6 = jnp.clip((prev_oh * lt[:, :, None]).sum(-1, dtype=I32), 0, T)
         has_e = ni <= ll[:, :, None]
         epos_oh = _oh(jnp.clip(ni - 1, 0, L - 1), L)
-        et6 = jnp.clip(jnp.einsum("bsdl,bsl->bsd", epos_oh, lt), 1, T)
-        ev6 = jnp.clip(jnp.einsum("bsdl,bsl->bsd", epos_oh, lv), 1, V)
+        et6 = jnp.clip((epos_oh * lt[:, :, None]).sum(-1, dtype=I32), 1, T)
+        ev6 = jnp.clip((epos_oh * lv[:, :, None]).sum(-1, dtype=I32), 1, V)
         ecode6 = jnp.where(has_e, 1 + (et6 - 1) * V + (ev6 - 1), 0)
-        lc6 = jnp.clip(ci, 1, L)[:, :, None]
-        present6 = jnp.einsum(
-            "bqtlmeh,sdq,bsdt,bsdl,bsdm,bsde,bsdh->bsd",
-            aq, self.SELP,
-            _oh(jnp.broadcast_to(tcur1[:, :, None], (B, S, S)) - 1, T),
-            _oh(pli6 - 1, L), _oh(plt6, T + 1), _oh(ecode6, E),
-            _oh(jnp.broadcast_to(lc6, (B, S, S)) - 1, L),
-        )
+        off6 = _flat(
+            (grid(tcur1[:, :, None]) - 1, T), (pli6 - 1, L), (plt6, T + 1),
+            (ecode6, E), (grid(jnp.clip(ci, 1, L)[:, :, None]) - 1, L),
+        )  # [B, s, d]
+        present6 = from_s(_pick(aq.reshape(B, NP, -1), self._pairs(off6)))
         emit(present6 == 0, one_(S, S))
-        # F7 FollowerAcceptEntry: the exact request present (+ the dead
+        # F7 FollowerAcceptEntry: the exact request c->s present, at s's
+        # term and prevLogTerm = log_term[s, pli-1] (+ the dead
         # FollowerAppendEntry's resp/commit-advance gate under mutation)
-        plt7 = jnp.clip(lt, 0, T)
-        oh_plt7 = _oh(plt7, T + 1)
-        present7 = jnp.einsum(
-            "bqtlmeh,csq,bst,bslm->bscleh", aq, self.SELP, oh_ct, oh_plt7
-        )
-        ok7 = present7 > 0
+        plt7 = jnp.clip(lt, 0, T)  # [B, s, l]
+        off7 = at_dst(
+            _flat((tsel[..., None], T), (pli_ax - 1, L), (plt7, T + 1))
+        )  # [B, NP, l]: the (t, pli, plt) row of pair c->s
+        present7 = to_s(_pick(aq, off7))  # [B, s, c, l, E*L]
+        ok7 = present7.reshape(B, S, S, L, E, L) > 0
         if legacy_ae:
-            oh_pi = _oh(self.PI - uni.ap_pli_min, NPLI)
-            resp_present7 = jnp.einsum(
-                "bqtj,scq,bst,lej->bscle", ap1, self.SELP, oh_ct, oh_pi
-            )
+            pi = self.PI - uni.ap_pli_min  # [l, e]
+            resp_present7 = from_s(
+                _pick(ap[..., 1], t_src[:, :, None, None] * NPLI + pi)
+            )  # [B, s, c, l, e]
             ci_adv = self.MINLC[None, None] > ci[:, :, None, None, None]
             ok7 = ok7 & (
                 (resp_present7[:, :, :, :, :, None] == 0) | ci_adv[:, :, None]
             )
         emit(ok7, one_(S, S, L, E, L))
-        # F8 FollowerRejectEntry: mismatching blocks present, reject unsent
+        # F8 FollowerRejectEntry: mismatching blocks c->s present, the
+        # reject s->c unsent
         log_match = pli_ax[None, None, :] <= ll[:, :, None]
-        tot8 = jnp.einsum("bqtlm,csq,bst->bscl", AQR, self.SELP, oh_ct)
-        match8 = jnp.einsum(
-            "bqtlm,csq,bst,bslm->bscl", AQR, self.SELP, oh_ct, oh_plt7
-        )
+        tot8 = to_s(
+            _pick(aq_rows.reshape(B, NP, T, L, T + 1).sum(4, dtype=I32), t_dst)
+        )  # [B, s, c, l]
+        match8 = to_s(_pick(aq_rows, off7))
         cnt8 = tot8 - jnp.where(log_match[:, :, None, :], match8, 0)
-        ap0_rej = ap0 if uni.ap_pli_min == 1 else ap0[:, :, :, :L]
-        rej_bit = jnp.einsum("bqtl,scq,bst->bscl", ap0_rej, self.SELP, oh_ct)
+        # the reject's pli digit is l0 in both pli domains (section F8 of
+        # ``__call__``)
+        rej_bit = from_s(
+            _pick(ap[..., 0], t_src[..., None] * NPLI + pli_ax - 1)
+        )
         emit((cnt8 > 0) & (rej_bit == 0), cnt8)
-        # F9 HandleAppendResp: the response bit present
-        ap9 = ap if uni.ap_pli_min == 1 else ap[:, :, :, 1:]
-        bit9 = jnp.einsum("bqtlx,csq,bst->bsclx", ap9, self.SELP, oh_ct)
+        # F9 HandleAppendResp: the response bit c->s present (witness pli
+        # 1..L: digit l0 + 1 - ap_pli_min)
+        bit9 = to_s(
+            _pick(ap, t_dst[..., None] * NPLI + pli_ax - uni.ap_pli_min)
+        )  # [B, s, c, l, 2]
         emit(bit9 > 0, one_(S, S, L, 2))
         # F10 LeaderCanCommit / F11 Restart: no message guard
         emit(true_(S), one_(S))

@@ -34,9 +34,10 @@ state blocks, the BLEST / "Graph Traversal on Tensor Cores" move
 * :class:`MXUExpand` routes the two kernels:
 
   - ``guards``: static matmul & the message-dependent guard terms
-    (``DenseExpand.msg_guard_parts`` — existence/count reductions over
-    the mixed-radix message blocks, the irreducibly data-indexed
-    digits staying on their exact einsum path);
+    (``DenseExpand.msg_guard_parts`` — contiguous-column counts over
+    the mixed-radix message blocks, and each guarded bit selected by
+    its flat offset in the pair block, computed from the lane's own
+    fields);
   - ``materialize``/``materialize_added``: every field update of every
     family expressed as masked row/rank-1 updates over the packed
     block (``new = old + onehot * delta`` style selects), combined by
@@ -401,9 +402,13 @@ class MXUExpand:
 
         ``phi @ W == theta`` resolves every static guard conjunction in
         one [B, feat] x [feat, K] MXU matmul (counts are tiny integers,
-        exact in f32); the message-side terms come from the dense block
-        reductions.  Bit-identical to the legacy decomposition: the two
-        factors partition exactly the conjuncts of each scalar guard.
+        exact in f32).  The message-side terms
+        (``DenseExpand.msg_guard_parts``) sum contiguous columns of the
+        message blocks and select each guarded bit by its mixed-radix
+        flat offset in the pair block (``iota == offset``, a masked
+        sum): no gather and no one-hot contraction.  Bit-identical to
+        the legacy decomposition: the two factors partition exactly the
+        conjuncts of each scalar guard.
         """
         t = self.tables
         # the name groups the guard pass's device ops in a profile
